@@ -87,8 +87,10 @@ class SocketSettings:
     selection: str = "kvhead"
     # Pallas kernel routing for the decode path (models.backends.socket):
     # score via kernels/socket_score and attend the selected subset via
-    # kernels/flash_decode.  Off-TPU both run in interpret mode (bit-exact
-    # semantics, interpreter speed) — the XLA fallback is the CPU default.
+    # kernels/flash_decode.  The kernels compile with Mosaic on a TPU and
+    # run in the Pallas interpreter elsewhere (same semantics, interpreter
+    # speed); with both flags off the decode path is plain XLA on every
+    # platform.
     use_score_kernel: bool = False
     use_flash_decode: bool = False
     # Route PagedView decode (the serving engine) through the fused

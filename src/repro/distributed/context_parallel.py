@@ -33,7 +33,6 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import socket
-from repro.distributed.sharding import shard_map
 
 __all__ = ["context_parallel_socket_attend", "merge_partials"]
 
@@ -150,7 +149,7 @@ def context_parallel_socket_attend(
         merged = merge_partials(m, l, o, axis)
         return merged.astype(q_l.dtype)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(rep, cache_spec, cache_spec, cache_spec, flat_spec, P()),
         out_specs=rep,

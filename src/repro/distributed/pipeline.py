@@ -26,7 +26,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.distributed.sharding import shard_map
 
 __all__ = ["gpipe_forward"]
 
@@ -83,7 +82,7 @@ def gpipe_forward(mesh: Mesh, stage_axis: str, stage_fn: Callable,
         outs = jax.lax.all_gather(outs, stage_axis)[stages - 1]
         return outs.reshape(b, *x_l.shape[1:])
 
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(stage_axis), P()),
         out_specs=P(),

@@ -23,30 +23,9 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 __all__ = [
     "DEFAULT_RULES", "activate_mesh", "current_mesh", "fallback_log", "lsc",
-    "logical_to_spec", "named_sharding", "shard_map", "spec_for_shape",
+    "logical_to_spec", "named_sharding", "spec_for_shape",
 ]
 
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """Version-portable ``shard_map``.
-
-    Newer jax exposes ``jax.shard_map`` with the replication check named
-    ``check_vma``; jax 0.4.x only has ``jax.experimental.shard_map``, and
-    some releases in between ship ``jax.shard_map`` with the flag still
-    named ``check_rep`` — so the kwarg name is chosen by signature, not
-    by version.  Every shard_map in this repo goes through this wrapper
-    so all three toolchains work unmodified.
-    """
-    import inspect
-
-    if hasattr(jax, "shard_map"):
-        sm = jax.shard_map
-    else:
-        from jax.experimental.shard_map import shard_map as sm
-    params = inspect.signature(sm).parameters
-    check_kw = "check_vma" if "check_vma" in params else "check_rep"
-    return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-              **{check_kw: check_vma})
 
 # logical axis -> mesh axis (or tuple of mesh axes)
 DEFAULT_RULES: Dict[str, Optional[Tuple[str, ...]]] = {

@@ -10,7 +10,8 @@
                     block table (one pass over the paged pool, no score
                     / index / gathered-K/V materialization in HBM).
 
-Each kernel ships ``ops.py`` (jitted wrapper; interpret=True off-TPU) and
+Each kernel ships ``ops.py`` (jitted wrapper; Mosaic on a TPU backend,
+the Pallas interpreter elsewhere — ``common.resolve_interpret``) and
 ``ref.py`` (pure-jnp oracle driven by ``tests/kernel_harness.py``).
 See README.md in this directory for the layout contract.
 """

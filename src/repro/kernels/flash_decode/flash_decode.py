@@ -25,8 +25,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.common import finish_softmax, fold_page, init_softmax
+
 DEFAULT_BLOCK_K = 512
-NEG_INF = -1e30
 
 
 def _decode_kernel(q_ref, k_ref, v_ref, mask_ref, out_ref, m_scr, l_scr,
@@ -35,40 +36,20 @@ def _decode_kernel(q_ref, k_ref, v_ref, mask_ref, out_ref, m_scr, l_scr,
 
     @pl.when(kb == 0)
     def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
+        init_softmax(m_scr, l_scr, acc_scr)
 
-    q = q_ref[0].astype(jnp.float32)              # (G, hd)
-    k = k_ref[0].astype(jnp.float32)              # (block_k, hd)
-    v = v_ref[0].astype(jnp.float32)
-    valid = mask_ref[0]                           # (block_k,) bool
-
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ()))) * scale
-    s = jnp.where(valid[None, :], s, NEG_INF)     # (G, block_k)
-
-    m_prev = m_scr[...]                           # (G,)
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-    alpha = jnp.exp(m_prev - m_new)               # (G,)
-    p = jnp.exp(s - m_new[:, None])               # (G, block_k)
-    p = jnp.where(valid[None, :], p, 0.0)
-
-    l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=-1)
-    acc_scr[...] = acc_scr[...] * alpha[:, None] + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())))
-    m_scr[...] = m_new
+    fold_page(q_ref[0].astype(jnp.float32), k_ref[0], v_ref[0],
+              mask_ref[0] != 0, m_scr, l_scr, acc_scr, scale=scale)
 
     @pl.when(kb == num_k_blocks - 1)
     def _done():
-        out_ref[0] = (acc_scr[...] /
-                      jnp.maximum(l_scr[...], 1e-30)[:, None]
-                      ).astype(out_ref.dtype)
+        out_ref[0] = finish_softmax(l_scr, acc_scr).astype(out_ref.dtype)
 
 
 def flash_decode_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
                         mask: jax.Array, *, scale: float,
                         block_k: int = DEFAULT_BLOCK_K,
-                        interpret: bool = True) -> jax.Array:
+                        interpret: bool) -> jax.Array:
     """q (BH, G, hd); k/v (BH, K, hd); mask (BH, K) -> f32 (BH, G, hd).
 
     ``K`` need not divide ``block_k``: the tail (and a whole short
@@ -94,14 +75,14 @@ def flash_decode_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
             pl.BlockSpec((1, g, hd), lambda b, i: (b, 0, 0)),
             pl.BlockSpec((1, block_k, hd), lambda b, i: (b, i, 0)),
             pl.BlockSpec((1, block_k, hd), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, block_k), lambda b, i: (b, i)),
+            pl.BlockSpec((1, 1, block_k), lambda b, i: (b, 0, i)),
         ],
         out_specs=pl.BlockSpec((1, g, hd), lambda b, i: (b, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, g, hd), jnp.float32),
         scratch_shapes=[
-            pltpu.VMEM((g,), jnp.float32),
-            pltpu.VMEM((g,), jnp.float32),
-            pltpu.VMEM((g, hd), jnp.float32),
+            pltpu.VMEM((g, 1), jnp.float32),     # m
+            pltpu.VMEM((g, 1), jnp.float32),     # l
+            pltpu.VMEM((g, hd), jnp.float32),    # acc
         ],
         interpret=interpret,
-    )(q, k, v, mask)
+    )(q, k, v, mask.astype(jnp.int32).reshape(bh, 1, kk + pad))

@@ -9,12 +9,9 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.common import resolve_interpret
 from repro.kernels.flash_decode.flash_decode import (DEFAULT_BLOCK_K,
                                                      flash_decode_pallas)
-
-
-def _auto_interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "block_k", "interpret"))
@@ -32,7 +29,7 @@ def flash_decode(q: jax.Array, k: jax.Array, v: jax.Array,
     q (B, KVH, G, 1, hd) or (BH, G, hd); k/v (B, KVH, K, hd) or (BH, K, hd);
     mask (B, KVH, K) / (BH, K).  Returns attention output in q's layout.
     """
-    interpret = _auto_interpret() if interpret is None else interpret
+    interpret = resolve_interpret(interpret)
     orig5 = q.ndim == 5
     if orig5:
         b, kvh, g, t, hd = q.shape

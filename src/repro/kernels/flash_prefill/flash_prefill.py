@@ -77,7 +77,7 @@ def flash_prefill_pallas(q: jax.Array, k: jax.Array, v: jax.Array, *,
                          scale: float, window: int = 0,
                          block_q: int = DEFAULT_BLOCK_Q,
                          block_k: int = DEFAULT_BLOCK_K,
-                         interpret: bool = True) -> jax.Array:
+                         interpret: bool) -> jax.Array:
     """q/k/v (BH, S, hd) -> f32 (BH, S, hd) causal attention."""
     bh, s, hd = q.shape
     if s % block_q or s % block_k:

@@ -8,13 +8,10 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.common import resolve_interpret
 from repro.kernels.flash_prefill.flash_prefill import (DEFAULT_BLOCK_K,
                                                        DEFAULT_BLOCK_Q,
                                                        flash_prefill_pallas)
-
-
-def _auto_interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "window", "block_q",
@@ -30,7 +27,7 @@ def flash_prefill(q: jax.Array, k: jax.Array, v: jax.Array, *, scale: float,
                   block_k: int = DEFAULT_BLOCK_K,
                   interpret: Optional[bool] = None) -> jax.Array:
     """Causal attention.  q/k/v (BH, S, hd); returns f32 (BH, S, hd)."""
-    interpret = _auto_interpret() if interpret is None else interpret
+    interpret = resolve_interpret(interpret)
     s = q.shape[1]
     bq = min(block_q, s)
     bk = min(block_k, s)

@@ -2,9 +2,9 @@
 
 Accepts the serving engine's natural layouts (5-D decode query, paged
 pool leaves, per-request block table / length / budget vectors) and
-launches :func:`paged_attention_pallas`; on non-TPU backends the kernel
-runs in interpret mode (bit-exact semantics) — set ``interpret=False``
-on real TPU.
+launches :func:`paged_attention_pallas`.  ``interpret=None`` (the
+default) compiles with Mosaic on a TPU backend and interprets elsewhere
+(:func:`repro.kernels.common.resolve_interpret`).
 """
 
 from __future__ import annotations
@@ -15,15 +15,12 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.common import resolve_interpret
 from repro.kernels.paged_attention.paged_attention import (
     paged_attention_pallas)
 from repro.kernels.paged_attention.paged_hard_lsh import paged_hard_lsh_pallas
 from repro.kernels.paged_attention.paged_quest import paged_quest_pallas
 from repro.kernels.paged_attention.paged_ring import paged_ring_pallas
-
-
-def _auto_interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -68,7 +65,7 @@ def paged_socket_attend(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     Returns attention output in q's layout (f32), plus the int32
     ``(B, KVH, nb, bs)`` selection mask when ``with_selection``.
     """
-    interpret = _auto_interpret() if interpret is None else interpret
+    interpret = resolve_interpret(interpret)
     orig5 = q.ndim == 5
     if orig5:
         b, kvh, g, t, hd = q.shape
@@ -123,7 +120,7 @@ def paged_hard_lsh_attend(q: jax.Array, k_pages: jax.Array,
     hash is ``u_signs`` — f32 ±1 plane signs ``(B, KVH, GS, L, P)``
     (``where(u >= 0, +1, -1)`` of the soft hash).
     """
-    interpret = _auto_interpret() if interpret is None else interpret
+    interpret = resolve_interpret(interpret)
     orig5 = q.ndim == 5
     if orig5:
         b, kvh, g, t, hd = q.shape
@@ -184,7 +181,7 @@ def paged_quest_attend(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
       k/v_scale      (NB, KVH, bs) per-row dequant scales (quantized
                      pools only — both or neither)
     """
-    interpret = _auto_interpret() if interpret is None else interpret
+    interpret = resolve_interpret(interpret)
     orig5 = q.ndim == 5
     if orig5:
         b, kvh, g, t, hd = q.shape
@@ -234,7 +231,7 @@ def paged_ring_attend(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
       k/v_scale    (NB, KVH, bs) per-row dequant scales (quantized pools
                    only — both or neither; dequantized in-kernel)
     """
-    interpret = _auto_interpret() if interpret is None else interpret
+    interpret = resolve_interpret(interpret)
     orig5 = q.ndim == 5
     if orig5:
         b, kvh, g, t, hd = q.shape

@@ -42,6 +42,14 @@ TPU); phase 0 is the score pass, phase 1 the attend pass.  Index maps
 pin the K/V page index to ``bt[b, 0]`` during the score phase (and the
 bits/vnorm index during the attend phase), so Pallas's revisiting
 pipeline fetches each page's K/V exactly once.
+
+Mosaic layout: every per-token vector is a ``(1, bs)`` lane row and
+every per-query-head statistic a ``(G, 1)`` column.  Per-head ``(NB,
+KVH, bs)`` leaves (value norms, dequant scales) are fetched as whole
+``(1, KVH, bs)`` tiles — a ``(1, 1, bs)`` block would put a 1 against
+KVH on the sublane axis, which Mosaic refuses — and the head's row is
+picked in-register.  The table/plane split of the unpacked hash bits is
+a matmul against a 0/1 segment matrix instead of a lane reshape.
 """
 
 from __future__ import annotations
@@ -55,7 +63,10 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-NEG_INF = -1e30
+from repro.kernels.common import (HIGHEST, NEG_INF, NN, finish_softmax,
+                                  fold_page, head_row, init_softmax,
+                                  table_scores, unpack_signs)
+
 FLT_MAX = float(np.finfo(np.float32).max)
 
 
@@ -64,6 +75,59 @@ def _sort_key(eff: jax.Array) -> jax.Array:
     u = jax.lax.bitcast_convert_type(eff, jnp.uint32)
     neg = (u >> jnp.uint32(31)) == jnp.uint32(1)
     return u ^ jnp.where(neg, jnp.uint32(0xFFFFFFFF), jnp.uint32(0x80000000))
+
+
+def _ring_chunk(rows: int, cap: int = 512) -> int:
+    """Rows of the score ring one select pass reads at a time: the whole
+    ring when it is small, else the largest multiple-of-8 divisor of
+    ``rows`` up to ``cap`` (bounds the live key temporaries in VMEM)."""
+    if rows <= cap:
+        return rows
+    for c in range(cap, 7, -8):
+        if rows % c == 0:
+            return c
+    return rows
+
+
+def count_keys(ring_ref, pred) -> jax.Array:
+    """``sum(pred(_sort_key(ring)))`` over the VMEM score ring, chunked
+    over its rows."""
+    rows = ring_ref.shape[0]
+    chunk = _ring_chunk(rows)
+    if chunk == rows:
+        return jnp.sum(pred(_sort_key(ring_ref[...])).astype(jnp.int32))
+
+    def body(c, acc):
+        start = pl.multiple_of(c * chunk, chunk)
+        keys = _sort_key(ring_ref[pl.ds(start, chunk), :])
+        return acc + jnp.sum(pred(keys).astype(jnp.int32))
+
+    return jax.lax.fori_loop(0, rows // chunk, body, jnp.int32(0))
+
+
+def radix_threshold(ring_ref, bud) -> jax.Array:
+    """Largest uint32 ``T`` with ``count(keys >= T) >= bud`` over the
+    ring's sort keys — the ``bud``-th largest key (attained), built
+    MSB-first."""
+    def body(t, prefix):
+        shift = jnp.uint32(31) - t.astype(jnp.uint32)
+        cand = prefix | (jnp.uint32(1) << shift)
+        cnt = count_keys(ring_ref, lambda k: k >= cand)
+        return jnp.where(cnt >= bud, cand, prefix)
+
+    return jax.lax.fori_loop(0, 32, body, jnp.uint32(0))
+
+
+def tie_rank(eq: jax.Array) -> jax.Array:
+    """Exclusive prefix count of a ``(1, n)`` bool row, as int32 —
+    a strict lower-triangular matmul (Mosaic has no cumsum)."""
+    n = eq.shape[-1]
+    r = jax.lax.broadcasted_iota(jnp.int32, (n, n), 0)
+    c = jax.lax.broadcasted_iota(jnp.int32, (n, n), 1)
+    before = (r < c).astype(jnp.float32)
+    prior = jax.lax.dot_general(eq.astype(jnp.float32), before, NN,
+                                precision=HIGHEST)
+    return prior.astype(jnp.int32)
 
 
 def _fused_kernel(bt_ref, len_ref, bud_ref,                 # scalar prefetch
@@ -83,6 +147,7 @@ def _fused_kernel(bt_ref, len_ref, bud_ref,                 # scalar prefetch
         eff_scr, m_scr, l_scr, acc_scr, thr_scr, ties_scr, cnt_scr = rest[1:]
 
     b = pl.program_id(0)
+    h = pl.program_id(1)
     phase = pl.program_id(2)
     i = pl.program_id(3)
     length = len_ref[b]
@@ -90,115 +155,60 @@ def _fused_kernel(bt_ref, len_ref, bud_ref,                 # scalar prefetch
     # ---- phase 0: score this page into the VMEM ring --------------------
     @pl.when(phase == 0)
     def _score():
-        words = bits_ref[0, 0]                    # (bs, W) uint32
-        bs, w = words.shape
-        shifts = jax.lax.broadcasted_iota(jnp.uint32, (1, 1, 32), 2)
-        bits = (words[:, :, None] >> shifts) & jnp.uint32(1)
-        signs = bits.reshape(bs, w * 32).astype(jnp.float32) * 2.0 - 1.0
-        signs = signs.reshape(bs, l_pad, num_planes)
+        # factorized soft-collision score (hard_lsh: collision count —
+        # u then holds the query's ±1 plane signs, 0 on padding tables)
+        scores = table_scores(
+            unpack_signs(bits_ref[0, 0]), u_ref[0, 0],
+            logz_ref[0, 0] if mode == "socket" else None,
+            num_planes=num_planes, tau=tau)       # (1, bs)
+        eff = scores * head_row(vnorm_ref, h)     # (1, bs)
 
-        u = u_ref[0, 0]                           # (GS, l_pad, P) f32
-        if mode == "socket":
-            logz = logz_ref[0, 0]                 # (GS, l_pad)
-            # factorized score, same reduction order as the XLA reference:
-            # exp(logits - logZ) summed over tables first, then the group
-            logits = jnp.einsum("nlp,glp->gnl", signs, u) / tau
-            z = jnp.exp(logits - logz[:, None, :])   # (GS, bs, l_pad)
-            scores = jnp.sum(jnp.sum(z, axis=-1), axis=0)       # (bs,)
-        else:                                     # hard_lsh
-            # u holds the query's ±1 plane signs (0 in the padded table
-            # slots, so agree < P there and padding never counts); a key
-            # collides in a table iff every plane sign agrees — the ±1
-            # inner product attains P exactly in that case.
-            agree = jnp.einsum("nlp,glp->gnl", signs, u)
-            hits = (agree >= jnp.float32(num_planes)).astype(jnp.float32)
-            scores = jnp.sum(jnp.sum(hits, axis=-1), axis=0)    # (bs,)
-        eff = scores * vnorm_ref[0, 0].astype(jnp.float32)
-
-        pos = (jax.lax.broadcasted_iota(jnp.int32, (bs, 1), 0).reshape(bs)
+        pos = (jax.lax.broadcasted_iota(jnp.int32, (1, block_size), 1)
                + i * block_size)
         forced = (pos < sink) | (pos >= length - window)
         eff = jnp.where(forced, jnp.float32(FLT_MAX), eff)
         eff = jnp.where(pos < length, eff, jnp.float32(NEG_INF))
-        eff_scr[i] = eff
-        if with_selection:
-            sel_ref[0, 0, 0] = jnp.zeros((sel_ref.shape[-1],), jnp.int32)
+        eff_scr[pl.ds(i, 1), :] = eff
 
     # ---- phase 1, first page: radix-select the budget threshold ---------
     @pl.when((phase == 1) & (i == 0))
     def _select():
-        keys = _sort_key(eff_scr[...])            # (nb, bs)
         bud = bud_ref[b]
-
-        def body(t, prefix):
-            shift = jnp.uint32(31) - t.astype(jnp.uint32)
-            cand = prefix | (jnp.uint32(1) << shift)
-            cnt = jnp.sum((keys >= cand).astype(jnp.int32))
-            return jnp.where(cnt >= bud, cand, prefix)
-
-        # largest T with count(keys >= T) >= budget == the budget-th
-        # largest key (attained), built MSB-first
-        thr = jax.lax.fori_loop(0, 32, body, jnp.uint32(0))
+        thr = radix_threshold(eff_scr, bud)
         thr_scr[0] = thr
-        ties_scr[0] = bud - jnp.sum((keys > thr).astype(jnp.int32))
+        ties_scr[0] = bud - count_keys(eff_scr, lambda k: k > thr)
         cnt_scr[0] = 0
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
+        init_softmax(m_scr, l_scr, acc_scr)
 
     # ---- phase 1: masked online-softmax over this K/V page --------------
     @pl.when(phase == 1)
     def _attend():
-        eff = eff_scr[i]                          # (bs,)
-        bs = eff.shape[0]
+        eff = eff_scr[pl.ds(i, 1), :]             # (1, bs)
         keys = _sort_key(eff)
         thr = thr_scr[0]
         gt = keys > thr
         eq = keys == thr
         # stable tie-break by index: position j takes a threshold tie iff
-        # (# earlier ties) < ties_needed.  Exclusive prefix count via a
-        # strict lower-triangular matmul (no cumsum primitive on Mosaic).
-        r = jax.lax.broadcasted_iota(jnp.int32, (bs, bs), 0)
-        c = jax.lax.broadcasted_iota(jnp.int32, (bs, bs), 1)
-        before = (r < c).astype(jnp.float32)
-        prior = jax.lax.dot_general(eq.astype(jnp.float32).reshape(1, bs),
-                                    before, (((1,), (0,)), ((), ())))
-        tie_rank = cnt_scr[0] + prior.reshape(bs).astype(jnp.int32)
-        sel = gt | (eq & (tie_rank < ties_scr[0]))
+        # (# earlier ties) < ties_needed
+        rank = cnt_scr[0] + tie_rank(eq)
+        sel = gt | (eq & (rank < ties_scr[0]))
         sel = sel & (eff > jnp.float32(NEG_INF / 2))
         cnt_scr[0] = cnt_scr[0] + jnp.sum(eq.astype(jnp.int32))
         if with_selection:
-            sel_ref[0, 0, 0] = sel.astype(jnp.int32)
+            sel_ref[0, 0, pl.ds(i, 1), :] = sel.astype(jnp.int32)
 
-        q = q_ref[0, 0].astype(jnp.float32)       # (G, hd)
-        k = k_ref[0, 0].astype(jnp.float32)       # (bs, hd)
-        v = v_ref[0, 0].astype(jnp.float32)
-        if quantized:
-            # int8/fp8 pool pages: per-row absmax scales ride along as
-            # (bs,) leaves — dequantize in-register, never in HBM.
-            k = k * ks_ref[0, 0].astype(jnp.float32)[:, None]
-            v = v * vs_ref[0, 0].astype(jnp.float32)[:, None]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ()))) * scale
-        s = jnp.where(sel[None, :], s, NEG_INF)   # (G, bs)
-
-        m_prev = m_scr[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new[:, None])
-        p = jnp.where(sel[None, :], p, 0.0)
-        l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=-1)
-        acc_scr[...] = acc_scr[...] * alpha[:, None] + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())))
-        m_scr[...] = m_new
+        fold_page(q_ref[0, 0].astype(jnp.float32), k_ref[0, 0], v_ref[0, 0],
+                  sel, m_scr, l_scr, acc_scr, scale=scale,
+                  k_scale=head_row(ks_ref, h) if quantized else None,
+                  v_scale=head_row(vs_ref, h) if quantized else None)
 
         @pl.when(i == num_seq_blocks - 1)
         def _done():
-            out_ref[0, 0] = (acc_scr[...] /
-                             jnp.maximum(l_scr[...], 1e-30)[:, None]
-                             ).astype(out_ref.dtype)
+            out_ref[0, 0] = finish_softmax(l_scr, acc_scr).astype(
+                out_ref.dtype)
 
 
-def _fused_call(kernel, q, bits_pages, vnorm_pages, u_pad, logz_pad,
+def _fused_call(kernel, q, bits_pages, vnorm_pages, u_flat, logz_pad,
                 k_pages, v_pages, block_table, length, budget, *,
                 with_selection: bool, interpret: bool,
                 k_scale=None, v_scale=None):
@@ -206,49 +216,58 @@ def _fused_call(kernel, q, bits_pages, vnorm_pages, u_pad, logz_pad,
     two-phase (score, attend) grid with dual scalar-prefetch index maps
     and the VMEM score ring + online-softmax scratch layout.
 
+    ``u_flat`` is the query hash ``(B, KVH, GS, l_pad * P)`` (tables
+    padded to ``l_pad``, flattened table-major like the packed bits).
     ``k_scale``/``v_scale`` (NB, KVH, bs) ride along as extra attend-phase
     page streams when the K/V pool is quantized (int8/fp8 storage)."""
     b, kvh, g, hd = q.shape
     bs, w = bits_pages.shape[2], bits_pages.shape[3]
     nb = block_table.shape[1]
-    gs, l_pad, num_planes = u_pad.shape[2:]
+    gs, nbits = u_flat.shape[2:]
+    l_pad = logz_pad.shape[-1]
 
     # K/V pages are pinned to bt[b, 0] during the score phase (and
     # bits/vnorm during the attend phase) so the revisiting pipeline
     # fetches each leaf once per page, not once per phase.
+    def score_page(b, h, ph, i, bt, ln, bd):
+        return bt[b, i * (1 - ph)]
+
+    def attend_page(b, h, ph, i, bt, ln, bd):
+        return bt[b, i * ph]
+
     in_specs = [
         pl.BlockSpec((1, 1, g, hd), lambda b, h, ph, i, *s: (b, h, 0, 0)),
         pl.BlockSpec((1, 1, bs, w),
-                     lambda b, h, ph, i, bt, ln, bd: (bt[b, i * (1 - ph)],
-                                                      h, 0, 0)),
-        pl.BlockSpec((1, 1, bs),
-                     lambda b, h, ph, i, bt, ln, bd: (bt[b, i * (1 - ph)],
-                                                      h, 0)),
-        pl.BlockSpec((1, 1, gs, l_pad, num_planes),
-                     lambda b, h, ph, i, *s: (b, h, 0, 0, 0)),
+                     lambda b, h, *a: (score_page(b, h, *a), h, 0, 0)),
+        pl.BlockSpec((1, kvh, bs),
+                     lambda b, h, *a: (score_page(b, h, *a), 0, 0)),
+        pl.BlockSpec((1, 1, gs, nbits),
+                     lambda b, h, ph, i, *s: (b, h, 0, 0)),
         pl.BlockSpec((1, 1, gs, l_pad),
                      lambda b, h, ph, i, *s: (b, h, 0, 0)),
         pl.BlockSpec((1, 1, bs, hd),
-                     lambda b, h, ph, i, bt, ln, bd: (bt[b, i * ph], h, 0, 0)),
+                     lambda b, h, *a: (attend_page(b, h, *a), h, 0, 0)),
         pl.BlockSpec((1, 1, bs, hd),
-                     lambda b, h, ph, i, bt, ln, bd: (bt[b, i * ph], h, 0, 0)),
+                     lambda b, h, *a: (attend_page(b, h, *a), h, 0, 0)),
     ]
-    operands = [q, bits_pages, vnorm_pages, u_pad, logz_pad,
+    operands = [q, bits_pages, vnorm_pages, u_flat, logz_pad,
                 k_pages, v_pages]
     if k_scale is not None:
         # per-row dequant scales stream with the K/V pages (attend phase)
         for _ in range(2):
             in_specs.append(pl.BlockSpec(
-                (1, 1, bs),
-                lambda b, h, ph, i, bt, ln, bd: (bt[b, i * ph], h, 0)))
+                (1, kvh, bs),
+                lambda b, h, *a: (attend_page(b, h, *a), 0, 0)))
         operands += [k_scale, v_scale]
     out_shape = [jax.ShapeDtypeStruct((b, kvh, g, hd), jnp.float32)]
     out_specs = [pl.BlockSpec((1, 1, g, hd),
                               lambda b, h, ph, i, *s: (b, h, 0, 0))]
     if with_selection:
+        # the whole (nb, bs) mask of one (request, head) stays resident
+        # and is written row by row in the attend phase
         out_shape.append(jax.ShapeDtypeStruct((b, kvh, nb, bs), jnp.int32))
-        out_specs.append(pl.BlockSpec((1, 1, 1, bs),
-                                      lambda b, h, ph, i, *s: (b, h, i, 0)))
+        out_specs.append(pl.BlockSpec((1, 1, nb, bs),
+                                      lambda b, h, ph, i, *s: (b, h, 0, 0)))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
@@ -257,8 +276,8 @@ def _fused_call(kernel, q, bits_pages, vnorm_pages, u_pad, logz_pad,
         out_specs=out_specs,
         scratch_shapes=[
             pltpu.VMEM((nb, bs), jnp.float32),    # eff score ring
-            pltpu.VMEM((g,), jnp.float32),        # m
-            pltpu.VMEM((g,), jnp.float32),        # l
+            pltpu.VMEM((g, 1), jnp.float32),      # m
+            pltpu.VMEM((g, 1), jnp.float32),      # l
             pltpu.VMEM((g, hd), jnp.float32),     # acc
             pltpu.SMEM((1,), jnp.uint32),         # threshold key
             pltpu.SMEM((1,), jnp.int32),          # ties still to take
@@ -280,7 +299,7 @@ def paged_attention_pallas(q: jax.Array, k_pages: jax.Array,
                            budget: jax.Array, *, num_tables: int,
                            num_planes: int, tau: float, scale: float,
                            sink_tokens: int, window_tokens: int,
-                           interpret: bool = True,
+                           interpret: bool,
                            with_selection: bool = False,
                            k_scale: Optional[jax.Array] = None,
                            v_scale: Optional[jax.Array] = None):
@@ -332,7 +351,8 @@ def paged_attention_pallas(q: jax.Array, k_pages: jax.Array,
         scale=float(scale), sink=int(sink_tokens), window=int(window_tokens),
         block_size=bs, num_seq_blocks=nb, with_selection=with_selection,
         mode="socket", quantized=k_scale is not None)
-    return _fused_call(kernel, q, bits_pages, vnorm_pages, u_pad, logz_pad,
+    return _fused_call(kernel, q, bits_pages, vnorm_pages,
+                       u_pad.reshape(b, kvh, gs, l_pad * p), logz_pad,
                        k_pages, v_pages, block_table, length, budget,
                        with_selection=with_selection, interpret=interpret,
                        k_scale=k_scale, v_scale=v_scale)

@@ -49,7 +49,7 @@ def paged_hard_lsh_pallas(q: jax.Array, k_pages: jax.Array,
                           budget: jax.Array, *, num_tables: int,
                           num_planes: int, scale: float,
                           sink_tokens: int, window_tokens: int,
-                          interpret: bool = True,
+                          interpret: bool,
                           with_selection: bool = False,
                           k_scale=None, v_scale=None):
     """Launch the fused hard-LSH kernel.
@@ -72,7 +72,7 @@ def paged_hard_lsh_pallas(q: jax.Array, k_pages: jax.Array,
     """
     bs, w = bits_pages.shape[2], bits_pages.shape[3]
     nb = block_table.shape[1]
-    _, _, gs, l, p = u_signs.shape
+    b, kvh, gs, l, p = u_signs.shape
     if l != num_tables or p != num_planes:
         raise ValueError("u_signs shape mismatch")
     if (w * 32) % num_planes:
@@ -97,7 +97,8 @@ def paged_hard_lsh_pallas(q: jax.Array, k_pages: jax.Array,
         window=int(window_tokens), block_size=bs, num_seq_blocks=nb,
         with_selection=with_selection, mode="hard_lsh",
         quantized=k_scale is not None)
-    return _fused_call(kernel, q, bits_pages, vnorm_pages, u_pad, logz_pad,
+    return _fused_call(kernel, q, bits_pages, vnorm_pages,
+                       u_pad.reshape(b, kvh, gs, l_pad * p), logz_pad,
                        k_pages, v_pages, block_table, length, budget,
                        with_selection=with_selection, interpret=interpret,
                        k_scale=k_scale, v_scale=v_scale)

@@ -37,8 +37,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.common import (NEG_INF, NN, finish_softmax, fold_page,
+                                  head_row, init_softmax)
 from repro.kernels.paged_attention.paged_attention import (
-    FLT_MAX, NEG_INF, _sort_key)
+    FLT_MAX, _sort_key, count_keys, radix_threshold, tie_rank)
 
 __all__ = ["paged_quest_pallas"]
 
@@ -59,6 +61,7 @@ def _quest_kernel(bt_ref, len_ref, bud_ref,                 # scalar prefetch
         eff_scr, m_scr, l_scr, acc_scr, thr_scr, ties_scr, cnt_scr = rest[1:]
 
     b = pl.program_id(0)
+    h = pl.program_id(1)
     phase = pl.program_id(2)
     i = pl.program_id(3)
     length = len_ref[b]
@@ -81,89 +84,54 @@ def _quest_kernel(bt_ref, len_ref, bud_ref,                 # scalar prefetch
             (page_start >= length - window - page_size)
         eff = jnp.where(forced, jnp.float32(FLT_MAX), scores)
         eff = jnp.where(page_start < length, eff, jnp.float32(NEG_INF))
-        eff_scr[i] = eff
-        if with_selection:
-            sel_ref[0, 0, 0] = jnp.zeros((sel_ref.shape[-1],), jnp.int32)
+        eff_scr[pl.ds(i, 1), :] = eff.reshape(1, ppb)
 
     # ---- phase 1, first block: radix-select the page-budget threshold ---
     @pl.when((phase == 1) & (i == 0))
     def _select():
-        keys = _sort_key(eff_scr[...])            # (nb, ppb)
         bud = bud_ref[b]
-
-        def body(t, prefix):
-            shift = jnp.uint32(31) - t.astype(jnp.uint32)
-            cand = prefix | (jnp.uint32(1) << shift)
-            cnt = jnp.sum((keys >= cand).astype(jnp.int32))
-            return jnp.where(cnt >= bud, cand, prefix)
-
-        thr = jax.lax.fori_loop(0, 32, body, jnp.uint32(0))
+        thr = radix_threshold(eff_scr, bud)
         thr_scr[0] = thr
-        ties_scr[0] = bud - jnp.sum((keys > thr).astype(jnp.int32))
+        ties_scr[0] = bud - count_keys(eff_scr, lambda k: k > thr)
         cnt_scr[0] = 0
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
+        init_softmax(m_scr, l_scr, acc_scr)
 
     # ---- phase 1: masked online-softmax over this K/V block -------------
     @pl.when(phase == 1)
     def _attend():
-        eff = eff_scr[i]                          # (ppb,)
-        keys = _sort_key(eff)
+        keys = _sort_key(eff_scr[pl.ds(i, 1), :])  # (1, ppb)
         thr = thr_scr[0]
         gt = keys > thr
         eq = keys == thr
-        r = jax.lax.broadcasted_iota(jnp.int32, (ppb, ppb), 0)
-        c = jax.lax.broadcasted_iota(jnp.int32, (ppb, ppb), 1)
-        before = (r < c).astype(jnp.float32)
-        prior = jax.lax.dot_general(eq.astype(jnp.float32).reshape(1, ppb),
-                                    before, (((1,), (0,)), ((), ())))
-        tie_rank = cnt_scr[0] + prior.reshape(ppb).astype(jnp.int32)
-        sel_page = gt | (eq & (tie_rank < ties_scr[0]))
+        rank = cnt_scr[0] + tie_rank(eq)
+        sel_page = gt | (eq & (rank < ties_scr[0]))
         cnt_scr[0] = cnt_scr[0] + jnp.sum(eq.astype(jnp.int32))
 
         # expand the page mask to rows via a one-hot matmul (row r belongs
         # to local page r // page_size) — reshape-free for Mosaic
-        rr = jax.lax.broadcasted_iota(jnp.int32, (block_size, ppb), 0)
-        cc = jax.lax.broadcasted_iota(jnp.int32, (block_size, ppb), 1)
+        cc = jax.lax.broadcasted_iota(jnp.int32, (ppb, block_size), 0)
+        rr = jax.lax.broadcasted_iota(jnp.int32, (ppb, block_size), 1)
         expand = ((rr // page_size) == cc).astype(jnp.float32)
-        row_sel = jax.lax.dot_general(
-            expand, sel_page.astype(jnp.float32).reshape(ppb, 1),
-            (((1,), (0,)), ((), ()))).reshape(block_size) > 0.5
-        pos = (jax.lax.broadcasted_iota(jnp.int32, (block_size, 1), 0)
-               .reshape(block_size) + i * block_size)
+        row_sel = jax.lax.dot_general(sel_page.astype(jnp.float32), expand,
+                                      NN) > 0.5                # (1, bs)
+        pos = (jax.lax.broadcasted_iota(jnp.int32, (1, block_size), 1)
+               + i * block_size)
         sel = row_sel & (pos < length)
         if with_selection:
-            sel_ref[0, 0, 0] = sel.astype(jnp.int32)
+            sel_ref[0, 0, pl.ds(i, 1), :] = sel.astype(jnp.int32)
 
-        q = q_ref[0, 0].astype(jnp.float32)       # (G, hd)
-        k = k_ref[0, 0].astype(jnp.float32)       # (bs, hd)
-        v = v_ref[0, 0].astype(jnp.float32)
-        if quantized:
-            # int8/fp8 pool pages: per-row absmax scales ride along as
-            # (bs,) leaves — dequantize in-register, never in HBM.  The
-            # kmin/kmax stats already bound the *dequantized* keys
-            # (cfg.quest.stats_from_quantized), so scoring is untouched.
-            k = k * ks_ref[0, 0].astype(jnp.float32)[:, None]
-            v = v * vs_ref[0, 0].astype(jnp.float32)[:, None]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ()))) * scale
-        s = jnp.where(sel[None, :], s, NEG_INF)   # (G, bs)
-
-        m_prev = m_scr[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new[:, None])
-        p = jnp.where(sel[None, :], p, 0.0)
-        l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=-1)
-        acc_scr[...] = acc_scr[...] * alpha[:, None] + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())))
-        m_scr[...] = m_new
+        # quantized pages: the kmin/kmax stats already bound the
+        # *dequantized* keys (cfg.quest.stats_from_quantized), so scoring
+        # is untouched by the in-register dequant
+        fold_page(q_ref[0, 0].astype(jnp.float32), k_ref[0, 0], v_ref[0, 0],
+                  sel, m_scr, l_scr, acc_scr, scale=scale,
+                  k_scale=head_row(ks_ref, h) if quantized else None,
+                  v_scale=head_row(vs_ref, h) if quantized else None)
 
         @pl.when(i == num_seq_blocks - 1)
         def _done():
-            out_ref[0, 0] = (acc_scr[...] /
-                             jnp.maximum(l_scr[...], 1e-30)[:, None]
-                             ).astype(out_ref.dtype)
+            out_ref[0, 0] = finish_softmax(l_scr, acc_scr).astype(
+                out_ref.dtype)
 
 
 def paged_quest_pallas(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
@@ -171,7 +139,7 @@ def paged_quest_pallas(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
                        block_table: jax.Array, length: jax.Array,
                        page_budget: jax.Array, *, page_size: int,
                        scale: float, sink_tokens: int, window_tokens: int,
-                       interpret: bool = True,
+                       interpret: bool,
                        with_selection: bool = False,
                        k_scale=None, v_scale=None):
     """Launch the fused Quest kernel.
@@ -232,16 +200,18 @@ def paged_quest_pallas(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
         # per-row dequant scales stream with the K/V pages (attend phase)
         for _ in range(2):
             in_specs.append(pl.BlockSpec(
-                (1, 1, bs),
-                lambda b, h, ph, i, bt, ln, bd: (bt[b, i * ph], h, 0)))
+                (1, kvh, bs),
+                lambda b, h, ph, i, bt, ln, bd: (bt[b, i * ph], 0, 0)))
         operands += [k_scale, v_scale]
     out_shape = [jax.ShapeDtypeStruct((b, kvh, g, hd), jnp.float32)]
     out_specs = [pl.BlockSpec((1, 1, g, hd),
                               lambda b, h, ph, i, *s: (b, h, 0, 0))]
     if with_selection:
         out_shape.append(jax.ShapeDtypeStruct((b, kvh, nb, bs), jnp.int32))
-        out_specs.append(pl.BlockSpec((1, 1, 1, bs),
-                                      lambda b, h, ph, i, *s: (b, h, i, 0)))
+        # the whole (nb, bs) mask of one (request, head) stays resident
+        # and is written row by row in the attend phase
+        out_specs.append(pl.BlockSpec((1, 1, nb, bs),
+                                      lambda b, h, ph, i, *s: (b, h, 0, 0)))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
@@ -250,8 +220,8 @@ def paged_quest_pallas(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
         out_specs=out_specs,
         scratch_shapes=[
             pltpu.VMEM((nb, ppb), jnp.float32),   # page-score ring
-            pltpu.VMEM((g,), jnp.float32),        # m
-            pltpu.VMEM((g,), jnp.float32),        # l
+            pltpu.VMEM((g, 1), jnp.float32),      # m
+            pltpu.VMEM((g, 1), jnp.float32),      # l
             pltpu.VMEM((g, hd), jnp.float32),     # acc
             pltpu.SMEM((1,), jnp.uint32),         # threshold key
             pltpu.SMEM((1,), jnp.int32),          # ties still to take

@@ -34,7 +34,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.paged_attention.paged_attention import NEG_INF
+from repro.kernels.common import (finish_softmax, fold_page, head_row,
+                                  init_softmax)
 
 __all__ = ["paged_ring_pallas"]
 
@@ -48,55 +49,36 @@ def _ring_kernel(bt_ref, pos_ref,                           # scalar prefetch
         rest = rest[2:]
     out_ref, m_scr, l_scr, acc_scr = rest
     b = pl.program_id(0)
+    h = pl.program_id(1)
     i = pl.program_id(2)
     pos = pos_ref[b]
     cap = ring_blocks * block_size
 
     @pl.when(i == 0)
     def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
+        init_softmax(m_scr, l_scr, acc_scr)
 
-    slot = (jax.lax.broadcasted_iota(jnp.int32, (block_size, 1), 0)
-            .reshape(block_size) + i * block_size)
+    slot = jax.lax.broadcasted_iota(jnp.int32, (1, block_size), 1) \
+        + i * block_size
     ring_pos = pos - ((pos - slot) % cap)
-    valid = (ring_pos >= 0) & (pos - ring_pos < window)
+    valid = (ring_pos >= 0) & (pos - ring_pos < window)      # (1, bs)
 
-    q = q_ref[0, 0].astype(jnp.float32)           # (G, hd)
-    k = k_ref[0, 0].astype(jnp.float32)           # (bs, hd)
-    v = v_ref[0, 0].astype(jnp.float32)
-    if quantized:
-        # int8/fp8 ring pages: per-row absmax scales ride along as (bs,)
-        # leaves — dequantize in-register, never in HBM.
-        k = k * ks_ref[0, 0].astype(jnp.float32)[:, None]
-        v = v * vs_ref[0, 0].astype(jnp.float32)[:, None]
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ()))) * scale
-    if softcap:                                   # static no-op at 0.0
-        s = softcap * jnp.tanh(s / softcap)
-    s = jnp.where(valid[None, :], s, NEG_INF)     # (G, bs)
-
-    m_prev = m_scr[...]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-    alpha = jnp.exp(m_prev - m_new)
-    p = jnp.exp(s - m_new[:, None])
-    p = jnp.where(valid[None, :], p, 0.0)
-    l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=-1)
-    acc_scr[...] = acc_scr[...] * alpha[:, None] + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())))
-    m_scr[...] = m_new
+    # int8/fp8 ring pages: per-row absmax scales ride along as (bs,)
+    # leaves — dequantized in-register, never in HBM
+    fold_page(q_ref[0, 0].astype(jnp.float32), k_ref[0, 0], v_ref[0, 0],
+              valid, m_scr, l_scr, acc_scr, scale=scale, softcap=softcap,
+              k_scale=head_row(ks_ref, h) if quantized else None,
+              v_scale=head_row(vs_ref, h) if quantized else None)
 
     @pl.when(i == ring_blocks - 1)
     def _done():
-        out_ref[0, 0] = (acc_scr[...] /
-                         jnp.maximum(l_scr[...], 1e-30)[:, None]
-                         ).astype(out_ref.dtype)
+        out_ref[0, 0] = finish_softmax(l_scr, acc_scr).astype(out_ref.dtype)
 
 
 def paged_ring_pallas(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
                       block_table: jax.Array, pos: jax.Array, *,
                       window: int, softcap: float, scale: float,
-                      interpret: bool = True, k_scale=None, v_scale=None):
+                      interpret: bool, k_scale=None, v_scale=None):
     """Launch the ring decode kernel.
 
     Args:
@@ -139,7 +121,7 @@ def paged_ring_pallas(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
         # per-row dequant scales stream with the K/V pages
         for _ in range(2):
             in_specs.append(pl.BlockSpec(
-                (1, 1, bs), lambda b, h, i, bt, ps: (bt[b, i], h, 0)))
+                (1, kvh, bs), lambda b, h, i, bt, ps: (bt[b, i], 0, 0)))
         operands += [k_scale, v_scale]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -148,8 +130,8 @@ def paged_ring_pallas(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, 1, g, hd), lambda b, h, i, *s: (b, h, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((g,), jnp.float32),        # m
-            pltpu.VMEM((g,), jnp.float32),        # l
+            pltpu.VMEM((g, 1), jnp.float32),      # m
+            pltpu.VMEM((g, 1), jnp.float32),      # l
             pltpu.VMEM((g, hd), jnp.float32),     # acc
         ],
     )

@@ -1,8 +1,9 @@
 """Jitted public wrapper for the SOCKET scoring kernel.
 
 Accepts the model's natural layouts and flattens to the kernel's (BH, ...)
-convention; on non-TPU backends runs the Pallas kernel in interpret mode
-(bit-exact semantics) — set ``interpret=False`` on real TPU.
+convention.  ``interpret=None`` (the default) compiles with Mosaic on a
+TPU backend and interprets elsewhere
+(:func:`repro.kernels.common.resolve_interpret`).
 """
 
 from __future__ import annotations
@@ -13,12 +14,9 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.common import resolve_interpret
 from repro.kernels.socket_score.socket_score import (DEFAULT_BLOCK_N,
                                                      socket_score_pallas)
-
-
-def _auto_interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 @functools.partial(jax.jit, static_argnames=("num_tables", "num_planes",
@@ -44,7 +42,7 @@ def socket_score(bits: jax.Array, u: jax.Array,
 
     Returns scores f32 matching the leading layout: (B, KVH, N) / (BH, N).
     """
-    interpret = _auto_interpret() if interpret is None else interpret
+    interpret = resolve_interpret(interpret)
     squeeze = False
     if bits.ndim == 4:
         b, kvh, n, w = bits.shape

@@ -15,19 +15,20 @@ what makes sparse decode profitable at long context on TPU v5e
 (819 GB/s HBM).
 
 Tiling: grid = (BH, N // block_n).  Per step the kernel holds
-  bits  (block_n, W)   uint32   — block_n=512, W=20 → 40 KiB
-  u     (G, L, P)      f32      — ≤ 8·64·16·4 = 32 KiB   (VMEM resident)
-  logz  (G, L)         f32
-  vnorm (block_n,)     f32
-  out   (block_n,)     f32
-comfortably inside VMEM.  The contraction (block_n, L, P) x (G, L, P) is
-vector-unit work (P is far below the 128-lane MXU contraction width; see
-EXPERIMENTS.md §Perf for the measured compute/memory balance and the
-pooled-query G=1 operating point that keeps the kernel memory-bound).
+  bits  (block_n, W)     uint32   — block_n=512, W=20 → 40 KiB
+  u     (G, L_pad * P)   f32      (VMEM resident)
+  logz  (G, L_pad)       f32
+  vnorm (1, block_n)     f32
+  out   (1, block_n)     f32
+comfortably inside VMEM.  vnorm and the output travel as ``(BH, 1, N)``
+rows so every block is tile-legal for Mosaic.
 
-The unpack exploits that ``W*32`` is a multiple of 128 (W=20 → 640 lanes):
-tables are processed in a (L_pad, P) view with L padded to W*32/P and the
-padding neutralised via logZ = +inf (=> exp(-inf) = 0 contribution).
+Tables are processed in a padded ``L_pad = W*32/P`` view with the
+padding neutralised via logZ = +1e30 (=> exp(-1e30) = 0 contribution).
+The per-table plane dot is ``(signs * u) @ seg`` with the 0/1 segment
+matrix ``seg[k, l] = (k // P == l)`` (``kernels.common.table_scores``),
+so the unpacked ``(block_n, W*32)`` signs are never reshaped across
+lanes.
 """
 
 from __future__ import annotations
@@ -39,41 +40,21 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.common import table_scores, unpack_signs
+
 DEFAULT_BLOCK_N = 512
 
 
 def _score_kernel(bits_ref, u_ref, logz_ref, vnorm_ref, out_ref, *,
-                  num_planes: int, l_pad: int, tau: float,
-                  bits_format: str = "packed"):
+                  num_planes: int, tau: float, bits_format: str = "packed"):
     """One (bh, n-block) tile."""
     if bits_format == "packed":
-        words = bits_ref[0]                      # (block_n, W) uint32
-        block_n, w = words.shape
-
-        # ---- unpack W uint32 words -> (block_n, W*32) ±1 float32 --------
-        shifts = jax.lax.broadcasted_iota(jnp.uint32, (1, 1, 32), 2)
-        bits = (words[:, :, None] >> shifts) & jnp.uint32(1)
-        signs = bits.reshape(block_n, w * 32).astype(jnp.float32) * 2.0 - 1.0
-        # padded-table view: (block_n, L_pad, P); pad tables contribute 0
-        # via logz = +inf supplied by the wrapper.
-        signs = signs.reshape(block_n, l_pad, num_planes)
+        signs = unpack_signs(bits_ref[0])        # (block_n, W*32) ±1
     else:                                        # "int8": ±1 plane bytes
-        planes = bits_ref[0]                     # (block_n, L*P) int8
-        block_n = planes.shape[0]
-        signs = planes.astype(jnp.float32).reshape(block_n, l_pad,
-                                                   num_planes)
-
-    u = u_ref[0]                                 # (G, L_pad, P) f32
-    logz = logz_ref[0]                           # (G, L_pad)
-    g = u.shape[0]
-
-    # ---- per-table logits + exp + reduce --------------------------------
-    # (block_n, 1, L_pad, P) * (1, G, L_pad, P) -> sum over P
-    prod = signs[:, None] * u[None]              # (block_n, G, L_pad, P)
-    logits = jnp.sum(prod, axis=-1) / tau        # (block_n, G, L_pad)
-    z = jnp.exp(logits - logz[None])             # (block_n, G, L_pad)
-    scores = jnp.sum(z, axis=(1, 2))             # (block_n,)
-
+        signs = bits_ref[0].astype(jnp.int32).astype(jnp.float32)
+    # (1, block_n); padding tables contribute 0 via logz = +1e30
+    scores = table_scores(signs, u_ref[0], logz_ref[0],
+                          num_planes=num_planes, tau=tau)
     out_ref[0] = scores * vnorm_ref[0]
 
 
@@ -81,7 +62,7 @@ def socket_score_pallas(bits: jax.Array, u: jax.Array,
                         vnorm: Optional[jax.Array], *, num_tables: int,
                         num_planes: int, tau: float,
                         block_n: int = DEFAULT_BLOCK_N,
-                        interpret: bool = True) -> jax.Array:
+                        interpret: bool) -> jax.Array:
     """Launch the scoring kernel.
 
     Args:
@@ -127,19 +108,22 @@ def socket_score_pallas(bits: jax.Array, u: jax.Array,
     if n % block_n:
         raise ValueError(f"N={n} not a multiple of block_n={block_n}")
 
+    # (BH, 1, N) rows: a (1, block_n) block of a (BH, N) array would put
+    # a 1 against BH on the sublane axis, which Mosaic refuses
     kernel = functools.partial(_score_kernel, num_planes=num_planes,
-                               l_pad=l_pad, tau=float(tau),
-                               bits_format=bits_format)
-    return pl.pallas_call(
+                               tau=float(tau), bits_format=bits_format)
+    nbits = l_pad * num_planes
+    out = pl.pallas_call(
         kernel,
         grid=(bh, n // block_n),
         in_specs=[
             pl.BlockSpec((1, block_n, w), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, g, l_pad, num_planes), lambda b, i: (b, 0, 0, 0)),
+            pl.BlockSpec((1, g, nbits), lambda b, i: (b, 0, 0)),
             pl.BlockSpec((1, g, l_pad), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, block_n), lambda b, i: (b, i)),
+            pl.BlockSpec((1, 1, block_n), lambda b, i: (b, 0, i)),
         ],
-        out_specs=pl.BlockSpec((1, block_n), lambda b, i: (b, i)),
-        out_shape=jax.ShapeDtypeStruct((bh, n), jnp.float32),
+        out_specs=pl.BlockSpec((1, 1, block_n), lambda b, i: (b, 0, i)),
+        out_shape=jax.ShapeDtypeStruct((bh, 1, n), jnp.float32),
         interpret=interpret,
-    )(bits, u_pad, logz_pad, vnorm)
+    )(bits, u_pad.reshape(bh, g, nbits), logz_pad, vnorm.reshape(bh, 1, n))
+    return out.reshape(bh, n)

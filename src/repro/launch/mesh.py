@@ -9,6 +9,7 @@ initialisation, and smoke tests must keep seeing 1 device.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 __all__ = ["make_production_mesh", "make_test_mesh"]
 
@@ -23,9 +24,16 @@ def make_production_mesh(*, multi_pod: bool = False):
     """
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
+
+
+def _auto_mesh(shape, axes):
+    """``jax.make_mesh`` with every axis ``Auto``: the model places
+    arrays through ``NamedSharding``/``with_sharding_constraint``, which
+    an ``Explicit`` axis (the make_mesh default) refuses."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_test_mesh(data: int = 2, model: int = 2):
     """Small mesh for subprocess-based distribution tests (8 host devices)."""
-    return jax.make_mesh((data, model), ("data", "model"))
+    return _auto_mesh((data, model), ("data", "model"))

@@ -19,7 +19,9 @@ Two engines:
 from __future__ import annotations
 
 import argparse
+import os
 import time
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -76,6 +78,23 @@ def apply_kv_dtype(cfg, kv_dtype):
     if kv_dtype not in KV_DTYPES:
         raise ValueError(f"kv_dtype={kv_dtype!r} not in {KV_DTYPES}")
     return cfg.replace(serving=cfg.serving.replace(kv_dtype=kv_dtype))
+
+
+def configure_compile_cache(repo_root: Path) -> str:
+    """Persistent compile cache location for an entry point's process.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and
+    nothing is overridden.  Otherwise the cache goes to the fixed
+    ``<repo_root>/.jax_cache`` (git-ignored): the directory is part of
+    the cache key, so a path built from a temporary name, a process id
+    or the time would never hit.  Call from ``main()``, never on import.
+    Returns the directory in use."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = str(Path(repo_root).resolve() / ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def run_serve(cfg, batch: int, prompt_len: int, decode_steps: int,
@@ -159,7 +178,7 @@ def run_continuous(cfg, num_requests: int, rate_rps: float, prompt_lens,
                    max_new_tokens: int, seed: int = 0, realtime=True,
                    warmup=False, temperature: float = 0.0,
                    top_p: float = 1.0, arrivals=None, obs=None,
-                   prompts=None):
+                   prompts=None, params=None):
     """Continuous-batching serve; returns (requests, ServeMetrics,
     engine) — the engine exposes the run's metrics registry
     (``engine.registry``) for snapshot / Prometheus exposition.
@@ -178,9 +197,12 @@ def run_continuous(cfg, num_requests: int, rate_rps: float, prompt_lens,
     :mod:`repro.serving.prefix_cache.workloads`) overriding the random
     draw — the prefix-cache workloads need real shared prefixes, which
     independent random prompts never have; ``prompt_lens`` is ignored.
+    ``params``: optional model weights (default: initialized from
+    ``seed``), so several runs can share one copy on the device.
     """
     from repro.serving.engine import ContinuousBatchingEngine
-    engine = ContinuousBatchingEngine(cfg, rng=jax.random.PRNGKey(seed),
+    engine = ContinuousBatchingEngine(cfg, params=params,
+                                      rng=jax.random.PRNGKey(seed),
                                       temperature=temperature, top_p=top_p,
                                       sample_seed=seed, obs=obs)
     if prompts is not None:
@@ -296,6 +318,7 @@ def main():
                     help="profiled window length in engine iterations "
                          "(with --profile-dir)")
     args = ap.parse_args()
+    configure_compile_cache(Path(__file__).resolve().parents[3])
 
     if args.backend.endswith("_fused") and args.engine != "continuous":
         ap.error(f"--backend {args.backend} requires --engine continuous: "

@@ -236,12 +236,8 @@ def input_specs(arch: str, shape_name: str = "train_4k"):
 
     shape = SHAPES[shape_name]
     # AbstractMesh: the production 16x16 topology without touching device
-    # state (usable for divisibility-checked spec construction anywhere).
-    # jax >= 0.5 takes (sizes, names); 0.4.x takes ((name, size), ...)
-    try:
-        mesh = _jax.sharding.AbstractMesh((16, 16), ("data", "model"))
-    except TypeError:
-        mesh = _jax.sharding.AbstractMesh((("data", 16), ("model", 16)))
+    # state (usable for divisibility-checked spec construction anywhere)
+    mesh = _jax.sharding.AbstractMesh((16, 16), ("data", "model"))
     cfg = dryrun_model_config(get_config(arch), shape)
     rules = arch_rules(cfg, shape, mesh)
     if shape.kind in ("train", "prefill"):

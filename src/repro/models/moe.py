@@ -226,7 +226,6 @@ def _apply_moe_alltoall(cfg: ModelConfig, params: Dict, x: jax.Array,
     """
     from jax.sharding import PartitionSpec as P
 
-    from repro.distributed.sharding import shard_map
 
     b, t, d = x.shape
     e, k = cfg.num_experts, cfg.num_experts_per_tok
@@ -304,10 +303,10 @@ def _apply_moe_alltoall(cfg: ModelConfig, params: Dict, x: jax.Array,
                 P("model", None, None), P("model", None, None),
                 P("model", None, None))
     aux_spec = {"moe_lb_loss": P(), "moe_z_loss": P()}
-    fn = shard_map(body, mesh=mesh,
-                   in_specs=in_specs,
-                   out_specs=(P(bax, tax, None), aux_spec),
-                   check_vma=False)
+    fn = jax.shard_map(body, mesh=mesh,
+                       in_specs=in_specs,
+                       out_specs=(P(bax, tax, None), aux_spec),
+                       check_vma=False)
     return fn(x, params["router"].astype(jnp.float32),
               params["w_gate"].astype(cdt), params["w_up"].astype(cdt),
               params["w_down"].astype(cdt))

@@ -226,25 +226,6 @@ def _input_embed(cfg: ModelConfig, params, batch: Dict) -> jax.Array:
                "batch", "act_seq", "embed")
 
 
-@jax.custom_vjp
-def _fwd_barrier(x):
-    # optimization_barrier has no differentiation rule in this jax; the
-    # barrier is only needed on the forward carry (see group_body), so give
-    # it a pass-through gradient.
-    return jax.lax.optimization_barrier(x)
-
-
-def _fwd_barrier_fwd(x):
-    return _fwd_barrier(x), None
-
-
-def _fwd_barrier_bwd(_, g):
-    return (g,)
-
-
-_fwd_barrier.defvjp(_fwd_barrier_fwd, _fwd_barrier_bwd)
-
-
 def forward_train(cfg: ModelConfig, params, batch: Dict):
     """Full forward.  batch: {tokens|embeds, (positions)} -> (logits, aux)."""
     x = _input_embed(cfg, params, batch)
@@ -263,7 +244,7 @@ def forward_train(cfg: ModelConfig, params, batch: Dict):
         # barrier: stops XLA from hoisting the backward pass's f32 upcast
         # of the saved carry into the forward loop (which would materialize
         # a duplicate f32 residual stack — observed 2.5x temp blowup).
-        x = _fwd_barrier(x)
+        x = jax.lax.optimization_barrier(x)
         return (x, lb, zl), None
 
     body = _remat(cfg, group_body)

@@ -48,13 +48,12 @@ def test_compressed_psum_exact_and_error_feedback():
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
 from repro.distributed.compression import compressed_psum
-from repro.distributed.sharding import shard_map
 
 mesh = jax.make_mesh((8,), ("data",))
-f = shard_map(lambda g, e: compressed_psum({"w": g}, {"w": e}, "data"),
-              mesh=mesh, in_specs=(P("data", None), P("data", None)),
-              out_specs=({"w": P(None, None)}, {"w": P("data", None)}),
-              check_vma=False)
+f = jax.shard_map(lambda g, e: compressed_psum({"w": g}, {"w": e}, "data"),
+                  mesh=mesh, in_specs=(P("data", None), P("data", None)),
+                  out_specs=({"w": P(None, None)}, {"w": P("data", None)}),
+                  check_vma=False)
 g = jax.random.normal(jax.random.PRNGKey(0), (8, 128))
 exact = jnp.mean(g, axis=0)
 e = jnp.zeros((8, 128))
@@ -142,9 +141,10 @@ from repro.distributed import sharding as shd
 from repro.launch import specs as sp
 from repro.optim import AdamWConfig, init_adamw
 from repro.runtime.steps import make_train_step
+from repro.launch.mesh import make_test_mesh
 from repro.models import param as pm, transformer as tfm
 
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = make_test_mesh(4, 2)
 cfg = get_config("minitron-8b").smoke().replace(num_groups=1)
 ocfg = AdamWConfig()
 rules = {}
