@@ -513,7 +513,8 @@ def attention_decode(cfg: ModelConfig, params: Dict, x: jax.Array,
     ragged = jnp.ndim(pos) == 1
     positions = jnp.reshape(pos, (b, 1)).astype(jnp.int32) if ragged \
         else jnp.full((b, 1), pos, jnp.int32)
-    q, k_new, v_new = _project_qkv(cfg, params, x, positions)
+    with jax.named_scope("layer.proj"):
+        q, k_new, v_new = _project_qkv(cfg, params, x, positions)
     qg = jnp.transpose(q.reshape(b, 1, kv, g, hd), (0, 2, 3, 1, 4))
     # qg: (B, KV, G, 1, hd)
 
@@ -609,4 +610,5 @@ def attention_decode(cfg: ModelConfig, params: Dict, x: jax.Array,
         cache = view.arrays
 
     ctx = jnp.transpose(ctx, (0, 3, 1, 2, 4)).reshape(b, 1, h_eff, hd)
-    return _merge_heads(cfg, params, ctx.astype(x.dtype)), cache
+    with jax.named_scope("layer.proj"):
+        return _merge_heads(cfg, params, ctx.astype(x.dtype)), cache

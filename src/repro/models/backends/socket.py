@@ -76,6 +76,7 @@ class SocketBackend(base.DecodeBackend):
             side.vnorm.astype(cache["vnorm"].dtype))
         return cache
 
+    @jax.named_scope("socket.append")
     def append(self, cfg, params, view: KVView, kc, vc, pos):
         base.write_token_kv(cfg, view, pos, kc[:, :, 0], vc[:, :, 0])
         # side-cache from the ORIGINAL full-precision K/V: selection is
@@ -94,6 +95,7 @@ class SocketBackend(base.DecodeBackend):
                                       sk.topk_budget(scfg, n))
 
     @staticmethod
+    @jax.named_scope("socket.hash")
     def _soft_hash(scfg, params, q):
         """Query soft-hash for the selection mode: pooled hashes the
         group-mean query once per KV head ((B,KVH,L,P) — G x less scoring
@@ -104,6 +106,7 @@ class SocketBackend(base.DecodeBackend):
                                       jnp.mean(q[..., 0, :], axis=2))
         return sk.soft_hash_query(params["hash_w"], q[..., 0, :])
 
+    @jax.named_scope("socket.score")
     def _scores(self, cfg, params, q, view: KVView):
         """(soft-hash u, collision scores) for the selection mode."""
         scfg = socket_config_of(cfg)
@@ -132,6 +135,7 @@ class SocketBackend(base.DecodeBackend):
                 scores = jnp.sum(scores, axis=2)
         return scores
 
+    @jax.named_scope("socket.fused")
     def _attend_fused(self, cfg, params, q, view, *, length, scale, budget):
         """Fused paged path: one Pallas pass over the block table."""
         scfg = socket_config_of(cfg)
@@ -218,12 +222,13 @@ class SocketBackend(base.DecodeBackend):
                 batch_axes=cfg.decode_cp_batch_axes)
 
         scores = self._scores(cfg, params, q, view)
-        vnorm = view.leaf("vnorm").astype(jnp.float32)
         kq = sk.topk_budget(scfg, n)
         if scfg.selection in ("kvhead", "pooled"):
-            idx, sel_mask = sk.value_aware_topk(
-                scfg, scores, vnorm, k=kq, length=length, n_total=n,
-                budget=budget)
+            with jax.named_scope("socket.select"):
+                vnorm = view.leaf("vnorm").astype(jnp.float32)
+                idx, sel_mask = sk.value_aware_topk(
+                    scfg, scores, vnorm, k=kq, length=length, n_total=n,
+                    budget=budget)
             if bprobe.capturing():
                 # probe reference reads the DEQUANTIZED cached keys — the
                 # same values the attend phase sees, so recall measures
@@ -232,22 +237,30 @@ class SocketBackend(base.DecodeBackend):
                     scfg, q, base.dequant_leaf(cfg, view, "k"), vnorm,
                     idx, sel_mask, length=length, budget=budget,
                     static_k=kq, scale=scale))
-            k_sel, v_sel = base.gather_kv_rows(cfg, view, idx)
-            return base.subset_attention(cfg, q, k_sel, v_sel, sel_mask,
-                                         scale=scale)
+            with jax.named_scope("socket.gather"):
+                k_sel, v_sel = base.gather_kv_rows(cfg, view, idx)
+            with jax.named_scope("socket.attend"):
+                return base.subset_attention(cfg, q, k_sel, v_sel,
+                                             sel_mask, scale=scale)
         # per-q-head selection: fold G into the selection axis, gather per
         # (kvh, g).  More faithful to the paper's single-head exposition
         # but loses the shared KV gather (and the flash_decode layout).
-        idx, sel_mask = sk.value_aware_topk(
-            scfg, scores, vnorm[:, :, None], k=kq, length=length,
-            n_total=n, budget=budget)
-        k_sel, v_sel = base.gather_kv_rows(cfg, view, idx)  # (B,KVH,G,K,hd)
-        logits = jnp.einsum("bhgtd,bhgkd->bhgtk", q.astype(jnp.float32),
-                            k_sel.astype(jnp.float32)) * scale
-        logits = jnp.where(sel_mask[:, :, :, None, :], logits, sk.NEG_INF)
-        wts = jax.nn.softmax(logits, axis=-1)
-        out = jnp.einsum("bhgtk,bhgkd->bhgtd", wts,
-                         v_sel.astype(jnp.float32))
+        with jax.named_scope("socket.select"):
+            vnorm = view.leaf("vnorm").astype(jnp.float32)
+            idx, sel_mask = sk.value_aware_topk(
+                scfg, scores, vnorm[:, :, None], k=kq, length=length,
+                n_total=n, budget=budget)
+        with jax.named_scope("socket.gather"):
+            # (B,KVH,G,K,hd)
+            k_sel, v_sel = base.gather_kv_rows(cfg, view, idx)
+        with jax.named_scope("socket.attend"):
+            logits = jnp.einsum("bhgtd,bhgkd->bhgtk", q.astype(jnp.float32),
+                                k_sel.astype(jnp.float32)) * scale
+            logits = jnp.where(sel_mask[:, :, :, None, :], logits,
+                               sk.NEG_INF)
+            wts = jax.nn.softmax(logits, axis=-1)
+            out = jnp.einsum("bhgtk,bhgkd->bhgtd", wts,
+                             v_sel.astype(jnp.float32))
         return out.astype(q.dtype)
 
     # ---- accounting -----------------------------------------------------

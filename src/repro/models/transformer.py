@@ -132,20 +132,24 @@ def _block_prefill_chunk(cfg: ModelConfig, params: Dict, spec: LayerSpec,
 def _block_decode(cfg: ModelConfig, params: Dict, spec: LayerSpec,
                   x: jax.Array, cache: Dict, pos: jax.Array,
                   block_tables=None):
-    h = rmsnorm(params["norm_mix"], x)
+    with jax.named_scope("layer.proj"):
+        h = rmsnorm(params["norm_mix"], x)
     if spec.kind == "attn":
+        # the QKV/O projections and rope are scoped layer.proj inside
         h, cache = attn.attention_decode(cfg, params["attn"], h, cache, pos,
                                          spec.attn_type,
                                          block_tables=block_tables)
     else:
         h, cache = mb.mamba_decode(cfg, params["mamba"], h, cache)
     x = x + h
-    if spec.mlp == "dense":
-        x = x + apply_mlp(cfg, params["mlp"], rmsnorm(params["norm_mlp"], x))
-    elif spec.mlp == "moe":
-        y, _ = moe_mod.apply_moe(cfg, params["moe"],
-                                 rmsnorm(params["norm_mlp"], x))
-        x = x + y
+    with jax.named_scope("layer.mlp"):
+        if spec.mlp == "dense":
+            x = x + apply_mlp(cfg, params["mlp"],
+                              rmsnorm(params["norm_mlp"], x))
+        elif spec.mlp == "moe":
+            y, _ = moe_mod.apply_moe(cfg, params["moe"],
+                                     rmsnorm(params["norm_mlp"], x))
+            x = x + y
     return x, cache
 
 
@@ -454,8 +458,9 @@ def decode_step(cfg: ModelConfig, params, caches, inputs: jax.Array,
             cfg, params["remainder"][f"slot_{i}"], spec, x,
             caches["remainder"][f"slot_{i}"], pos, block_tables)
 
-    x = rmsnorm(params["final_norm"], x)
-    logits = lm_head(cfg, params["embed"], x)
+    with jax.named_scope("model.head"):
+        x = rmsnorm(params["final_norm"], x)
+        logits = lm_head(cfg, params["embed"], x)
     return logits, {"groups": new_group_caches, "remainder": new_rem}
 
 

@@ -52,7 +52,6 @@ composition never perturbs a request's randomness.
 
 from __future__ import annotations
 
-import contextlib
 import dataclasses
 import math
 import time
@@ -72,6 +71,7 @@ from repro.serving import paged, sampling
 from repro.serving.block_pool import TRASH_BLOCK, BlockPool
 from repro.serving.obs import Observability
 from repro.serving.obs.metrics import Registry
+from repro.serving.obs.profiling import null_span
 from repro.serving.prefix_cache import PrefixCache
 from repro.serving.scheduler import (PREFILL, PrefillChunk, Request,
                                      Scheduler)
@@ -89,6 +89,7 @@ class ServeMetrics:
     throughput_tok_s: float
     ttft_s_mean: float
     ttft_s_p99: float
+    # percentiles of the gaps between consecutive tokens of one request
     token_latency_s_p50: float
     token_latency_s_p99: float
     preemptions: int
@@ -229,12 +230,12 @@ class ContinuousBatchingEngine:
         direct ``np.percentile`` over the recorded series."""
         self._c_tokens = reg.counter("serve_tokens_total")
         self._h_ttft = reg.histogram("serve_ttft_s", exact=True)
-        self._h_lat = reg.histogram("serve_token_latency_s", exact=True)
         self._h_stall = reg.histogram("serve_intertoken_stall_s",
                                       exact=True)
         self._h_iter = reg.histogram("serve_iter_s", exact=True)
 
     def _set_gauges(self, reg: Registry) -> None:
+        """Pool and batch gauges, set once when a run ends."""
         st = self.pool.stats()
         reg.gauge("pool_blocks_free").set(st["free"])
         reg.gauge("pool_blocks_used").set(st["used"])
@@ -293,6 +294,7 @@ class ContinuousBatchingEngine:
                 "ragged decode + context-parallel SOCKET is a ROADMAP item")
 
     # --------------------------------------------------------------- jit
+    @jax.named_scope("model.head")
     def _pick(self, logits: jax.Array, keys: jax.Array):
         """Next-token choice from one step's ``(B, 1, V)`` logits."""
         last = logits[:, -1]
@@ -481,12 +483,12 @@ class ContinuousBatchingEngine:
         start) to completion.  ``realtime=False`` treats arrivals as
         already-arrived (offline batch; deterministic, used by tests)."""
         sched = self.scheduler
-        sv = self.serving
         obs = self.obs
         tracer = obs.tracer if obs is not None else None
         probe = obs.probe if obs is not None and obs.probe.every > 0 \
             and self._probe_capable else None
         profiler = obs.profiler if obs is not None else None
+        span = profiler.annotate if profiler is not None else null_span
         reg = self.registry = Registry()    # run-scoped, like the metrics
         self._bind_instruments(reg)
         sched.bind_obs(reg, tracer)
@@ -509,63 +511,13 @@ class ContinuousBatchingEngine:
         c_iters_mixed = reg.counter("serve_iters_total", kind="mixed")
         c_iters_decode = reg.counter("serve_iters_total", kind="decode")
         c_chunks = reg.counter("serve_chunks_total")
+        t_synced = None             # the previous step's token on the host
 
         while sched.has_work:
-            chunk: Optional[PrefillChunk] = None
-            if self.chunked:
-                # decode-table growth FIRST (it may evict the prefiller,
-                # which must not happen after a chunk has been granted —
-                # the granted chunk's block ids would be dangling)...
-                runnable = sched.ensure_decode_blocks()
-                if self.prefix_cache is not None:
-                    self._resolve_decode_cow(runnable)
-                if self._prefilling is not None and \
-                        self._prefilling.state != PREFILL:
-                    self._prefilling = None  # evicted by decode growth/CoW
-                # ...then the chunk grant (alloc-only — its cache-evict
-                # tier frees refcount-1 pages, never a live request — so
-                # it cannot invalidate the runnable snapshot)
-                if self._prefilling is None:
-                    req = sched.try_admit(now())
-                    if req is not None:
-                        self._install_key(req)
-                        self._prefilling = req
-                if self._prefilling is not None:
-                    chunk = sched.grant_chunk(self._prefilling)
-                    if chunk is None and \
-                            self._prefilling.state != PREFILL:
-                        self._prefilling = None   # safety self-preempt
-                if chunk is not None and self.prefix_cache is not None \
-                        and not self._resolve_chunk_cow(self._prefilling,
-                                                        chunk):
-                    # the prefiller itself was preempted making room for
-                    # its CoW clone — the granted chunk is void
-                    chunk = None
-                    self._prefilling = None
-                if self.prefix_cache is not None:
-                    # CoW allocation may have LRU-preempted decoders out
-                    # of the snapshot taken above
-                    runnable = [r for r in runnable
-                                if sched.running.get(r.slot) is r]
-            else:
-                # legacy order: whole-prompt prefill phase, then growth —
-                # a request admitted this iteration decodes this
-                # iteration (ensure-first would cost every admission one
-                # extra iteration of inter-token latency)
-                for _ in range(sv.max_prefill_per_iter):
-                    req = sched.try_admit(now())
-                    if req is None:
-                        break
-                    self._install_key(req)
-                    self._prefill_one(req, wall)
-                    if req.t_first_token is None:
-                        self._note_first_token(req, stamp())
-                    sched.activate(req)
-                    if req.done:      # max_new_tokens == 1 degenerate case
-                        sched.finish(req, stamp())
-                runnable = sched.ensure_decode_blocks()
-
-            # ---------------- ragged decode (+ chunk) -------------------
+            if profiler is not None:
+                profiler.maybe_start(decode_iters, tracer)
+            with span("engine.schedule"):
+                runnable, chunk = self._schedule(now, stamp, wall)
             if not runnable and chunk is None:
                 if sched.waiting and not sched.running and \
                         self._prefilling is None:
@@ -574,81 +526,79 @@ class ContinuousBatchingEngine:
                     if realtime and wait > 0:
                         time.sleep(min(wait, 0.05))
                 continue
-            if profiler is not None:
-                profiler.maybe_start(decode_iters, tracer)
-            t_it = time.perf_counter()
-            tokens = np.zeros((sv.max_batch, 1), np.int32)
-            bt = np.full((sv.max_batch, sv.max_blocks_per_seq),
-                         TRASH_BLOCK, np.int32)
-            pos = np.zeros((sv.max_batch,), np.int32)
-            active = np.zeros((sv.max_batch,), bool)
-            for r in runnable:
-                tokens[r.slot, 0] = r.input_token(r.pos)
-                bt[r.slot, :len(r.blocks)] = r.blocks
-                pos[r.slot] = r.pos
-                active[r.slot] = True
+            # ---------------- ragged decode (+ chunk) -------------------
+            t_tables = time.perf_counter()
+            with span("engine.tables"):
+                tokens, bt, pos, active = self._tables(runnable)
             if probe is not None and runnable \
                     and probe.due(decode_iters):
-                self._run_probe(decode_iters, tokens, bt, pos, active,
-                                runnable)
+                with span("engine.probe"):
+                    self._run_probe(decode_iters, tokens, bt, pos, active,
+                                    runnable)
             kind = "decode" if chunk is None else "mixed"
-            ann = profiler.annotate(kind) if profiler is not None \
-                else contextlib.nullcontext()
-            if chunk is not None:
-                with ann:
+            t_dispatch = time.perf_counter()
+            with span(kind):
+                if chunk is not None:
                     first_tok, next_tok = self._run_mixed(
                         chunk, tokens, bt, pos, active)
-                self.chunk_trace.append((decode_iters,
-                                         self._prefilling.rid,
-                                         chunk.start, chunk.tokens))
-                c_chunks.inc()
-                self._finish_chunk(chunk, first_tok, wall, stamp)
-            else:
-                with ann:
+                else:
                     next_tok, self._keys, self.pages = self._decode_fn(
                         self.params, self.pages, self._keys,
                         jnp.asarray(tokens), jnp.asarray(bt),
                         jnp.asarray(pos), jnp.asarray(active))
-            next_tok = np.asarray(next_tok)
-            it_s = time.perf_counter() - t_it
-            self._note_call(kind, it_s)
-            self._h_iter.record(it_s)
-            (c_iters_mixed if chunk is not None else c_iters_decode).inc()
-            for r in runnable:
-                # post-preemption replay: steps whose output token is
-                # already recorded only rebuild the cache — the
-                # recomputation is identical, so the produced token is
-                # discarded, not re-sampled (token-exact resume).
-                replaying = r.pos - len(r.prompt) + 1 < len(r.generated)
-                if not replaying:
-                    r.generated.append(int(next_tok[r.slot]))
-                    r.token_latencies.append(it_s)
-                    self._h_lat.record(it_s)
-                    self._note_token(r, wall())
-                r.pos += 1
-                if r.done and not replaying:
-                    sched.finish(r, stamp())
-            self._set_gauges(reg)
-            if tracer:
-                st = self.pool.stats()
-                tracer.emit(
-                    "step", iter=decode_iters, kind=kind,
-                    occupancy=int(active.sum()),
-                    chunk_tokens=chunk.tokens if chunk is not None else 0,
-                    step_s=round(it_s, 6), pool_free=st["free"],
-                    pool_used=st["used"],
-                    pool_high_water=st["high_water"],
-                    waiting=len(sched.waiting),
-                    prefilling=len(sched.prefilling),
-                    running=len(sched.running))
-            decode_iters += 1
-            if self.iter_hook is not None:
-                self.iter_hook(self, decode_iters)
+            t_sync = time.perf_counter()
+            with span("engine.sync"):
+                next_tok = np.asarray(next_tok)
+            t_prev, t_synced = t_synced, time.perf_counter()
+            with span("engine.emit"):
+                it_s = t_synced - t_tables
+                self._note_call(kind, it_s)
+                self._h_iter.record(it_s)
+                if chunk is not None:
+                    self.chunk_trace.append((decode_iters,
+                                             self._prefilling.rid,
+                                             chunk.start, chunk.tokens))
+                    c_chunks.inc()
+                    self._finish_chunk(chunk, first_tok, wall, stamp)
+                    c_iters_mixed.inc()
+                else:
+                    c_iters_decode.inc()
+                for r in runnable:
+                    # post-preemption replay: steps whose output token is
+                    # already recorded only rebuild the cache — the
+                    # recomputation is identical, so the produced token is
+                    # discarded, not re-sampled (token-exact resume).
+                    replaying = r.pos - len(r.prompt) + 1 < len(r.generated)
+                    if not replaying:
+                        r.generated.append(int(next_tok[r.slot]))
+                        self._note_token(r, wall())
+                    r.pos += 1
+                    if r.done and not replaying:
+                        sched.finish(r, stamp())
+                if tracer:
+                    pool = self.pool
+                    tracer.emit(
+                        "step", t=t_dispatch, iter=decode_iters, kind=kind,
+                        occupancy=int(active.sum()),
+                        chunk_tokens=chunk.tokens if chunk is not None
+                        else 0,
+                        step_s=round(it_s, 6), pool_free=pool.num_free,
+                        pool_used=pool.num_used,
+                        pool_high_water=pool.high_water,
+                        waiting=len(sched.waiting),
+                        prefilling=len(sched.prefilling),
+                        running=len(sched.running),
+                        host_s=None if t_prev is None
+                        else round(t_sync - t_prev, 6))
+                decode_iters += 1
+                if self.iter_hook is not None:
+                    self.iter_hook(self, decode_iters)
             if profiler is not None:
                 profiler.maybe_stop(decode_iters, tracer)
 
         if profiler is not None:
             profiler.stop(tracer)           # run shorter than the window
+        self._set_gauges(reg)
         wall_total = time.perf_counter() - t0
         m = self._metrics(requests, wall_total)
         if tracer:
@@ -656,6 +606,81 @@ class ContinuousBatchingEngine:
                            generated=m.total_generated,
                            wall_s=round(wall_total, 6))
         return m
+
+    def _schedule(self, now, stamp, wall
+                  ) -> Tuple[List[Request], Optional[PrefillChunk]]:
+        """One iteration's host scheduling: decode-table growth, CoW,
+        admission and the chunk grant (chunked mode), or whole-prompt
+        prefills then growth (legacy mode).  Returns the runnable decode
+        batch and the granted chunk (None when none was granted)."""
+        sched = self.scheduler
+        chunk: Optional[PrefillChunk] = None
+        if not self.chunked:
+            # legacy order: whole-prompt prefill phase, then growth — a
+            # request admitted this iteration decodes this iteration
+            # (ensure-first would cost every admission one extra
+            # iteration of inter-token latency)
+            for _ in range(self.serving.max_prefill_per_iter):
+                req = sched.try_admit(now())
+                if req is None:
+                    break
+                self._install_key(req)
+                self._prefill_one(req, wall)
+                if req.t_first_token is None:
+                    self._note_first_token(req, stamp())
+                sched.activate(req)
+                if req.done:          # max_new_tokens == 1 degenerate case
+                    sched.finish(req, stamp())
+            return sched.ensure_decode_blocks(), None
+        # decode-table growth FIRST (it may evict the prefiller, which
+        # must not happen after a chunk has been granted — the granted
+        # chunk's block ids would be dangling)...
+        runnable = sched.ensure_decode_blocks()
+        if self.prefix_cache is not None:
+            self._resolve_decode_cow(runnable)
+        if self._prefilling is not None and \
+                self._prefilling.state != PREFILL:
+            self._prefilling = None      # evicted by decode growth/CoW
+        # ...then the chunk grant (alloc-only — its cache-evict tier frees
+        # refcount-1 pages, never a live request — so it cannot invalidate
+        # the runnable snapshot)
+        if self._prefilling is None:
+            req = sched.try_admit(now())
+            if req is not None:
+                self._install_key(req)
+                self._prefilling = req
+        if self._prefilling is not None:
+            chunk = sched.grant_chunk(self._prefilling)
+            if chunk is None and self._prefilling.state != PREFILL:
+                self._prefilling = None       # safety self-preempt
+        if chunk is not None and self.prefix_cache is not None \
+                and not self._resolve_chunk_cow(self._prefilling, chunk):
+            # the prefiller itself was preempted making room for its CoW
+            # clone — the granted chunk is void
+            chunk = None
+            self._prefilling = None
+        if self.prefix_cache is not None:
+            # CoW allocation may have LRU-preempted decoders out of the
+            # snapshot taken above
+            runnable = [r for r in runnable
+                        if sched.running.get(r.slot) is r]
+        return runnable, chunk
+
+    def _tables(self, runnable: List[Request]):
+        """The decode batch's host tables: ``(tokens, block tables,
+        positions, active)`` numpy arrays, one row per slot."""
+        sv = self.serving
+        tokens = np.zeros((sv.max_batch, 1), np.int32)
+        bt = np.full((sv.max_batch, sv.max_blocks_per_seq), TRASH_BLOCK,
+                     np.int32)
+        pos = np.zeros((sv.max_batch,), np.int32)
+        active = np.zeros((sv.max_batch,), bool)
+        for r in runnable:
+            tokens[r.slot, 0] = r.input_token(r.pos)
+            bt[r.slot, :len(r.blocks)] = r.blocks
+            pos[r.slot] = r.pos
+            active[r.slot] = True
+        return tokens, bt, pos, active
 
     # --------------------------------------------------------------- cow
     def _cow(self, req: Request, idx: int, keep: int) -> bool:
@@ -832,8 +857,8 @@ class ContinuousBatchingEngine:
             throughput_tok_s=total / wall if wall > 0 else float("nan"),
             ttft_s_mean=self._h_ttft.mean_exact(),
             ttft_s_p99=self._h_ttft.percentile_exact(99),
-            token_latency_s_p50=self._h_lat.percentile_exact(50),
-            token_latency_s_p99=self._h_lat.percentile_exact(99),
+            token_latency_s_p50=self._h_stall.percentile_exact(50),
+            token_latency_s_p99=self._h_stall.percentile_exact(99),
             preemptions=int(reg.value("serve_preemptions_total")),
             decode_iters=int(reg.value("serve_iters_total")),
             prefill_chunks=int(reg.value("serve_chunks_total")),

@@ -27,19 +27,21 @@ import math
 from typing import Any, Dict, Iterable, List
 
 __all__ = ["SCHEMA_VERSION", "SUPPORTED_SCHEMAS", "EVENT_SCHEMA",
-           "EVENT_SCHEMA_V1", "validate_event", "validate_jsonl",
-           "sanitize", "strict_dumps", "strict_loads"]
+           "EVENT_SCHEMA_V1", "EVENT_SCHEMA_V2", "validate_event",
+           "validate_jsonl", "sanitize", "strict_dumps", "strict_loads"]
 
-SCHEMA_VERSION = 2
+SCHEMA_VERSION = 3
 
 # Field type specs: int / float / str / bool.  ``float`` accepts ints
 # (JSON has one number type) and ``None`` (a sanitized non-finite value);
 # every other type is exact.  ``?`` prefix marks the field optional.
 #
-# This dict is the CURRENT (v2) schema; v1 — before the prefix-cache
-# events — is frozen below as :data:`EVENT_SCHEMA_V1`, and
-# :func:`validate_jsonl` checks each trace against the schema its
-# handshake declares, so both generations of traces stay readable.
+# This dict is the CURRENT (v3) schema; v2 — before the step record's
+# host turnaround and the profiler's clock anchor — is frozen below as
+# :data:`EVENT_SCHEMA_V2`, v1 — before the prefix-cache events — as
+# :data:`EVENT_SCHEMA_V1`, and :func:`validate_jsonl` checks each trace
+# against the schema its handshake declares, so every generation of
+# traces stays readable.
 EVENT_SCHEMA: Dict[str, Dict[str, type]] = {
     # one per trace file — version handshake + engine metadata (warmup
     # compiles may precede the first run, so runs are bracketed by
@@ -64,10 +66,14 @@ EVENT_SCHEMA: Dict[str, Dict[str, type]] = {
     "first_token": {"rid": int, "ttft_s": float},
     "finish": {"rid": int, "generated": int, "preemptions": int},
     # ---- per-iteration step record ---------------------------------------
+    # ``ts`` is the dispatch's start.  ``host_s`` (v3) runs from the
+    # previous step's token reaching the host to the end of this dispatch:
+    # the turnaround in which the device has nothing queued; null on a
+    # run's first step.  Each host phase's own time is its profiler span.
     "step": {"iter": int, "kind": str, "occupancy": int,
              "chunk_tokens": int, "step_s": float, "pool_free": int,
              "pool_used": int, "pool_high_water": int, "waiting": int,
-             "prefilling": int, "running": int},
+             "prefilling": int, "running": int, "host_s": float},
     # first execution of a jitted shape (trace + compile + first run)
     "compile": {"fn": str, "seconds": float},
     # ---- sampled selection-quality probe (one event per probed layer) ----
@@ -76,7 +82,9 @@ EVENT_SCHEMA: Dict[str, Dict[str, type]] = {
               "forced_share": float, "selected_mean": float,
               "budget_mean": float},
     # ---- profiler lifecycle ----------------------------------------------
-    "profile_start": {"dir": str, "steps": int},
+    # clock_ns (v3): the tracer's epoch on the profiler's host clock
+    # (wall ``time.time_ns``), so ts * 1e9 + clock_ns is an xplane time
+    "profile_start": {"dir": str, "steps": int, "clock_ns": int},
     "profile_stop": {"dir": str},
     # ---- prefix cache (v2) -----------------------------------------------
     # admission-time match result (one per admission when the cache is on)
@@ -96,16 +104,23 @@ EVENT_SCHEMA: Dict[str, Dict[str, type]] = {
 _V2_EVENTS = ("cache_hit", "cache_miss", "page_share", "cow_copy",
               "cache_evict")
 
-# v1, frozen: no prefix-cache events, no trace_start.prefix_cache field.
+_V3_FIELDS = {"step": ("host_s",), "profile_start": ("clock_ns",)}
+
+# v2, frozen: no step.host_s, no profile_start.clock_ns.
+EVENT_SCHEMA_V2: Dict[str, Dict[str, type]] = {
+    ev: {k: v for k, v in fields.items() if k not in _V3_FIELDS.get(ev, ())}
+    for ev, fields in EVENT_SCHEMA.items()}
+
+# v1, frozen: v2 less the prefix-cache events and trace_start.prefix_cache.
 EVENT_SCHEMA_V1: Dict[str, Dict[str, type]] = {
-    ev: dict(fields) for ev, fields in EVENT_SCHEMA.items()
+    ev: dict(fields) for ev, fields in EVENT_SCHEMA_V2.items()
     if ev not in _V2_EVENTS}
 EVENT_SCHEMA_V1["trace_start"] = {
-    k: v for k, v in EVENT_SCHEMA["trace_start"].items()
+    k: v for k, v in EVENT_SCHEMA_V2["trace_start"].items()
     if k != "?prefix_cache"}
 
 SUPPORTED_SCHEMAS: Dict[int, Dict[str, Dict[str, type]]] = {
-    1: EVENT_SCHEMA_V1, 2: EVENT_SCHEMA}
+    1: EVENT_SCHEMA_V1, 2: EVENT_SCHEMA_V2, 3: EVENT_SCHEMA}
 
 
 def sanitize(obj: Any) -> Any:

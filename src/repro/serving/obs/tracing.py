@@ -4,7 +4,8 @@ One :class:`Tracer` serves one engine's lifetime (it may span several
 ``run()`` calls; the trace opens with one ``trace_start`` version
 handshake and each run is bracketed by ``run_start``/``run_end``).
 Timestamps are seconds since the tracer's epoch (``time.perf_counter``
-based — monotonic, sub-μs).
+based — monotonic, sub-μs); :meth:`Tracer.clock_ns` gives that epoch on
+the wall clock the ``jax.profiler`` trace uses, which joins the two.
 
 Every event is validated against :data:`~repro.serving.obs.events
 .EVENT_SCHEMA` at emit time and serialized strictly (non-finite floats
@@ -48,8 +49,18 @@ class Tracer:
         """Seconds since the tracer epoch."""
         return time.perf_counter() - self._t0
 
-    def emit(self, event_type: str, **fields) -> Dict:
-        event = {"ev": event_type, "ts": round(self.now(), 6)}
+    def clock_ns(self) -> int:
+        """The tracer's epoch in ``time.time_ns`` nanoseconds — the clock
+        ``jax.profiler`` stamps host events with."""
+        t, wall = time.perf_counter(), time.time_ns()
+        return wall - round((t - self._t0) * 1e9)
+
+    def emit(self, event_type: str, t: Optional[float] = None,
+             **fields) -> Dict:
+        """Validate and record one event, stamped now, or at ``t`` (a
+        ``time.perf_counter()`` reading) when given."""
+        ts = self.now() if t is None else t - self._t0
+        event = {"ev": event_type, "ts": round(ts, 6)}
         event.update(ev.sanitize(fields))
         ev.validate_event(event)
         self.events.append(event)

@@ -92,7 +92,6 @@ class Request:
     # metrics (seconds relative to run start)
     t_first_token: Optional[float] = None
     t_finished: Optional[float] = None
-    token_latencies: List[float] = dataclasses.field(default_factory=list)
     # wall-clock emission time of each token (engine-relative seconds) —
     # feeds the max inter-token-stall metric
     token_walls: List[float] = dataclasses.field(default_factory=list)

@@ -47,7 +47,7 @@ def _step_event(**over):
     base = {"ev": "step", "ts": 0.5, "iter": 0, "kind": "decode",
             "occupancy": 2, "chunk_tokens": 0, "step_s": 0.01,
             "pool_free": 40, "pool_used": 7, "pool_high_water": 9,
-            "waiting": 0, "prefilling": 0, "running": 2}
+            "waiting": 0, "prefilling": 0, "running": 2, "host_s": 0.004}
     base.update(over)
     return base
 
@@ -465,19 +465,20 @@ def test_disabled_path_constructs_no_tracing_objects(monkeypatch):
 
 def test_serve_metrics_are_byte_identical_to_direct_aggregation(served):
     """ServeMetrics now derives from the registry's exact histograms; it
-    must equal the pre-registry direct aggregation over the per-request
-    series, float-for-float."""
+    must equal the direct aggregation over the per-request series,
+    float-for-float.  Token latency is the gap between consecutive
+    tokens of one request."""
     engine, reqs, m = served["traced"]
     ttfts = [r.t_first_token - r.arrival for r in reqs]
-    lats = [s for r in reqs for s in r.token_latencies]
     stalls = [b - a for r in reqs
               for a, b in zip(r.token_walls, r.token_walls[1:])]
     assert m.num_requests == len(reqs)
     assert m.total_generated == sum(len(r.generated) for r in reqs)
     assert m.ttft_s_mean == float(np.mean(ttfts))
     assert m.ttft_s_p99 == float(np.percentile(ttfts, 99))
-    assert m.token_latency_s_p50 == float(np.percentile(lats, 50))
-    assert m.token_latency_s_p99 == float(np.percentile(lats, 99))
+    assert m.token_latency_s_p50 == float(np.percentile(stalls, 50))
+    assert m.token_latency_s_p99 == float(np.percentile(stalls, 99))
+    assert engine.registry.get("serve_token_latency_s") is None
     assert m.intertoken_stall_s_max == max(stalls)
     assert m.preemptions == sum(r.preemptions for r in reqs)
     reg = engine.registry
