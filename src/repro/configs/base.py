@@ -89,8 +89,9 @@ class SocketSettings:
     # score via kernels/socket_score and attend the selected subset via
     # kernels/flash_decode.  The kernels compile with Mosaic on a TPU and
     # run in the Pallas interpreter elsewhere (same semantics, interpreter
-    # speed); with both flags off the decode path is plain XLA on every
-    # platform.
+    # speed).  On a TPU, kvhead/pooled scoring of packed bits runs
+    # socket_score without the flag; with both flags off the decode path
+    # is otherwise plain XLA.
     use_score_kernel: bool = False
     use_flash_decode: bool = False
     # Route PagedView decode (the serving engine) through the fused

@@ -248,8 +248,9 @@ def soft_scores_factorized(cfg: SocketConfig, bits: jax.Array,
 
     where ``S`` are the stored ±1 sign bits.  This replaces the GPU gather
     with a dense ±1 contraction (DESIGN.md §2).  The Pallas kernel
-    (kernels/socket_score) computes the same expression with streaming
-    bit-unpack; this jnp version is the XLA fallback / dry-run path.
+    (kernels/socket_score) computes the same scores in one pass over the
+    packed bits and serves kvhead/pooled decode on a TPU; this jnp
+    version is the XLA path everywhere else.
 
     When ``cfg.score_chunk`` divides N, keys are scored under ``lax.scan``
     in chunks so the live unpacked-sign buffer stays bounded at long

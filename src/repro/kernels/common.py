@@ -30,6 +30,12 @@ def resolve_interpret(interpret: Optional[bool]) -> bool:
     return bool(interpret)
 
 
+def compiles_with_mosaic() -> bool:
+    """Whether kernels compile here (a TPU backend): the one predicate a
+    served path reads to route through a kernel without a flag."""
+    return not resolve_interpret(None)
+
+
 def head_row(ref, h) -> jax.Array:
     """Row ``h`` of a ``(1, KVH, n)`` block as an f32 ``(1, n)`` lane row.
 

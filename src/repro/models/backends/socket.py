@@ -3,7 +3,8 @@
 Cache leaves: K/V plus the side-cache of packed hash bits and value norms
 (Algorithm 1).  ``attend`` soft-hashes the query (Algorithm 2), scores
 every cached key with the factorized soft-collision kernel — through the
-Pallas scoring kernel when ``cfg.socket.use_score_kernel`` is set — runs
+Pallas scoring kernel wherever it compiles (a TPU) or
+``cfg.socket.use_score_kernel`` asks for it, else in XLA — runs
 value-aware top-k (Algorithm 3), and attends exactly over the selected
 subset (``flash_decode`` when ``cfg.socket.use_flash_decode``).
 
@@ -28,6 +29,7 @@ import jax.numpy as jnp
 
 from repro.core import hashing
 from repro.core import socket as sk
+from repro.kernels import common as kcommon
 from repro.models.backends import base
 from repro.models.backends import probe as bprobe
 from repro.models.backends.base import ContiguousView, KVView, LeafSpec
@@ -107,16 +109,23 @@ class SocketBackend(base.DecodeBackend):
         return sk.soft_hash_query(params["hash_w"], q[..., 0, :])
 
     @jax.named_scope("socket.score")
-    def _scores(self, cfg, params, q, view: KVView):
-        """(soft-hash u, collision scores) for the selection mode."""
+    def _scores(self, cfg, params, q, view: KVView, length):
+        """(soft-hash u, collision scores) for the selection mode.
+
+        kvhead/pooled scoring runs the Pallas kernel where it compiles
+        (a TPU) on packed bits, and wherever ``use_score_kernel`` asks
+        for it; elsewhere the XLA factorized scorer."""
         scfg = socket_config_of(cfg)
         u = self._soft_hash(scfg, params, q)
         bits = view.leaf("bits")
-        if cfg.socket.use_score_kernel:
-            if scfg.selection not in ("kvhead", "pooled"):
-                raise NotImplementedError(
-                    "the Pallas scoring kernel group-sums scores (kvhead "
-                    "selection); use the XLA path for per-q-head selection")
+        if cfg.socket.use_score_kernel and scfg.selection == "qhead":
+            raise NotImplementedError(
+                "the Pallas scoring kernel group-sums scores (kvhead "
+                "selection); use the XLA path for per-q-head selection")
+        if scfg.selection != "qhead" and (
+                cfg.socket.use_score_kernel
+                or (scfg.bits_storage == "packed"
+                    and kcommon.compiles_with_mosaic())):
             # bits_storage='int8' streams the ±1 plane bytes directly (the
             # kernel skips the unpack; format inferred from the dtype)
             from repro.kernels.socket_score import ops as score_ops
@@ -124,7 +133,9 @@ class SocketBackend(base.DecodeBackend):
             u_k = u[:, :, None] if scfg.selection == "pooled" else u
             scores = score_ops.socket_score(
                 bits, u_k, vnorm=None, num_tables=scfg.num_tables,
-                num_planes=scfg.num_planes, tau=scfg.tau)  # (B,KVH,N), G-sum
+                num_planes=scfg.num_planes, tau=scfg.tau,
+                length=length)                         # (B,KVH,N), G-sum
+            base.record_fused("socket_score", scores.shape)
         elif scfg.selection == "pooled":
             scores = sk.soft_scores_factorized(scfg, bits, u)  # (B,KVH,N)
         else:
@@ -221,7 +232,7 @@ class SocketBackend(base.DecodeBackend):
                 length=length, scale=scale,
                 batch_axes=cfg.decode_cp_batch_axes)
 
-        scores = self._scores(cfg, params, q, view)
+        scores = self._scores(cfg, params, q, view, length)
         kq = sk.topk_budget(scfg, n)
         if scfg.selection in ("kvhead", "pooled"):
             with jax.named_scope("socket.select"):

@@ -162,6 +162,42 @@ def test_socket_kernel_scores_int8_bits():
                                np.asarray(outs[False]), atol=2e-5)
 
 
+@pytest.mark.parametrize("selection", ["kvhead", "pooled", "qhead"])
+def test_paged_socket_scoring_routes_to_kernel_where_it_compiles(
+        selection, monkeypatch):
+    """With no flag set, kvhead/pooled scoring runs the Pallas kernel
+    wherever it compiles (a TPU) and records its dispatch; qhead keeps
+    the XLA scorer.  On the CPU's default routing nothing is recorded;
+    with the compile predicate patched, the kernel runs here in the
+    interpreter and the attend output matches the XLA route."""
+    from repro.kernels import common as kcommon
+
+    cfg, be, params, _, pview, q = _setup("socket")
+    cfg = cfg.replace(socket=dataclasses.replace(cfg.socket,
+                                                 selection=selection))
+    lengths = jnp.asarray([13, 29], jnp.int32)
+
+    def attend():
+        bk.gather_trace_reset()
+        out = be.attend(cfg, params, q, pview, length=lengths, scale=0.125)
+        return out, [t for t in bk.gather_trace() if t[0] == "fused"]
+
+    out_xla, fused = attend()
+    assert fused == []
+    monkeypatch.setattr(kcommon, "compiles_with_mosaic", lambda: True)
+    out_k, fused = attend()
+    if selection == "qhead":
+        assert fused == []
+        np.testing.assert_array_equal(np.asarray(out_k),
+                                      np.asarray(out_xla))
+    else:
+        b, kvh = pview.block_table.shape[0], params["wk"].shape[1]
+        assert fused == [("fused", "socket_score",
+                          (b, kvh, pview.n_tokens))]
+        np.testing.assert_allclose(np.asarray(out_k), np.asarray(out_xla),
+                                   atol=2e-5)
+
+
 def test_quest_append_resets_stats_on_reused_page():
     """A decode-growth block may be a reused page still carrying the
     previous owner's min/max (BlockPool never scrubs device memory): the

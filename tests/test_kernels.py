@@ -45,6 +45,7 @@ def _build_socket_score(case):
         case.kwargs[k] for k in
         ("p", "l", "n", "g", "bh", "d", "block_n", "weighted"))
     bits_fmt = case.kwargs.get("bits_fmt", "packed")
+    lengths = case.kwargs.get("lengths")
     rng = jax.random.PRNGKey(p * l + n + block_n)
     kk, kq, kw, kv = jax.random.split(rng, 4)
     w = hashing.make_hash_params(kw, d, p, l)
@@ -57,13 +58,42 @@ def _build_socket_score(case):
         bits = (signs.astype(jnp.int8) * 2 - 1).reshape(bh, n, l * p)
     else:
         bits = hashing.pack_signs(signs)
-    u = socket.soft_hash_query(w, q)
+    # u_scale > 1 pushes u/tau across the exp range (u itself is tanh-
+    # bounded by 1/sqrt(d))
+    u = socket.soft_hash_query(w, q) * case.kwargs.get("u_scale", 1.0)
     vnorm = (jax.random.uniform(kv, (bh, n)) + 0.5) if weighted else None
+    length = None if lengths is None else jnp.asarray(lengths, jnp.int32)
     out = socket_score(bits, u, vnorm, num_tables=l, num_planes=p, tau=0.4,
-                       block_n=block_n)
+                       length=length, block_n=block_n)
     ref = socket_score_ref(bits, u, vnorm, num_tables=l, num_planes=p,
                            tau=0.4)
-    return [("scores", out, ref)]
+    # the XLA scorer the served path runs where the kernel does not
+    scfg = socket.SocketConfig(num_planes=p, num_tables=l, tau=0.4,
+                               sparsity=4.0, sink_tokens=4, window_tokens=4,
+                               min_k=4, bits_storage=bits_fmt)
+    vn = jnp.ones((bh, n)) if vnorm is None else vnorm
+    with jax.default_matmul_precision("float32"):
+        xla = jnp.sum(socket.soft_scores_factorized(
+            scfg, bits[:, None], u), axis=1) * vn
+    live = np.ones((bh, n), bool) if lengths is None else (
+        np.arange(n)[None] < np.asarray(lengths)[:, None])
+    out_np = np.asarray(out)
+    cmps = [("scores", out_np[live], np.asarray(ref)[live]),
+            ("xla-f32", out_np[live], np.asarray(xla)[live])]
+    if lengths is not None:
+        # whole tiles (the case's block_n keys) past a row's length
+        # score 0 (never selected)
+        dead = np.arange(n)[None] >= -(-np.asarray(lengths)[:, None]
+                                       // block_n) * block_n
+        cmps.append(("dead-tiles", out_np[dead], np.zeros(dead.sum()),
+                     BITWISE))
+    # value-aware top-k picks the same keys (inputs have no exact ties)
+    k = socket.topk_budget(scfg, n)
+    n_live = n if length is None else length
+    picks = [socket.value_aware_topk(scfg, s, vn, k=k, length=n_live,
+                                     n_total=n)[0] for s in (out, ref)]
+    cmps.append(("topk", picks[0], picks[1], BITWISE))
+    return cmps
 
 
 def _build_flash_decode(case):
@@ -319,7 +349,7 @@ KERNEL_OPS = (
     KernelOp(
         name="socket_score",
         build=_build_socket_score,
-        policy=ParityPolicy(atol=1e-6, rtol=1e-4),
+        policy=ParityPolicy(rtol=1e-5),
         cases=(
             _score_case("paper-point", 10, 60, 1024, 4, 2),
             _score_case("longbench", 8, 60, 512, 1, 2),
@@ -331,6 +361,22 @@ KERNEL_OPS = (
             _score_case("block-256", 10, 60, 1024, 2, 1, d=32,
                         block_n=256, weighted=False),
             _score_case("ragged-n", 10, 60, 384, 2, 1, block_n=512),
+            # packed words: (P, L) = (10, 60) leaves 40 alignment bits
+            # (the kernel never reads them); P=8 divides the 32-bit word
+            _score_case("paper-point-g1", 10, 60, 1024, 1, 2),
+            _score_case("p-divides-word-g4", 8, 60, 1024, 4, 2),
+            # N a multiple of the tile: two 1024-key tiles, and one
+            # 2048-key tile walked in two 1024-key steps
+            _score_case("two-tiles", 10, 60, 2048, 4, 1, block_n=1024),
+            _score_case("two-chunks", 10, 60, 2048, 1, 1, block_n=2048),
+            # N under one tile and off the 128-key lane width (padded)
+            _score_case("n-below-tile", 10, 60, 200, 4, 2),
+            # u/tau across the exp range: table log probabilities to about -60
+            _c("u-spans-exp", p=10, l=60, n=512, g=4, bh=2, d=64,
+               block_n=512, weighted=True, u_scale=10.0),
+            # tiles wholly past a row's length are skipped and score 0
+            _c("length-skips-tiles", p=10, l=60, n=3072, g=4, bh=2, d=64,
+               block_n=1024, weighted=True, lengths=(700, 2100)),
             # bits_storage="int8": the kernel streams ±1 plane bytes
             # (no unpack, no padding tables) — same scores as packed
             _c("int8-bits-paper-point", p=10, l=60, n=1024, g=4, bh=2,
@@ -339,6 +385,8 @@ KERNEL_OPS = (
                bh=2, d=64, block_n=512, weighted=True, bits_fmt="int8"),
             _c("int8-bits-block-128", p=6, l=12, n=256, g=2, bh=3,
                d=64, block_n=128, weighted=False, bits_fmt="int8"),
+            _c("int8-bits-g1", p=10, l=60, n=512, g=1, bh=2, d=64,
+               block_n=512, weighted=True, bits_fmt="int8"),
         ),
     ),
     KernelOp(

@@ -29,6 +29,7 @@ from repro.kernels.paged_attention import (paged_hard_lsh_attend,
                                            paged_ring_attend,
                                            paged_socket_attend)
 from repro.kernels.socket_score import socket_score
+from repro.kernels.socket_score.socket_score import socket_score_pallas
 
 B, KVH, G, HD = 8, 8, 4, 128           # decode batch, minitron-8b heads
 BS, P, L, W = 16, 10, 60, 20           # page rows, SOCKET planes/tables/words
@@ -142,6 +143,21 @@ def _socket_score():
                 ((B, KVH, n), jnp.float32)]
 
 
+def _socket_score_cell():
+    """The kernel as the served step calls it at the mistral-7b-v0.3
+    decode-long cell's shape: 4 requests, 8 KV heads, group 4, an
+    8,192-token ceiling, the packed words key-major (B, N, KVH, W) as
+    the block-table gather writes them, and per-request lengths."""
+    b, n = 4, 8192
+
+    def fn(words, u, length):
+        return socket_score_pallas(words, u, None, num_tables=L,
+                                   num_planes=P, tau=0.4, length=length,
+                                   interpret=False)
+    return fn, [((b, n, KVH, W), jnp.uint32),
+                ((b, KVH, G, L, P), jnp.float32), ((b,), jnp.int32)]
+
+
 def _flash_decode():
     k = 832                              # a top-k selection width
 
@@ -161,6 +177,7 @@ KERNELS = {
     "paged_ring_bf16": _paged_ring,
     "paged_ring_int8": functools.partial(_paged_ring, jnp.int8),
     "socket_score": _socket_score,
+    "socket_score_cell": _socket_score_cell,
     "flash_decode": _flash_decode,
 }
 
