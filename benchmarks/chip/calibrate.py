@@ -85,7 +85,7 @@ def main(argv):
     for i in range(args.seeds):
         seed = args.first_seed + 7919 * i
         t0 = time.perf_counter()
-        weights = model.make_weights(cell.config, seed)
+        weights = model.make_weights(cell.config, seed, cell.root)
         engine = harness.build_engine(cell, weights)
         sessions = harness.requests_of(traffic.generate(
             cell.mix, seed, cell.config["vocab_size"]))

@@ -1,11 +1,16 @@
 """Run one cell of ``BENCHMARK.json`` once, on the chip this process holds.
 
-A cell names a configuration (``configs/<config>.json``), a traffic mix
-(``traffic/<mix>.json``) and, by its own name, its serving geometry and
-correctness limit (``cells/<cell>.json``); its metrics are the entries
-of ``BENCHMARK.json`` whose ``workloads`` list it (or that list none).
-Each per-layer metric is read by ``metrics/<metric>.py``.  Adding a
-configuration, a mix, a cell or a metric adds files and entries only.
+A cell names a configuration (``configs/<config>.json``, whose
+``block`` key names its block, ``blocks/<block>.py``, and whose
+``reference`` key its plain reference, ``references/<reference>.py``), a
+traffic mix (``traffic/<mix>.json``) and, by its own name, its serving
+geometry and correctness limit (``cells/<cell>.json``); its metrics are
+the entries of ``BENCHMARK.json`` whose ``workloads`` list it (or that
+list none).  Each per-layer metric is read by ``metrics/<metric>.py``
+from a context that holds, in a traced run, the trace's reading with
+device time by named scope (``scopes.breakdown``).  Adding a
+configuration (its block and its reference), a mix, a cell or a metric
+adds files and entries only.
 
 One run:
 
@@ -17,9 +22,11 @@ One run:
    real time; the engine's ``iter_hook`` times every step and ends the
    run at the first step end past ``--seconds``;
 3. with ``--trace 1`` the window runs under ``jax.profiler`` and the
-   per-layer metrics are read from the trace, the engine's step records
-   and the step times; otherwise the end-to-end metrics are computed from
-   the sessions' token times;
+   per-layer metrics are read from the trace (``read_trace``), the
+   engine's step records and the step times; otherwise the end-to-end
+   metrics are computed from the sessions' token times.  Every run keys
+   the compile cache by op metadata, so that the trace's scope names are
+   this program's;
 4. ``correct``: the engine's state is freed, then the plain reference
    recomputes the logits at every served token of a seeded sample of
    sessions; the gap by which each served token's reference logit lies
@@ -63,6 +70,7 @@ class Cell:
     geometry: dict
     end_to_end: List[dict]
     per_layer: List[dict]
+    root: Path = HERE                  # where its files were found
 
 
 def load_manifest(path: Path = REPO / "BENCHMARK.json") -> dict:
@@ -85,7 +93,8 @@ def load_cell(name: str, manifest: dict, root: Path = HERE) -> Cell:
         mix=traffic.load_mix(entry["traffic"], root),
         geometry=json.loads((root / "cells" / f"{name}.json").read_text()),
         end_to_end=[m for m in manifest["end_to_end"] if _listed(m, name)],
-        per_layer=[m for m in manifest["per_layer"] if _listed(m, name)])
+        per_layer=[m for m in manifest["per_layer"] if _listed(m, name)],
+        root=root)
 
 
 def metric_reader(name: str, root: Path = HERE) -> Callable:
@@ -132,13 +141,17 @@ def peak_bytes(jax) -> Optional[int]:
 def configure_cache(jax) -> str:
     """JAX's persistent compile cache: where ``JAX_COMPILATION_CACHE_DIR``
     says, else ``<checkout>/.jax_cache`` (a fixed path: the path is part
-    of the cache key)."""
+    of the cache key).  Keyed by op metadata too, which JAX leaves out by
+    default: a cached executable could otherwise carry another program's
+    scope names.  Every run keys alike, so a traced run loads what an
+    untraced run compiled."""
     path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if not path:
         path = str(REPO / ".jax_cache")
         jax.config.update("jax_compilation_cache_dir", path)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     return path
 
 
@@ -169,7 +182,8 @@ def requests_of(specs) -> list:
 
 def build_engine(cell: Cell, weights):
     from repro.serving.engine import ContinuousBatchingEngine
-    cfg = model.program_config(cell.config, serving=cell.geometry)
+    cfg = model.block(cell.config, cell.root).program_config(
+        cell.config, serving=cell.geometry)
     engine = ContinuousBatchingEngine(cfg, params=weights)
     engine.warmup()
     return engine
@@ -269,10 +283,11 @@ def end_to_end(win: Window) -> Dict[str, float]:
     return out
 
 
-def window_steps(cfg: dict, win: Window) -> List[dict]:
-    """Each decode step of the window with the work it did, from the
-    step times and the token times: a token emitted by step ``s``
-    decoded at its request's context length then."""
+def window_steps(cfg: dict, win: Window, root: Path = HERE) -> List[dict]:
+    """Each decode step of the window with the work it did (as the
+    configuration's block counts it), from the step times and the token
+    times: a token emitted by step ``s`` decoded at its request's context
+    length then."""
     decode_lengths: List[List[int]] = [[] for _ in win.hooks]
     for r in win.requests:
         p = len(r.prompt)
@@ -282,10 +297,22 @@ def window_steps(cfg: dict, win: Window) -> List[dict]:
                 decode_lengths[s].append(p + i)
     steps = []
     for lengths in decode_lengths:
-        f, b = flops.step(cfg, lengths)
+        f, b = flops.step(cfg, lengths, root=root)
         steps.append({"kind": "decode", "flops": f, "bytes": b,
                       "occupancy": len(lengths)})
     return steps
+
+
+def read_trace(xplane: str, **planes) -> Optional[dict]:
+    """A traced window's reading (``scopes.breakdown``): busy and idle
+    time over the window the step spans set, idle gaps named by every
+    host span the engine emits, and device time by named scope.
+    ``planes`` names the trace's planes and lines where they are not a
+    TPU's."""
+    from benchmarks.chip import scopes
+    from repro.serving.obs.profiling import HOST_SPANS
+    pd, paths = scopes.load(xplane)
+    return scopes.breakdown(pd, paths, label_names=HOST_SPANS, **planes)
 
 
 # ------------------------------------------------------------ correctness
@@ -332,7 +359,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, *, trace: bool,
     ``checks`` key last)."""
     import jax
     counter = CompileCounter(jax)
-    weights = model.make_weights(cell.config, seed)
+    weights = model.make_weights(cell.config, seed, cell.root)
     jax.block_until_ready(weights)
     engine = build_engine(cell, weights)
     if fault is not None:
@@ -350,9 +377,9 @@ def run_cell(cell: Cell, seed: int, seconds: float, *, trace: bool,
         red = None
         if trace:
             from benchmarks.chip import trace as tr_mod
-            red = tr_mod.reduce(tr_mod.load(tr_mod.find_xplane(tmp)))
+            red = read_trace(tr_mod.find_xplane(tmp))
     e2e = end_to_end(win)
-    steps = window_steps(cell.config, win)
+    steps = window_steps(cell.config, win, cell.root)
     release(engine)
     del engine
 
@@ -371,7 +398,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, *, trace: bool,
                "step_events": win.step_events, "trace": red,
                "peak": peak(device["kind"]), "cell": cell}
         for m in cell.per_layer:
-            v = metric_reader(m["name"])(ctx)
+            v = metric_reader(m["name"], cell.root)(ctx)
             if v is not None:
                 metrics[m["name"]] = {"value": v, "unit": m["unit"]}
     else:

@@ -197,7 +197,8 @@ def served_logits(cfg: dict, weights, prompt, served, *, mode="f32",
     """Logits ``(len(served), vocab)`` at each position that produced a
     served token: the last prompt position, then each decode step, with
     the served tokens fed back (teacher-forced)."""
-    from benchmarks.chip.model import dims, layer_weights
+    from benchmarks.chip.blocks.dense import layer_weights
+    from benchmarks.chip.model import dims
     dm = dims(cfg)
     prompt = np.asarray(prompt, np.int32)
     served = np.asarray(served, np.int32)

@@ -44,10 +44,11 @@ def main(argv):
             lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one),
             tree)
 
+    blk = model.block(cell.config, cell.root)
     params = sds(jax.eval_shape(
-        lambda: model.make_weights(cell.config, 0)))
-    small = model.program_config(cell.config,
-                                 serving={**cell.geometry, "pool_blocks": 2})
+        lambda: model.make_weights(cell.config, 0, cell.root)))
+    small = blk.program_config(cell.config,
+                               serving={**cell.geometry, "pool_blocks": 2})
     engine = ContinuousBatchingEngine(small, params=params)
     sv = small.serving
     b, nb, c = sv.max_batch, sv.max_blocks_per_seq, sv.prefill_chunk
@@ -62,7 +63,7 @@ def main(argv):
     mix = (arg((1, c)), arg((engine._chunk_bt_len(),)), arg(()), arg(()),
            arg((1,)), arg((), jnp.bool_)) + dec
     for pool in pools:
-        full = model.program_config(cell.config, serving={
+        full = blk.program_config(cell.config, serving={
             **cell.geometry, "pool_blocks": pool})
         pages = sds(jax.eval_shape(
             lambda: paged.init_paged_caches(full, full.serving)))

@@ -8,25 +8,30 @@ prints one JSON object: ``trace.reduce``'s reading of the trace, with
 ``idle_gaps`` and ``idle_by_span`` named by every host span of
 ``repro.serving.obs.profiling.HOST_SPANS`` (``reduce`` names them by
 the step spans alone), plus ``scope_device_s`` (seconds of device self
-time per scope of :data:`SCOPES`, and ``unscoped``),
-``programs_in_window`` (step programs counted by the share of each
-inside the window), ``scope_ms_per_step`` (where every step in the
-window is a decode step) and ``span_ms`` (host milliseconds per span).
-The harness does not call it: a run deletes its trace; keep one by
-serving a window with ``harness.serve_window(..., profile_dir=DIR)``.
+time per named scope, and ``unscoped``), ``programs_in_window`` (step
+programs counted by the share of each inside the window),
+``scope_ms_per_step`` (where every step in the window is a decode step)
+and ``span_ms`` (host milliseconds per span).  A traced run of the
+harness reads its trace the same way (``harness.read_trace``) and hands
+the reading to the metric readers; a run deletes its trace, so keep one
+by serving a window with ``harness.serve_window(..., profile_dir=DIR)``.
 
-An op's scope is the innermost of :data:`SCOPES` in its op-name path,
-the ``tf_op`` stat of its event metadata, which ``ProfileData`` does not
-expose: :func:`op_paths` reads it from the file's protobuf fields.  An
+An op's scope is the innermost segment of its op-name path that has the
+form of a named scope (:data:`SCOPE`), so a scope the program adds is
+attributed with no edit here.  The path is the ``tf_op`` stat of the
+op's event metadata, which ``ProfileData`` does not expose:
+:func:`op_paths` reads it from the file's protobuf fields.  An
 executable loaded from JAX's persistent compile cache carries the
 metadata of the program it was compiled from, and the cache key leaves
 metadata out unless ``jax_compilation_cache_include_metadata_in_key`` is
-set: capture traces with it set, or another commit's names appear.
+set: capture traces with it set (the harness's runs do), or another
+commit's names appear.
 """
 
 from __future__ import annotations
 
 import json
+import re
 import statistics
 import sys
 from pathlib import Path
@@ -38,10 +43,11 @@ if __name__ == "__main__":
 
 from benchmarks.chip import trace as tr  # noqa: E402
 
-# The decode step's named scopes (``jax.named_scope`` in the program).
-SCOPES = ("socket.append", "socket.hash", "socket.score", "socket.select",
-          "socket.gather", "socket.attend", "socket.fused", "layer.proj",
-          "layer.mlp", "model.head")
+# A named scope of the program (``jax.named_scope``): ``<area>.<phase>``
+# in lower case, such as ``socket.score`` or ``layer.mlp``.  JAX's own
+# segments (``jit(step)``, ``while``, ``dot_general``) and XLA's op
+# names (``fusion.221``, ``dot_general.1``) never have that form.
+SCOPE = re.compile(r"[a-z][a-z0-9_]*\.[a-z][a-z0-9_]*")
 
 Span = Tuple[str, float, float]
 
@@ -125,13 +131,14 @@ def op_paths(raw: bytes, stat: str = "tf_op") -> Dict[str, Dict[str, str]]:
     return out
 
 
-def scope_of(path: str, scopes: Sequence[str] = SCOPES) -> str:
-    """The innermost of ``scopes`` in an op-name path such as
+def scope_of(path: str) -> str:
+    """The innermost named scope in an op-name path such as
     ``jit(step)/while/body/socket.score/socket.hash/div:``, else
     ``unscoped``."""
     for part in reversed(path.split("/")):
-        if part.split(":")[0] in scopes:
-            return part.split(":")[0]
+        name = part.split(":")[0]
+        if SCOPE.fullmatch(name):
+            return name
     return "unscoped"
 
 
@@ -195,11 +202,26 @@ def breakdown(pd, paths: Dict[str, Dict[str, str]], *,
     })
 
 
+def ms_per_decode_step(ctx: dict, names: Sequence[str]) -> Optional[float]:
+    """Device milliseconds a decode step spends in the scopes ``names``,
+    from a metric reader's context: their summed ``scope_device_s`` over
+    ``programs_in_window``.  None unless every step of the window is a
+    decode step and the trace names one of the scopes."""
+    red = ctx["trace"] or {}
+    by_scope = red.get("scope_device_s") or {}
+    programs = red.get("programs_in_window")
+    steps = ctx["step_events"]
+    if not programs or not steps or any(e["kind"] != "decode"
+                                        for e in steps) \
+            or not any(n in by_scope for n in names):
+        return None
+    return 1e3 * sum(by_scope.get(n, 0.0) for n in names) / programs
+
+
 def load(path: str):
     """(``ProfileData``, :func:`op_paths`) of one ``.xplane.pb``."""
-    from jax.profiler import ProfileData
-    raw = Path(path).read_bytes()
-    return ProfileData.from_serialized_xspace(raw), op_paths(raw)
+    pd, raw = tr.load(path)
+    return pd, op_paths(raw)
 
 
 def main(argv: List[str]) -> int:
@@ -207,11 +229,8 @@ def main(argv: List[str]) -> int:
     p = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     p.add_argument("xplane")
     args = p.parse_args(argv)
-    from repro.serving.obs import profiling
-    pd, paths = load(args.xplane)
-    out = breakdown(pd, paths,
-                    label_names=getattr(profiling, "HOST_SPANS", ()))
-    print(json.dumps(out))
+    from benchmarks.chip.harness import read_trace
+    print(json.dumps(read_trace(args.xplane)))
     return 0
 
 

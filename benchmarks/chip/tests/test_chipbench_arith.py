@@ -71,22 +71,24 @@ def test_window_steps_split_tokens_by_step():
 @pytest.mark.parametrize("name,root", [("mistral-7b-v0.3", None),
                                        ("tiny", DATA)])
 def test_weight_flops_are_twice_the_counted_parameters(name, root):
-    c = model.load_config(name, **({"root": root} if root else {}))
-    counted = model.matmul_params(c)
-    assert flops.weight_flops_per_token(c) == 2 * counted
-    assert flops.weight_bytes(c) == 2 * counted
+    root = root or model.HERE
+    c = model.load_config(name, root)
+    blk = model.block(c, root)
+    counted = blk.matmul_params(c)
+    assert blk.weight_flops_per_token(c) == 2 * counted
+    assert blk.weight_bytes(c) == 2 * counted
 
 
 def test_weight_flops_match_the_made_weights():
     c = model.load_config("tiny", root=DATA)
-    w = model.make_weights(c, 3)
+    w = model.make_weights(c, 3, DATA)
     import jax
     leaves = jax.tree_util.tree_leaves_with_path(w)
     counted = sum(x.size for p, x in leaves
                   if jax.tree_util.keystr(p).split("'")[-2]
                   in ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down",
                       "head"))
-    assert flops.weight_flops_per_token(c) == 2 * counted
+    assert model.block(c, DATA).weight_flops_per_token(c) == 2 * counted
 
 
 def test_decode_token_counts_follow_the_budget():
@@ -122,7 +124,7 @@ def test_interval_union_gaps_and_attribution():
 
 
 def test_reduction_of_a_recorded_trace():
-    pd = tr.load(str(DATA / "cpu.xplane.pb"))
+    pd, _ = tr.load(str(DATA / "cpu.xplane.pb"))
     red = tr.reduce(pd, span_names=("bench.hook",),
                     device_prefix="/host:CPU",
                     ops_line="tf_XLAPjRtCpuClient",

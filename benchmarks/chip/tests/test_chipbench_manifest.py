@@ -84,7 +84,7 @@ GEOMETRY = {"block_size": 16, "pool_blocks": 1665, "max_batch": 4,
 
 def test_program_config_follows_the_file():
     c = model.load_config("mistral-7b-v0.3")
-    cfg = model.program_config(c, serving=GEOMETRY)
+    cfg = model.block(c).program_config(c, serving=GEOMETRY)
     assert (cfg.d_model, cfg.num_heads, cfg.num_kv_heads, cfg.head_dim,
             cfg.d_ff, cfg.vocab_size, cfg.num_layers, cfg.rope_theta) == \
         (4096, 32, 8, 128, 14336, 32768, 8, 1e6)
@@ -104,4 +104,4 @@ def test_program_config_follows_the_file():
 def test_program_config_refuses_what_the_program_cannot_run(key, value):
     c = {**model.load_config("mistral-7b-v0.3"), key: value}
     with pytest.raises(ValueError, match="the program's dense block"):
-        model.program_config(c, serving=GEOMETRY)
+        model.block(c).program_config(c, serving=GEOMETRY)

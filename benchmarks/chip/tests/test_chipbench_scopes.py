@@ -1,7 +1,10 @@
 """Device time by named scope and idle gaps named by host spans
-(``scopes.py``), on synthetic ops and on the recorded CPU trace, and the
-host turnaround metric on synthetic step records."""
+(``scopes.py``), on synthetic ops and on the recorded CPU trace; the
+form that makes a path segment a scope; the metric readers that read
+scope time from a traced run's context; and the host turnaround metric
+on synthetic step records."""
 
+import re
 import sys
 from pathlib import Path
 from types import SimpleNamespace as NS
@@ -143,3 +146,103 @@ def test_host_ms_reads_the_decode_step_records():
         del e["host_s"]
     assert read(old) is None
     assert read({"step_events": []}) is None
+
+
+TODAYS_SCOPES = {"socket.append", "socket.hash", "socket.score",
+                 "socket.select", "socket.gather", "socket.attend",
+                 "socket.fused", "layer.proj", "layer.mlp", "model.head"}
+
+
+def test_every_named_scope_of_the_program_has_the_scope_form():
+    named = set()
+    for path in (REPO / "src" / "repro").rglob("*.py"):
+        named |= set(re.findall(r"named_scope\(\s*[\"']([^\"']*)[\"']",
+                                path.read_text()))
+    assert TODAYS_SCOPES <= named
+    assert all(scopes.SCOPE.fullmatch(n) for n in named), named
+    for name in named:
+        assert scopes.scope_of(f"jit(step)/while/body/{name}/mul:") == name
+
+
+def test_no_op_name_of_a_recorded_trace_has_the_scope_form():
+    pd, _ = tr.load(str(DATA / "cpu.xplane.pb"))
+    ops = {e.name for p in pd.planes for ln in p.lines
+           if ln.name != "python" for e in ln.events}
+    assert "dot_general.1" in ops
+    ops |= {"%fusion.221", "copy-start.1", "jit(_decode_step)/jit(main)/"
+            "while/body/closed_call/pallas_call:", "transpose(jvp(x.y))"}
+    for op in ops:
+        assert scopes.scope_of(op) == "unscoped", op
+
+
+def _traced(paths, kinds=("decode",)):
+    """A one-program trace of ops under ``paths`` ({op: path}), 10 ns
+    each, with the host step spans of ``kinds``."""
+    ops = [(op, 10 * i, 10 * i + 10) for i, op in enumerate(paths)]
+    end = 10 * len(ops)
+    steps = [(k, -5 + i, end + i) for i, k in enumerate(kinds)]
+    return NS(planes=[
+        _plane("/device:TPU:0", {"XLA Ops": ops,
+                                 "XLA Modules": [("jit_step", 0, end)]}),
+        _plane("/host:CPU", {"python": steps})]), {"/device:TPU:0": paths}
+
+
+def test_a_scope_the_program_adds_later_is_attributed():
+    pd, paths = _traced({"a": "jit(step)/while/body/layer.moe/dot_general:",
+                         "b": "jit(step)/while/body/layer.mamba/ssd.chunk/"
+                              "mul:",
+                         "c": "jit(step)/socket.score/add:"})
+    got = scopes.breakdown(pd, paths)["scope_device_s"]
+    assert got == pytest.approx({"layer.moe": 1e-8, "ssd.chunk": 1e-8,
+                                 "socket.score": 1e-8})
+
+
+SOCKET_METRICS = {"socket_score_ms.decode": ("socket.score", "socket.hash"),
+                  "socket_select_ms.decode": ("socket.select",),
+                  "socket_attend_ms.decode": ("socket.gather",
+                                              "socket.attend")}
+
+
+def test_a_metric_reader_reads_scope_time_of_a_recorded_trace():
+    red = harness.read_trace(str(DATA / "cpu.xplane.pb"),
+                             device_prefix="/host:CPU",
+                             ops_line="tf_XLAPjRtCpuClient",
+                             modules_line="tf_XLAPjRtCpuClient")
+    plain, _ = tr.load(str(DATA / "cpu.xplane.pb"))
+    plain = tr.reduce(plain, device_prefix="/host:CPU",
+                      ops_line="tf_XLAPjRtCpuClient",
+                      modules_line="tf_XLAPjRtCpuClient")
+    # the window is still the step spans': the gaps' names alone differ
+    for key in ("window_s", "busy_s", "idle_share", "step_device_s"):
+        assert red[key] == plain[key], key
+    ctx = {"trace": red, "step_events": [{"kind": "decode"}] * 3}
+    n = red["programs_in_window"]
+    assert n > 0
+    read = harness.metric_reader("unscoped_ms.decode", root=DATA)
+    assert read(ctx) == pytest.approx(
+        1e3 * red["scope_device_s"]["unscoped"] / n)
+    assert read(ctx) == pytest.approx(1e3 * red["busy_s"] / n)
+    # a CPU client's ops carry no scope: the SOCKET readers find nothing
+    for name in SOCKET_METRICS:
+        assert harness.metric_reader(name)(ctx) is None
+    assert read({**ctx, "step_events": [{"kind": "mixed"}]}) is None
+    assert read({**ctx, "trace": None}) is None
+
+
+def test_the_socket_metrics_read_their_scopes_per_decode_step():
+    pd, paths = _traced({f"op{i}": f"jit(step)/while/body/{s}/x:"
+                         for i, s in enumerate(
+                             ("socket.score", "socket.score/socket.hash",
+                              "socket.select", "socket.gather",
+                              "socket.gather", "socket.attend",
+                              "layer.mlp"))})
+    red = scopes.breakdown(pd, paths)
+    ctx = {"trace": red, "step_events": [{"kind": "decode"}]}
+    got = {m: harness.metric_reader(m)(ctx) for m in SOCKET_METRICS}
+    # 10 ns an op, one program in the window
+    assert got == pytest.approx({"socket_score_ms.decode": 2e-5,
+                                 "socket_select_ms.decode": 1e-5,
+                                 "socket_attend_ms.decode": 3e-5})
+    mixed = {**ctx, "step_events": [{"kind": "decode"}, {"kind": "mixed"}]}
+    assert all(harness.metric_reader(m)(mixed) is None
+               for m in SOCKET_METRICS)

@@ -18,6 +18,7 @@ import bisect
 import glob
 import os
 import re
+from pathlib import Path
 from typing import Dict, List, Optional, Sequence, Tuple
 
 Interval = Tuple[float, float]          # (start_ns, end_ns)
@@ -35,8 +36,12 @@ def find_xplane(trace_dir: str) -> str:
 
 
 def load(path: str):
+    """(``ProfileData``, the file's bytes) of one ``.xplane.pb``: the
+    bytes hold what ``ProfileData`` does not expose, each op's op-name
+    path (``scopes.op_paths``)."""
     from jax.profiler import ProfileData
-    return ProfileData.from_file(path)
+    raw = Path(path).read_bytes()
+    return ProfileData.from_serialized_xspace(raw), raw
 
 
 def _events(line) -> List[Tuple[str, float, float]]:
