@@ -83,7 +83,7 @@ class SocketSettings:
     score_dtype: str = "float32"  # "bfloat16" halves long-context buffers
     # "kvhead": per-q-head scores summed over the GQA group (paper-faithful)
     # "pooled": score once with the group-mean query (G x less score
-    #           compute/memory; §Perf fidelity numbers in EXPERIMENTS.md)
+    #           compute/memory)
     selection: str = "kvhead"
     # Pallas kernel routing for the decode path (models.backends.socket):
     # score via kernels/socket_score and attend the selected subset via
@@ -225,6 +225,10 @@ class ModelConfig:
     # q-chunked attention for the XLA train/prefill path: bounds the live
     # (chunk, S) logits buffer at long sequence lengths (0 = disabled).
     attn_q_chunk: int = 0
+    # rotary position embedding on q/k (False: no position embedding)
+    use_rope: bool = True
+    # scale of the attention logits (None: 1/sqrt(head_dim))
+    attention_multiplier: Optional[float] = None
     # --- mlp ------------------------------------------------------------
     mlp_activation: str = "swiglu"  # "swiglu" | "geglu"
     # --- moe ------------------------------------------------------------
@@ -234,6 +238,16 @@ class ModelConfig:
     moe_dispatch: str = "global"    # "global" | "batch" (see models.moe)
     capacity_factor: float = 1.25
     router_z_loss: float = 1e-3
+    # width of one routed expert (0: d_ff)
+    expert_d_ff: int = 0
+    # width of the shared SwiGLU expert every token passes (0: none)
+    shared_expert_d_ff: int = 0
+    # the experts this chip holds of the router's ``num_experts``:
+    # ``num_held_experts`` of them (0: all) from ``first_held_expert`` on.
+    # Only their part of the routed output is computed (expert
+    # parallelism without its exchange).
+    first_held_expert: int = 0
+    num_held_experts: int = 0
     # --- mamba (SSD) ------------------------------------------------------
     ssm_state: int = 128
     ssm_expand: int = 2
@@ -243,6 +257,12 @@ class ModelConfig:
     # --- io / modality ----------------------------------------------------
     input_mode: str = "tokens"      # "tokens" | "embeddings" (audio/vlm stub)
     tie_embeddings: bool = False
+    # token embeddings are scaled by this (None: sqrt(d_model))
+    embedding_multiplier: Optional[float] = None
+    # each block adds residual_multiplier x (mixer, then MLP) output
+    residual_multiplier: float = 1.0
+    # the output logits are divided by this
+    logits_scaling: float = 1.0
     # --- numerics ---------------------------------------------------------
     param_dtype: str = "float32"
     compute_dtype: str = "float32"
@@ -288,6 +308,14 @@ class ModelConfig:
     def ssm_heads(self) -> int:
         return self.d_inner // self.ssm_head_dim
 
+    @property
+    def expert_ff(self) -> int:
+        return self.expert_d_ff or self.d_ff
+
+    @property
+    def held_experts(self) -> int:
+        return self.num_held_experts or self.num_experts
+
     # ----------------------------------------------------------- validation
     def validate(self) -> None:
         """Config-time fused-kernel eligibility: every combination the
@@ -327,6 +355,12 @@ class ModelConfig:
                     f"({self.quest.page_size}) to divide "
                     f"serving.block_size ({self.serving.block_size}) so "
                     "each pool block carries whole min/max pages")
+        if self.first_held_expert < 0 or self.first_held_expert + \
+                self.held_experts > self.num_experts:
+            raise ValueError(
+                f"held experts {self.first_held_expert}.."
+                f"{self.first_held_expert + self.held_experts - 1} lie "
+                f"outside the router's {self.num_experts}")
         if self.use_ring_kernel and self.serving.block_size % 8:
             raise ValueError(
                 f"use_ring_kernel=True needs serving.block_size % 8 == 0 "
@@ -457,19 +491,23 @@ class ModelConfig:
                 n += d + 3 * d * ff
             elif spec.mlp == "moe":
                 n += d + d * self.num_experts              # norm + router
-                n += self.num_experts * 3 * d * ff
+                n += self.held_experts * 3 * d * self.expert_ff
+                n += 3 * d * self.shared_expert_d_ff
         n += d                                             # final norm
         return n
 
     def active_param_count(self) -> int:
-        """Params touched per token (MoE: only routed experts)."""
+        """Params touched per token (MoE: only routed experts; of held
+        experts, the ``k * held / num_experts`` a token routes to here on
+        average)."""
         if not self.uses_moe:
             return self.param_count()
         full_moe = sum(1 for s in self.layer_specs if s.mlp == "moe")
-        per_expert = 3 * self.d_model * self.d_ff
-        inactive = full_moe * (self.num_experts -
-                               self.num_experts_per_tok) * per_expert
-        return self.param_count() - inactive
+        per_expert = 3 * self.d_model * self.expert_ff
+        routed = self.num_experts_per_tok * self.held_experts / \
+            self.num_experts
+        inactive = full_moe * (self.held_experts - routed) * per_expert
+        return self.param_count() - int(round(inactive))
 
     # ------------------------------------------------------------- reduction
     def smoke(self) -> "ModelConfig":

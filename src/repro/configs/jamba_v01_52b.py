@@ -6,7 +6,7 @@ MoE 16 experts top-2.  [arXiv:2403.19887; hf].
 Layout follows the Jamba block: period-8 pattern with attention at index 4
 (1:7 attn:mamba ratio) and MoE replacing the dense MLP on every other
 layer.  SOCKET applies only to the attention layers (which hold all of
-Jamba's KV memory); Mamba layers decode from O(1) state — DESIGN.md §5.
+Jamba's KV memory); Mamba layers decode from O(1) state.
 """
 
 from repro.configs.base import LayerSpec, ModelConfig

@@ -52,7 +52,7 @@ sparsity budgets from the vector case.
 
 Local (sliding-window) layers decode from a ring buffer of ``window``
 slots — for gemma3's 5:1 pattern this keeps the long_500k cache bounded
-by the window on 52 of 62 layers (DESIGN.md §5).  On the continuous
+by the window on 52 of 62 layers.  On the continuous
 engine the ring lives in pool pages (cache-plan kind ``"ring"``): pass
 ``block_tables`` and the layer reads/writes through a
 :class:`~repro.models.backends.RingView`, whose circular page list
@@ -147,9 +147,18 @@ def _project_qkv(cfg: ModelConfig, params: Dict, x: jax.Array,
     if cfg.qk_norm:
         q = rmsnorm(params["q_norm"], q)
         k = rmsnorm(params["k_norm"], k)
-    q = apply_rope(q, positions, cfg.rope_theta)
-    k = apply_rope(k, positions, cfg.rope_theta)
+    if cfg.use_rope:
+        q = apply_rope(q, positions, cfg.rope_theta)
+        k = apply_rope(k, positions, cfg.rope_theta)
     return q, k, v
+
+
+def _scale(cfg: ModelConfig) -> float:
+    """The attention logits' scale: ``cfg.attention_multiplier``, else
+    1/sqrt(head_dim)."""
+    if cfg.attention_multiplier is None:
+        return 1.0 / np.sqrt(cfg.head_dim)
+    return cfg.attention_multiplier
 
 
 def _merge_heads(cfg: ModelConfig, params: Dict, ctx: jax.Array) -> jax.Array:
@@ -161,7 +170,7 @@ def _merge_heads(cfg: ModelConfig, params: Dict, ctx: jax.Array) -> jax.Array:
 # ------------------------------------------------------------------ train
 
 def _use_repeat_kv(h_eff: int, kv: int) -> bool:
-    """GQA sharding strategy (DESIGN.md §4): the grouped (kv, g) einsum
+    """GQA sharding strategy: the grouped (kv, g) einsum
     layout cannot be sharded when kv_heads doesn't divide the model axis —
     XLA then replicates *all* heads and the (B,H,T,S) logits explode.
     Repeating K/V up to the flat q-head axis keeps 16-way head sharding at
@@ -220,7 +229,7 @@ def attention_train(cfg: ModelConfig, params: Dict, x: jax.Array,
     g = h_eff // kv
     q, k, v = _project_qkv(cfg, params, x, positions)
     q = lsc(q, "batch", "seq", "q_heads", None)
-    scale = 1.0 / np.sqrt(cfg.head_dim)
+    scale = _scale(cfg)
 
     repeat_kv = _use_repeat_kv(h_eff, kv)
     if repeat_kv:
@@ -374,7 +383,7 @@ def attention_prefill_chunk(cfg: ModelConfig, params: Dict, x: jax.Array,
     h_eff = params["wq"].shape[1]
     kv = params["wk"].shape[1]
     g = h_eff // kv
-    scale = 1.0 / np.sqrt(hd)
+    scale = _scale(cfg)
     q, k, v = _project_qkv(cfg, params, x, positions)
     kc = jnp.swapaxes(k, 1, 2)                       # (B, KV, C, hd)
     vc = jnp.swapaxes(v, 1, 2)
@@ -509,7 +518,7 @@ def attention_decode(cfg: ModelConfig, params: Dict, x: jax.Array,
     h_eff = params["wq"].shape[1]
     kv = params["wk"].shape[1]
     g = h_eff // kv
-    scale = 1.0 / np.sqrt(hd)
+    scale = _scale(cfg)
     ragged = jnp.ndim(pos) == 1
     positions = jnp.reshape(pos, (b, 1)).astype(jnp.int32) if ragged \
         else jnp.full((b, 1), pos, jnp.int32)

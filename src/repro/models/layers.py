@@ -102,14 +102,20 @@ def embed_tokens(cfg: ModelConfig, params: Dict, tokens: jax.Array
                  ) -> jax.Array:
     cdt = jnp.dtype(cfg.compute_dtype)
     x = jnp.take(params["table"], tokens, axis=0).astype(cdt)
-    return lsc(x * jnp.asarray(np.sqrt(cfg.d_model), cdt),
-               "batch", "act_seq", "embed")
+    mult = cfg.embedding_multiplier
+    if mult is None:
+        mult = np.sqrt(cfg.d_model)
+    return lsc(x * jnp.asarray(mult, cdt), "batch", "act_seq", "embed")
 
 
 def lm_head(cfg: ModelConfig, params: Dict, x: jax.Array) -> jax.Array:
+    """Logits over the (padded) vocabulary, divided by
+    ``cfg.logits_scaling``."""
     cdt = jnp.dtype(cfg.compute_dtype)
     w = params.get("head")
     if w is None:
         w = params["table"].T
     logits = x.astype(cdt) @ w.astype(cdt)
+    if cfg.logits_scaling != 1.0:
+        logits = logits / jnp.asarray(cfg.logits_scaling, cdt)
     return lsc(logits, "batch", "seq", "vocab")

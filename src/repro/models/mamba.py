@@ -5,8 +5,11 @@ Chunked SSD forward for training/prefill (a port of the paper's
 inter-chunk recurrent state passing via ``lax.scan``), plus an O(1)-state
 decode step.  Layout: x (B, S, d_model) -> in_proj -> [z | xc | B | C | dt]
 -> causal depthwise conv over (xc,B,C) -> SSD -> gated RMSNorm -> out_proj.
+The projections are scoped ``mamba.proj``; the conv, the recurrence and
+the gated norm ``mamba.scan``.
 
-SOCKET does not apply to these layers (no KV cache) — DESIGN.md §5.
+SOCKET does not apply to these layers: they keep no K/V, only an O(1)
+state per sequence.
 """
 
 from __future__ import annotations
@@ -190,7 +193,58 @@ def mamba_train(cfg: ModelConfig, params: Dict, x: jax.Array,
     b, s, d = x.shape
     di, nh, st, conv_dim = _dims(cfg)
     cdt = jnp.dtype(cfg.compute_dtype)
-    proj = x.astype(cdt) @ params["in_proj"].astype(cdt)
+    with jax.named_scope("mamba.proj"):
+        proj = x.astype(cdt) @ params["in_proj"].astype(cdt)
+    with jax.named_scope("mamba.scan"):
+        y, z, conv_in, h_final = _scan_train(cfg, params, proj, h0, conv0,
+                                             last_index)
+        y = _gated_norm(cfg, params, y, z)
+    with jax.named_scope("mamba.proj"):
+        out = y @ params["out_proj"].astype(cdt)
+    if return_state:
+        with jax.named_scope("mamba.scan"):
+            conv_tail = _conv_tail(cfg, conv_in, conv0, last_index)
+        return out.astype(x.dtype), {"ssm": h_final,
+                                     "conv": conv_tail.astype(cdt)}
+    return out.astype(x.dtype)
+
+
+def _conv_tail(cfg: ModelConfig, conv_in: jax.Array, conv0, last_index):
+    """The last ``conv_width - 1`` conv inputs, ending at ``last_index``
+    where it is given.  With ``conv0`` the carried tail prefixes conv_in,
+    so a chunk whose real tokens number fewer than the tail reaches back
+    into the previous chunk's rows instead of zeroing them."""
+    kw = cfg.ssm_conv_width - 1
+    ext = conv_in if conv0 is None else jnp.concatenate(
+        [conv0.astype(conv_in.dtype), conv_in], axis=1)
+    off = 0 if conv0 is None else kw
+    if last_index is None:
+        if ext.shape[1] < kw:
+            return jnp.pad(ext, ((0, 0), (kw - ext.shape[1], 0), (0, 0)))
+        return ext[:, -kw:]
+    li = jnp.asarray(last_index, jnp.int32)
+    idx = off + li[:, None] - kw + 1 + jnp.arange(kw, dtype=jnp.int32)
+    tail = jnp.take_along_axis(ext, jnp.maximum(idx, 0)[..., None], axis=1)
+    return jnp.where((idx >= 0)[..., None], tail, 0)
+
+
+def _gated_norm(cfg: ModelConfig, params: Dict, y: jax.Array,
+                z: jax.Array) -> jax.Array:
+    """RMSNorm of y · silu(z) over the inner channels."""
+    cdt = jnp.dtype(cfg.compute_dtype)
+    y = y * jax.nn.silu(z)
+    var = jnp.mean(jnp.square(y.astype(jnp.float32)), axis=-1, keepdims=True)
+    return (y.astype(jnp.float32) * jax.lax.rsqrt(var + 1e-6) *
+            params["norm_scale"].astype(jnp.float32)).astype(cdt)
+
+
+def _scan_train(cfg: ModelConfig, params: Dict, proj: jax.Array, h0,
+                conv0, last_index):
+    """Conv and chunked SSD of a projected segment: (y, z, conv_in,
+    final SSD state)."""
+    b, s, _ = proj.shape
+    di, nh, st, conv_dim = _dims(cfg)
+    cdt = jnp.dtype(cfg.compute_dtype)
     z, xc, bmat, cmat, dt = _split_proj(cfg, proj)
 
     conv_in = jnp.concatenate([xc, bmat, cmat], axis=-1)  # (B,S,conv_dim)
@@ -212,36 +266,7 @@ def mamba_train(cfg: ModelConfig, params: Dict, x: jax.Array,
         h0 = h0.astype(jnp.float32)
     y, h_final = _ssd_chunked(cfg, xh, dt, a_coef, bg, cg, h0)
     y = y + xh * params["D"].astype(jnp.float32)[None, None, :, None]
-    y = y.reshape(b, s, di).astype(cdt)
-
-    # gated RMSNorm
-    y = y * jax.nn.silu(z)
-    var = jnp.mean(jnp.square(y.astype(jnp.float32)), axis=-1, keepdims=True)
-    y = (y.astype(jnp.float32) * jax.lax.rsqrt(var + 1e-6) *
-         params["norm_scale"].astype(jnp.float32)).astype(cdt)
-    out = y @ params["out_proj"].astype(cdt)
-    if return_state:
-        kw = cfg.ssm_conv_width - 1
-        # conv0 given: the carried tail prefixes conv_in, so a chunk whose
-        # real tokens number fewer than kw reaches back into the previous
-        # chunk's rows instead of zeroing them.
-        ext = conv_in if conv0 is None else jnp.concatenate(
-            [conv0.astype(conv_in.dtype), conv_in], axis=1)
-        off = 0 if conv0 is None else kw
-        if last_index is None:
-            conv_tail = ext[:, -kw:]
-            if ext.shape[1] < kw:
-                conv_tail = jnp.pad(
-                    ext, ((0, 0), (kw - ext.shape[1], 0), (0, 0)))
-        else:
-            idx = off + li[:, None] - kw + 1 + \
-                jnp.arange(kw, dtype=jnp.int32)
-            tail = jnp.take_along_axis(ext, jnp.maximum(idx, 0)[..., None],
-                                       axis=1)
-            conv_tail = jnp.where((idx >= 0)[..., None], tail, 0)
-        return out.astype(x.dtype), {"ssm": h_final,
-                                     "conv": conv_tail.astype(cdt)}
-    return out.astype(x.dtype)
+    return y.reshape(b, s, di).astype(cdt), z, conv_in, h_final
 
 
 def init_mamba_cache(cfg: ModelConfig, batch: int, dtype=None) -> Dict:
@@ -261,10 +286,23 @@ def mamba_cache_logical_axes() -> Dict:
 def mamba_decode(cfg: ModelConfig, params: Dict, x: jax.Array,
                  cache: Dict) -> Tuple[jax.Array, Dict]:
     """Single-token recurrent update.  x: (B, 1, d_model)."""
-    b = x.shape[0]
+    cdt = jnp.dtype(cfg.compute_dtype)
+    with jax.named_scope("mamba.proj"):
+        proj = x[:, 0].astype(cdt) @ params["in_proj"].astype(cdt)
+    with jax.named_scope("mamba.scan"):
+        y, z, new_cache = _scan_decode(cfg, params, proj, cache)
+        y = _gated_norm(cfg, params, y, z)
+    with jax.named_scope("mamba.proj"):
+        out = (y @ params["out_proj"].astype(cdt))[:, None]
+    return out.astype(x.dtype), new_cache
+
+
+def _scan_decode(cfg: ModelConfig, params: Dict, proj: jax.Array,
+                 cache: Dict):
+    """One token's conv and recurrent update: (y, z, new cache)."""
+    b = proj.shape[0]
     di, nh, st, conv_dim = _dims(cfg)
     cdt = jnp.dtype(cfg.compute_dtype)
-    proj = x[:, 0].astype(cdt) @ params["in_proj"].astype(cdt)  # (B, ·)
     z, xc, bmat, cmat, dt = _split_proj(cfg, proj)
 
     conv_in = jnp.concatenate([xc, bmat, cmat], axis=-1)        # (B, conv_dim)
@@ -289,13 +327,6 @@ def mamba_decode(cfg: ModelConfig, params: Dict, x: jax.Array,
         "bhd,bhs->bhds", xh * dt[..., None], bg)
     y = jnp.einsum("bhds,bhs->bhd", h, cg) + \
         xh * params["D"].astype(jnp.float32)[None, :, None]
-    y = y.reshape(b, di).astype(cdt)
-
-    y = y * jax.nn.silu(z)
-    var = jnp.mean(jnp.square(y.astype(jnp.float32)), axis=-1, keepdims=True)
-    y = (y.astype(jnp.float32) * jax.lax.rsqrt(var + 1e-6) *
-         params["norm_scale"].astype(jnp.float32)).astype(cdt)
-    out = (y @ params["out_proj"].astype(cdt))[:, None]
     new_cache = {"ssm": h,
                  "conv": hist[:, 1:].astype(cache["conv"].dtype)}
-    return out.astype(x.dtype), new_cache
+    return y.reshape(b, di).astype(cdt), z, new_cache

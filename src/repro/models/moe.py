@@ -3,9 +3,18 @@
 Dispatch avoids the O(N·E·C) one-hot tensor of the classic Switch
 implementation (impossible at llama4's E=128): token→expert assignments are
 argsorted by expert, positions-within-expert computed by a cumulative
-count, and tokens scattered into an (E, C, d) buffer.  Capacity overflow
-drops tokens (standard; ``capacity_factor`` controls slack) — the residual
-connection carries dropped tokens through unchanged.
+count, and tokens scattered into an (E, C, d) buffer.  In training,
+capacity overflow drops tokens (standard; ``capacity_factor`` controls
+slack) — the residual connection carries dropped tokens through
+unchanged.  Serving (decode steps and prefill chunks) is dropless: an
+expert takes a token at most once, so a capacity of one row per token
+holds any routing.
+
+Held experts: the router keeps its ``num_experts`` outputs, but a layer
+holds only ``cfg.held_experts`` of them (from ``cfg.first_held_expert``)
+and computes only their part of the routed output — one chip's share
+under expert parallelism, without the exchange.  A shared expert
+(``cfg.shared_expert_d_ff``) adds a SwiGLU MLP that every token passes.
 
 Parallelism (cfg.moe_parallelism):
 * ``"ep"`` — expert axis sharded over "model"; the scatter/gather between
@@ -29,20 +38,21 @@ import numpy as np
 from repro.configs.base import ModelConfig
 from repro.distributed.sharding import lsc
 from repro.models import param as pm
+from repro.models.layers import apply_mlp, init_mlp
 
 __all__ = ["init_moe", "apply_moe"]
 
 
 def init_moe(cfg: ModelConfig, rng: jax.Array) -> Dict:
-    d, ff, e = cfg.d_model, cfg.d_ff, cfg.num_experts
+    d, ff, e = cfg.d_model, cfg.expert_ff, cfg.held_experts
     k1, k2, k3, k4 = jax.random.split(rng, 4)
     dtype = jnp.dtype(cfg.param_dtype)
     s_in, s_out = 1.0 / np.sqrt(d), 1.0 / np.sqrt(ff)
     ff_axis = None if cfg.moe_parallelism == "ep" else "expert_mlp"
     e_axis = "experts" if cfg.moe_parallelism == "ep" else None
-    return {
-        "router": pm.normal(k1, (d, e), ("embed_w", None), stddev=s_in,
-                            dtype=jnp.float32),
+    params = {
+        "router": pm.normal(k1, (d, cfg.num_experts), ("embed_w", None),
+                            stddev=s_in, dtype=jnp.float32),
         "w_gate": pm.normal(k2, (e, d, ff), (e_axis, "embed_w", ff_axis),
                             stddev=s_in, dtype=dtype),
         "w_up": pm.normal(k3, (e, d, ff), (e_axis, "embed_w", ff_axis),
@@ -50,6 +60,10 @@ def init_moe(cfg: ModelConfig, rng: jax.Array) -> Dict:
         "w_down": pm.normal(k4, (e, ff, d), (e_axis, ff_axis, "embed_w"),
                             stddev=s_out, dtype=dtype),
     }
+    if cfg.shared_expert_d_ff:
+        params["shared"] = init_mlp(cfg.replace(d_ff=cfg.shared_expert_d_ff),
+                                    jax.random.fold_in(rng, 5))
+    return params
 
 
 def _router(cfg: ModelConfig, params: Dict, x2d: jax.Array):
@@ -74,17 +88,34 @@ def _router(cfg: ModelConfig, params: Dict, x2d: jax.Array):
 
 def _dispatch_ffn(cfg: ModelConfig, params: Dict, x2d: jax.Array,
                   capacity: int) -> Tuple[jax.Array, Dict]:
-    """Sort-dispatch + expert FFN + combine over flat tokens (N, d)."""
-    n, d = x2d.shape
-    e, k = cfg.num_experts, cfg.num_experts_per_tok
-    cdt = jnp.dtype(cfg.compute_dtype)
+    """Sort-dispatch + expert FFN + combine over flat tokens (N, d), for
+    the held experts."""
+    with jax.named_scope("moe.route"):
+        top_idx, top_probs, aux = _router(cfg, params, x2d)
 
-    top_idx, top_probs, aux = _router(cfg, params, x2d)
+    with jax.named_scope("moe.experts"):
+        y2d = _experts(cfg, params, x2d, top_idx, top_probs, capacity)
+    return y2d.astype(x2d.dtype), aux
+
+
+def _experts(cfg: ModelConfig, params: Dict, x2d: jax.Array,
+             top_idx: jax.Array, top_probs: jax.Array,
+             capacity: int) -> jax.Array:
+    n, d = x2d.shape
+    e, k = cfg.held_experts, cfg.num_experts_per_tok
+    cdt = jnp.dtype(cfg.compute_dtype)
 
     # ---- sort-based dispatch -------------------------------------------
     flat_expert = top_idx.reshape(n * k)                       # (NK,)
     flat_token = jnp.repeat(jnp.arange(n, dtype=jnp.int32), k)
     flat_prob = top_probs.reshape(n * k)
+    held = None
+    if e != cfg.num_experts:
+        # experts held elsewhere sort past the held ones (id e) and are
+        # never kept
+        local = flat_expert - cfg.first_held_expert
+        held = (local >= 0) & (local < e)
+        flat_expert = jnp.where(held, local, e)
 
     order = jnp.argsort(flat_expert)                           # stable
     sorted_expert = flat_expert[order]
@@ -94,14 +125,20 @@ def _dispatch_ffn(cfg: ModelConfig, params: Dict, x2d: jax.Array,
     counts = jnp.bincount(sorted_expert, length=e)             # (E,)
     starts = jnp.concatenate([jnp.zeros((1,), counts.dtype),
                               jnp.cumsum(counts)[:-1]])
-    pos_in_expert = jnp.arange(n * k) - starts[sorted_expert]
-    keep = pos_in_expert < capacity
+    if held is None:
+        pos_in_expert = jnp.arange(n * k) - starts[sorted_expert]
+        keep = pos_in_expert < capacity
+    else:
+        sorted_held = held[order]
+        sorted_expert = jnp.minimum(sorted_expert, e - 1)
+        pos_in_expert = jnp.arange(n * k) - starts[sorted_expert]
+        keep = sorted_held & (pos_in_expert < capacity)
     slot = jnp.where(keep, pos_in_expert, capacity - 1).astype(jnp.int32)
 
     # scatter tokens into (E, C, d) buffers (dropped tokens masked to 0).
     # The capacity dim shards over "data" — without this the buffers
     # replicate whenever E doesn't divide the model axis (mixtral: 8
-    # experts on 16-way TP => 32 GB/device/buffer; measured in §Perf).
+    # experts on 16-way TP => 32 GB/device/buffer).
     gathered = jnp.take(x2d, sorted_token, axis=0).astype(cdt)  # (NK, d)
     gathered = jnp.where(keep[:, None], gathered, 0.0)
     gathered = lsc(gathered, "moe_tokens", "embed")
@@ -126,37 +163,58 @@ def _dispatch_ffn(cfg: ModelConfig, params: Dict, x2d: jax.Array,
     expert_out = out_buf[sorted_expert, slot]                  # (NK, d)
     expert_out = jnp.where(keep[:, None], expert_out, 0.0)
     weighted = expert_out * sorted_prob[:, None].astype(cdt)
-    y2d = jnp.zeros((n, d), cdt).at[sorted_token].add(weighted)
-    return y2d.astype(x2d.dtype), aux
+    return jnp.zeros((n, d), cdt).at[sorted_token].add(weighted)
 
 
-def _capacity_for(cfg: ModelConfig, n: int, t: int) -> int:
+def _capacity_for(cfg: ModelConfig, n: int) -> int:
+    """Rows per expert for ``n`` training tokens under
+    ``capacity_factor``."""
     e, k = cfg.num_experts, cfg.num_experts_per_tok
-    if t == 1:
-        # decode: guarantee dropless routing (worst case: every token on
-        # the same expert); capacity drops would corrupt generation.
-        capacity = n * k
-    else:
-        capacity = int(np.ceil(n * k / e * cfg.capacity_factor))
-        capacity = max(capacity, 4)
+    capacity = int(np.ceil(n * k / e * cfg.capacity_factor))
+    capacity = max(capacity, 4)
     return min(capacity, n * k)
 
 
-def apply_moe(cfg: ModelConfig, params: Dict, x: jax.Array
-              ) -> Tuple[jax.Array, Dict]:
-    """MoE FFN.  x: (B, T, d) -> (y, aux_losses).
+def apply_shared(cfg: ModelConfig, params: Dict, x: jax.Array
+                 ) -> jax.Array:
+    """The shared expert's output for every token of x (B, T, d)."""
+    with jax.named_scope("moe.shared"):
+        return apply_mlp(cfg, params["shared"], x)
 
-    ``cfg.moe_dispatch``:
+
+def apply_moe(cfg: ModelConfig, params: Dict, x: jax.Array, *,
+              dropless: bool = False) -> Tuple[jax.Array, Dict]:
+    """MoE FFN: the held experts' routed output, plus the shared
+    expert's where there is one.  x: (B, T, d) -> (y, aux_losses).
+
+    ``dropless`` (the serving path's prefill chunks; decode, T = 1, is
+    dropless always): one global dispatch with a row per token for every
+    expert, so no routing drops a token.  Otherwise ``cfg.moe_dispatch``:
     * "global" — one sort over all B*T tokens (best load balancing; the
       token<->expert order crossing becomes global collective traffic);
     * "batch"  — vmapped per-batch-row dispatch: every gather/scatter stays
       inside the row's data shard, so the only cross-device traffic is the
       expert GEMM itself.  Measured 40x collective reduction on jamba
-      prefill_32k (§Perf iteration 2).  Capacity is per-row (slightly more
+      prefill_32k.  Capacity is per-row (slightly more
       drops under cross-row imbalance).
     """
+    y, aux = _apply_routed(cfg, params, x, dropless)
+    if cfg.shared_expert_d_ff:
+        y = y + apply_shared(cfg, params, x).astype(y.dtype)
+    return y, aux
+
+
+def _apply_routed(cfg: ModelConfig, params: Dict, x: jax.Array,
+                  dropless: bool) -> Tuple[jax.Array, Dict]:
     b, t, d = x.shape
-    if cfg.moe_dispatch == "alltoall" and t > 1:
+    if dropless or t == 1:
+        # serving: dropless under any routing — the worst case is every
+        # token on one expert, which takes each token at most once;
+        # capacity drops would corrupt generation.
+        y2d, aux = _dispatch_ffn(cfg, params, x.reshape(b * t, d), b * t)
+        return y2d.reshape(b, t, d).astype(x.dtype), aux
+    if cfg.moe_dispatch == "alltoall" and cfg.held_experts == \
+            cfg.num_experts:
         from repro.distributed import sharding as _shd
         mesh = _shd.current_mesh()
         model = dict(mesh.shape).get("model", 1) if mesh else 1
@@ -164,7 +222,7 @@ def apply_moe(cfg: ModelConfig, params: Dict, x: jax.Array
             return _apply_moe_alltoall(cfg, params, x, mesh)
         # fall through to global dispatch when not applicable
     if cfg.moe_dispatch == "batch" and b > 1 and t > 1:
-        capacity = _capacity_for(cfg, t, t)
+        capacity = _capacity_for(cfg, t)
 
         def row(x_row):
             return _dispatch_ffn(cfg, params, x_row, capacity)
@@ -174,7 +232,7 @@ def apply_moe(cfg: ModelConfig, params: Dict, x: jax.Array
         return y.astype(x.dtype), aux
 
     x2d = x.reshape(b * t, d)
-    capacity = _capacity_for(cfg, b * t, t)
+    capacity = _capacity_for(cfg, b * t)
     y2d, aux = _dispatch_ffn(cfg, params, x2d, capacity)
     return y2d.reshape(b, t, d).astype(x.dtype), aux
 
@@ -222,7 +280,7 @@ def _apply_moe_alltoall(cfg: ModelConfig, params: Dict, x: jax.Array,
     layer = 2 x (local tokens x k x d) bf16 — the information-theoretic
     minimum for EP — instead of the replicate+all-reduce XLA emits for a
     global order-crossing scatter (measured 32 GB f32 per layer on jamba
-    prefill_32k; see EXPERIMENTS.md §Perf).
+    prefill_32k).
     """
     from jax.sharding import PartitionSpec as P
 

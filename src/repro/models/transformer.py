@@ -50,6 +50,27 @@ def _init_block(cfg: ModelConfig, rng: jax.Array, spec: LayerSpec) -> Dict:
     return params
 
 
+def _residual(cfg: ModelConfig, x: jax.Array, h: jax.Array) -> jax.Array:
+    """x + residual_multiplier · h (the mixer's and the MLP's branch)."""
+    if cfg.residual_multiplier != 1.0:
+        h = h * jnp.asarray(cfg.residual_multiplier, h.dtype)
+    return x + h
+
+
+def _mlp_branch(cfg: ModelConfig, params: Dict, spec: LayerSpec,
+                x: jax.Array, dropless: bool = False):
+    """(the block's MLP output, or None where it has none; the MoE's aux
+    losses, or None)."""
+    if spec.mlp == "dense":
+        return apply_mlp(cfg, params["mlp"],
+                         rmsnorm(params["norm_mlp"], x)), None
+    if spec.mlp == "moe":
+        return moe_mod.apply_moe(cfg, params["moe"],
+                                 rmsnorm(params["norm_mlp"], x),
+                                 dropless=dropless)
+    return None, None
+
+
 def _block_train(cfg: ModelConfig, params: Dict, spec: LayerSpec,
                  x: jax.Array, positions: jax.Array):
     aux = {"moe_lb_loss": jnp.float32(0), "moe_z_loss": jnp.float32(0)}
@@ -59,14 +80,12 @@ def _block_train(cfg: ModelConfig, params: Dict, spec: LayerSpec,
                                  spec.attn_type)
     else:
         h = mb.mamba_train(cfg, params["mamba"], h)
-    x = x + h
-    if spec.mlp == "dense":
-        x = x + apply_mlp(cfg, params["mlp"],
-                          rmsnorm(params["norm_mlp"], x))
-    elif spec.mlp == "moe":
-        y, aux = moe_mod.apply_moe(cfg, params["moe"],
-                                   rmsnorm(params["norm_mlp"], x))
-        x = x + y
+    x = _residual(cfg, x, h)
+    y, moe_aux = _mlp_branch(cfg, params, spec, x)
+    if y is not None:
+        x = _residual(cfg, x, y)
+    if moe_aux is not None:
+        aux = moe_aux
     return lsc(x, "batch", "act_seq", "embed"), aux
 
 
@@ -81,13 +100,10 @@ def _block_prefill(cfg: ModelConfig, params: Dict, spec: LayerSpec,
     else:
         h, cache = mb.mamba_train(cfg, params["mamba"], h,
                                   return_state=True, last_index=last_index)
-    x = x + h
-    if spec.mlp == "dense":
-        x = x + apply_mlp(cfg, params["mlp"], rmsnorm(params["norm_mlp"], x))
-    elif spec.mlp == "moe":
-        y, _ = moe_mod.apply_moe(cfg, params["moe"],
-                                 rmsnorm(params["norm_mlp"], x))
-        x = x + y
+    x = _residual(cfg, x, h)
+    y, _ = _mlp_branch(cfg, params, spec, x)
+    if y is not None:
+        x = _residual(cfg, x, y)
     return lsc(x, "batch", "act_seq", "embed"), cache
 
 
@@ -96,7 +112,7 @@ def _block_prefill_chunk(cfg: ModelConfig, params: Dict, spec: LayerSpec,
                          bt_row: jax.Array, slot: jax.Array,
                          history: jax.Array, last_index: jax.Array):
     """One block's share of one prefill chunk, writing the pool in place
-    (see :func:`prefill_chunk`)."""
+    (see :func:`prefill_chunk`).  An MoE MLP dispatches dropless."""
     h = rmsnorm(params["norm_mix"], x)
     if spec.kind == "attn":
         h, cache = attn.attention_prefill_chunk(
@@ -108,24 +124,23 @@ def _block_prefill_chunk(cfg: ModelConfig, params: Dict, spec: LayerSpec,
         # (padding past last_index is exact identity steps), write back.
         # The FIRST chunk starts from zeros — the slot row still holds the
         # previous occupant's state (nothing scrubs it on free).
-        first = jnp.asarray(history, jnp.int32) == 0
-        h0 = jnp.where(first, 0.0, cache["ssm"][slot][None])
-        conv0 = jnp.where(first, 0.0, cache["conv"][slot][None])
+        with jax.named_scope("mamba.state"):
+            first = jnp.asarray(history, jnp.int32) == 0
+            h0 = jnp.where(first, 0.0, cache["ssm"][slot][None])
+            conv0 = jnp.where(first, 0.0, cache["conv"][slot][None])
         h, st = mb.mamba_train(cfg, params["mamba"], h, h0=h0, conv0=conv0,
                                return_state=True, last_index=last_index)
-        cache = {
-            "ssm": cache["ssm"].at[slot].set(
-                st["ssm"][0].astype(cache["ssm"].dtype)),
-            "conv": cache["conv"].at[slot].set(
-                st["conv"][0].astype(cache["conv"].dtype)),
-        }
-    x = x + h
-    if spec.mlp == "dense":
-        x = x + apply_mlp(cfg, params["mlp"], rmsnorm(params["norm_mlp"], x))
-    elif spec.mlp == "moe":
-        y, _ = moe_mod.apply_moe(cfg, params["moe"],
-                                 rmsnorm(params["norm_mlp"], x))
-        x = x + y
+        with jax.named_scope("mamba.state"):
+            cache = {
+                "ssm": cache["ssm"].at[slot].set(
+                    st["ssm"][0].astype(cache["ssm"].dtype)),
+                "conv": cache["conv"].at[slot].set(
+                    st["conv"][0].astype(cache["conv"].dtype)),
+            }
+    x = _residual(cfg, x, h)
+    y, _ = _mlp_branch(cfg, params, spec, x, dropless=True)
+    if y is not None:
+        x = _residual(cfg, x, y)
     return x, cache
 
 
@@ -140,16 +155,14 @@ def _block_decode(cfg: ModelConfig, params: Dict, spec: LayerSpec,
                                          spec.attn_type,
                                          block_tables=block_tables)
     else:
+        # scoped mamba.proj / mamba.scan inside
         h, cache = mb.mamba_decode(cfg, params["mamba"], h, cache)
-    x = x + h
+    x = _residual(cfg, x, h)
     with jax.named_scope("layer.mlp"):
-        if spec.mlp == "dense":
-            x = x + apply_mlp(cfg, params["mlp"],
-                              rmsnorm(params["norm_mlp"], x))
-        elif spec.mlp == "moe":
-            y, _ = moe_mod.apply_moe(cfg, params["moe"],
-                                     rmsnorm(params["norm_mlp"], x))
-            x = x + y
+        # an MoE's router, experts and shared expert are scoped moe.*
+        y, _ = _mlp_branch(cfg, params, spec, x, dropless=True)
+        if y is not None:
+            x = _residual(cfg, x, y)
     return x, cache
 
 

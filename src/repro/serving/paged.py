@@ -129,14 +129,15 @@ def keep_state_rows(cfg: ModelConfig, before, after, active: jax.Array):
     leaves take the post-step value only where ``active`` (``(B,)``
     bool) marks a runnable request; paged/ring leaves pass through
     untouched (their inactive-slot writes already land in the trash
-    page)."""
+    page).  Scoped ``mamba.state``: it rewrites every state row."""
     def sel(h, old, new):
         if h.kind != "state":
             return new
         return {name: jnp.where(
             active.reshape((-1,) + (1,) * (new[name].ndim - 1)),
             new[name], old[name]) for name in new}
-    return _map_slots(cfg, sel, before, after)
+    with jax.named_scope("mamba.state"):
+        return _map_slots(cfg, sel, before, after)
 
 
 def clone_block(cfg: ModelConfig, pages, src, dst, keep_tokens):
