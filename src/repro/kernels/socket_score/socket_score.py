@@ -13,11 +13,13 @@ reaches HBM.
 
 Packed words (``bits_storage="packed"``) arrive in the key-major order
 ``(B, N, KVH, W)`` in which the block-table gather of the paged pool
-writes them, viewed as ``(B, N*KVH, W)`` rows of (key, head).  Each
-128-row slice is transposed in VMEM (XLU) to ``(W, 128)``, so word ``w``
-of 8 slices is one dense ``(8, 128)`` vreg of 1,024 (key, head) pairs;
-lane ``j`` holds head ``j % KVH``.  With ``x = u / tau`` each plane's
-log probability is
+writes them, viewed as ``(B, N*KVH, W)`` rows of (key, head); a row
+may be wider than its ``ceil(L*P / 32)`` words of sign bits (the bits
+leaf pads it to whole lanes) and the rest is never read.  Each 128-row
+slice's words are transposed in VMEM (XLU) to ``(words, 128)``, so
+word ``w`` of 8 slices is one dense ``(8, 128)`` vreg of 1,024 (key,
+head) pairs; lane ``j`` holds head ``j % KVH``.  With ``x = u / tau``
+each plane's log probability is
 
     log p_gli(S) = -softplus(-2 S x_gli),   S = ±1,
 
@@ -64,6 +66,12 @@ INT8_BLOCK_N = 512              # keys per ±1-byte grid step
 LANES = 128
 
 
+def _words_read(num_tables: int, num_planes: int) -> int:
+    """Leading words of a row that hold the ``L*P`` sign bits; the rest
+    of the row (alignment bits, lane padding) is never read."""
+    return -(-num_tables * num_planes // 32)
+
+
 def _table_group(p: int):
     """(tables, words) of one group: the tables of whole 32-bit words."""
     return math.lcm(p, 32) // p, math.lcm(p, 32) // 32
@@ -83,7 +91,7 @@ def _packed_kernel(len_ref, words_ref, logp_ref, *refs, num_tables: int,
     vnorm_ref, out_ref, words = refs if len(refs) == 3 else (None, *refs)
     b, tile = pl.program_id(0), pl.program_id(1)
     live = tile * block_n < len_ref[b]
-    num_words = words_ref.shape[2]
+    num_words = _words_read(num_tables, num_planes)
     p = num_planes
     per_group, words_per_group = _table_group(p)
     full_groups, tail = divmod(num_tables, per_group)
@@ -119,7 +127,7 @@ def _packed_kernel(len_ref, words_ref, logp_ref, *refs, num_tables: int,
             for sl in range(slices):
                 start = pl.multiple_of((c * slices + sl) * LANES, LANES)
                 words[pl.ds(sl, num_words, stride=slices), :] = \
-                    words_ref[0, pl.ds(start, LANES), :].T
+                    words_ref[0, pl.ds(start, LANES), :num_words].T
             score = jnp.zeros((slices, LANES), jnp.float32)
             score = jax.lax.fori_loop(
                 0, full_groups, lambda j, sc: tables(sc, j, per_group),
@@ -198,7 +206,8 @@ def _packed_call(words, u, vnorm, length, *, num_planes, tau, block_n,
     # many group members make the coefficient block outgrow the 16 MiB
     # default
     f32_rows = block_n * kvh + logp.shape[1] + 2 * rows
-    vmem = 2 * 4 * LANES * f32_rows + 4 * LANES * w * chunk * kvh // LANES
+    nw = _words_read(l, p)
+    vmem = 2 * 4 * LANES * f32_rows + 4 * LANES * nw * chunk * kvh // LANES
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -207,7 +216,7 @@ def _packed_call(words, u, vnorm, length, *, num_planes, tau, block_n,
             in_specs=in_specs,
             out_specs=pl.BlockSpec((1, rows, LANES),
                                    lambda r, i, lens: (r, i, 0)),
-            scratch_shapes=[pltpu.VMEM((w * chunk * kvh // LANES, LANES),
+            scratch_shapes=[pltpu.VMEM((nw * chunk * kvh // LANES, LANES),
                                        jnp.uint32)],
         ),
         out_shape=jax.ShapeDtypeStruct((b, n * kvh // LANES, LANES),
@@ -257,7 +266,8 @@ def socket_score_pallas(bits: jax.Array, u: jax.Array,
     Args:
       bits:  uint32 (B, N, KVH, W) packed sign words, key-major (N a
              multiple of the tile, or one tile; KVH dividing 128, and
-             N*KVH a multiple of 128), or int8 (BH, N, L*P) ±1 plane
+             N*KVH a multiple of 128; words past the ``L*P`` bits are
+             not read), or int8 (BH, N, L*P) ±1 plane
              bytes (``bits_storage="int8"`` — format inferred from the
              dtype).
       u:     f32 (B, KVH, G, L, P) query soft-hash; (BH, G, L, P) with

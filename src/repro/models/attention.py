@@ -292,8 +292,7 @@ def cache_logical_axes(cfg: ModelConfig, attn_type: str,
                        long_context: bool = False) -> Dict:
     """Logical axis names mirroring :func:`init_attention_cache`."""
     if attn_type == "local":
-        return {name: ("cache_batch", "cache_heads", "cache_seq") +
-                (None,) * len(s.suffix)
+        return {name: s.axes("cache_seq")
                 for name, s in backends.kv_leaf_specs(cfg).items()}
     seq = "cache_seq_cp" if long_context else "cache_seq"
     return backends.get_backend(cfg.attention_backend).cache_axes(cfg, seq)
@@ -351,7 +350,7 @@ def attention_prefill(cfg: ModelConfig, params: Dict, x: jax.Array,
 
 def attention_prefill_chunk(cfg: ModelConfig, params: Dict, x: jax.Array,
                             positions: jax.Array, attn_type: str,
-                            cache: Dict, bt_row: jax.Array,
+                            cache: Dict, layer, bt_row: jax.Array,
                             history: jax.Array, last_index: jax.Array,
                             ) -> Tuple[jax.Array, Dict]:
     """One **prefix-extension** prefill chunk straight against the pool.
@@ -360,7 +359,8 @@ def attention_prefill_chunk(cfg: ModelConfig, params: Dict, x: jax.Array,
     ``prefill_chunk`` tokens at a time; this is one attention layer's
     share of one chunk.  ``x`` is ``(1, C, d)`` (one chunk per engine
     iteration), ``positions`` the absolute token positions ``history +
-    [0, C)``, ``cache`` this layer's *pool* leaves, ``bt_row`` the
+    [0, C)``, ``cache`` the layer-stacked *pool* leaves this layer is
+    index ``layer`` of (written in place), ``bt_row`` the
     request's trash-padded block-id row, ``history`` the number of
     prompt tokens already committed by earlier chunks (a traced scalar —
     one compile covers every chunk index), and ``last_index`` the
@@ -400,14 +400,16 @@ def attention_prefill_chunk(cfg: ModelConfig, params: Dict, x: jax.Array,
         # fallen out of the window) mask out.  Gathered BEFORE the chunk
         # writes, so early chunk queries still see positions a later
         # in-chunk token will recycle.
-        ring_k = backends.gather_block_leaf(cache["k"], bt_row[None, :rb])
-        ring_v = backends.gather_block_leaf(cache["v"], bt_row[None, :rb])
+        ring_k = backends.gather_block_leaf(cache["k"], bt_row[None, :rb],
+                                            layer)
+        ring_v = backends.gather_block_leaf(cache["v"], bt_row[None, :rb],
+                                            layer)
         kvd = backends.kv_quant_mode(cfg)
         if kvquant.is_quantized(kvd):
             ring_k = kvquant.dequantize(ring_k, backends.gather_block_leaf(
-                cache["k_scale"], bt_row[None, :rb]))
+                cache["k_scale"], bt_row[None, :rb], layer))
             ring_v = kvquant.dequantize(ring_v, backends.gather_block_leaf(
-                cache["v_scale"], bt_row[None, :rb]))
+                cache["v_scale"], bt_row[None, :rb], layer))
         sl = jnp.arange(cap, dtype=jnp.int32)
         lp = jnp.asarray(history, jnp.int32) - 1
         rp = lp - ((lp - sl) % cap)                          # (cap,)
@@ -438,7 +440,7 @@ def attention_prefill_chunk(cfg: ModelConfig, params: Dict, x: jax.Array,
                 vals["k"], vals["k_scale"] = kvquant.quantize(vals["k"], kvd)
                 vals["v"], vals["v_scale"] = kvquant.quantize(vals["v"], kvd)
             return {name: backends.ring_write_page(
-                cc[name], blk, pos, vals[name], block_size=bs,
+                cc[name], layer, blk, pos, vals[name], block_size=bs,
                 ring_blocks=rb, window=w) for name in cc}
 
         ring_names = [n for n in ("k", "v", "k_scale", "v_scale")
@@ -457,31 +459,32 @@ def attention_prefill_chunk(cfg: ModelConfig, params: Dict, x: jax.Array,
         mini = backend.prefill_build(cfg, params, mini, kc, vc)
         block0 = jnp.asarray(history, jnp.int32) // bs
         spec = backend.cache_spec(cfg)
+        # row-granular commit: supports a mid-page chunk start (prefix-
+        # cache hit resuming past the shared tail page) and routes
+        # final-chunk padding to the trash page.
+        by_row = [name for name in cache if spec[name].granularity == 1]
+        cache.update(backends.write_chunk_rows(
+            {name: cache[name] for name in by_row}, layer,
+            {name: mini[name] for name in by_row}, bt_row, history, li[0]))
         for name in cache:
-            if spec[name].granularity == 1:
-                # row-granular commit: supports a mid-page chunk start
-                # (prefix-cache hit resuming past the shared tail page)
-                # and routes final-chunk padding to the trash page.
-                cache[name] = backends.write_chunk_rows(
-                    cache[name], mini[name], bt_row, history, li[0])
-            else:
+            if spec[name].granularity != 1:
                 # page-granular metadata (Quest min/max): whole-block
-                # scatter — chunk starts are page-aligned here (the
+                # writes — chunk starts are page-aligned here (the
                 # prefix cache only shares page-aligned prefixes when
                 # any leaf has granularity > 1).
                 cache[name] = backends.write_chunk_blocks(
-                    cache[name], mini[name], bt_row, block0)
+                    cache[name], layer, mini[name], bt_row, block0)
         # prefix-extension attend over the paged logical view: the chunk's
         # own rows were just committed, so the causal si <= ti mask covers
         # both the earlier chunks' pages and in-chunk causality; trash
         # rows sit past every real query's position.
-        k_full = backends.gather_block_leaf(cache["k"], bt_row[None])
-        v_full = backends.gather_block_leaf(cache["v"], bt_row[None])
+        k_full = backends.gather_block_leaf(cache["k"], bt_row[None], layer)
+        v_full = backends.gather_block_leaf(cache["v"], bt_row[None], layer)
         if kvquant.is_quantized(backends.kv_quant_mode(cfg)):
             k_full = kvquant.dequantize(k_full, backends.gather_block_leaf(
-                cache["k_scale"], bt_row[None]))
+                cache["k_scale"], bt_row[None], layer))
             v_full = kvquant.dequantize(v_full, backends.gather_block_leaf(
-                cache["v_scale"], bt_row[None]))
+                cache["v_scale"], bt_row[None], layer))
         ctx = _attn_chunk(cfg, qg, jnp.swapaxes(k_full, 1, 2),
                           jnp.swapaxes(v_full, 1, 2), history, "global",
                           scale, repeat_kv=False)
@@ -494,12 +497,14 @@ def attention_prefill_chunk(cfg: ModelConfig, params: Dict, x: jax.Array,
 
 def attention_decode(cfg: ModelConfig, params: Dict, x: jax.Array,
                      cache: Dict, pos: jax.Array, attn_type: str,
-                     block_tables: Optional[jax.Array] = None,
+                     layer, block_tables: Optional[jax.Array] = None,
                      ) -> Tuple[jax.Array, Dict]:
     """One decode step.  x: (B, 1, d); pos: scalar int32 (current index)
     OR a ``(B,)`` int32 vector of per-request indices (ragged serving
     batch — each row of the batch sits at its own context length).
 
+    ``cache`` holds the layer-stacked leaves this layer is index
+    ``layer`` of; the new token's rows are written in place.
     ``block_tables``: when given (``(B, blocks_per_seq)`` physical block
     ids), ``cache`` is the serving engine's **page pool** rather than a
     contiguous cache — the backend appends and attends through a
@@ -529,62 +534,40 @@ def attention_decode(cfg: ModelConfig, params: Dict, x: jax.Array,
 
     if attn_type == "local":
         ring_fused = block_tables is not None and cfg.use_ring_kernel
-        kvd = backends.kv_quant_mode(cfg)
-        quantized = kvquant.is_quantized(kvd)
+        spec = backends.kv_leaf_specs(cfg)
         if block_tables is not None:
             # paged ring: the block table's first ring_blocks entries are
             # a circular page list (plan kind "ring"); the bounded ring
             # view (window-sized) then runs the same attention math.
             rb, cap = cfg.ring_geometry()
-            spec = backends.kv_leaf_specs(cfg)
             view = backends.RingView(
-                {name: cache[name] for name in spec},
-                spec, block_tables,
-                cfg.serving.block_size, rb, cfg.sliding_window)
+                cache, spec, block_tables, cfg.serving.block_size, rb,
+                cfg.sliding_window, layer=layer)
             backends.write_token_kv(cfg, view, pos, k_new[:, 0],
                                     v_new[:, 0])
-            cache = dict(cache)
-            cache.update(view.arrays)
-            if ring_fused:
-                # fused Pallas ring pass: stream the circular page list
-                # straight from the pool, window mask (and dequant, for
-                # quantized pages) in-kernel — the leaf() gather below
-                # never materializes.
-                from repro.kernels.paged_attention import ops as pa_ops
-                ctx = pa_ops.paged_ring_attend(
-                    qg, cache["k"], cache["v"], block_tables[:, :rb],
-                    pos=pos, window=cfg.sliding_window,
-                    softcap=cfg.attn_logit_softcap, scale=scale,
-                    k_scale=cache.get("k_scale"),
-                    v_scale=cache.get("v_scale"))
-                backends.record_fused("paged_ring", ctx.shape)
-            else:
-                ring_k = backends.dequant_leaf(cfg, view, "k")
-                ring_v = backends.dequant_leaf(cfg, view, "v")
         else:
-            cap = cache["k"].shape[2]
-            slot = pos % cap
-            cache = dict(cache)
-            vals = {"k": jnp.swapaxes(k_new, 1, 2),
-                    "v": jnp.swapaxes(v_new, 1, 2)}       # (B,KV,1,hd)
-            if quantized:
-                vals["k"], vals["k_scale"] = kvquant.quantize(vals["k"], kvd)
-                vals["v"], vals["v_scale"] = kvquant.quantize(vals["v"], kvd)
-            for name, val in vals.items():
-                a = cache[name]
-                if ragged:
-                    bidx = jnp.arange(b)
-                    cache[name] = a.at[bidx, :, slot].set(
-                        val[:, :, 0].astype(a.dtype))
-                else:
-                    cache[name] = jax.lax.dynamic_update_slice(
-                        a, val.astype(a.dtype),
-                        (0, 0, slot) + (0,) * (a.ndim - 3))
-            ring_k, ring_v = cache["k"], cache["v"]
-            if quantized:
-                ring_k = kvquant.dequantize(ring_k, cache["k_scale"])
-                ring_v = kvquant.dequantize(ring_v, cache["v_scale"])
-        if not ring_fused:
+            # static ring of cap slots: token pos lives at slot pos % cap
+            cap = cache["k"].shape[3]
+            view = backends.ContiguousView(cache, spec, layer=layer)
+            backends.write_token_kv(cfg, view, pos % cap, k_new[:, 0],
+                                    v_new[:, 0])
+        if ring_fused:
+            # fused Pallas ring pass: stream the circular page list
+            # straight from the pool, window mask (and dequant, for
+            # quantized pages) in-kernel — the leaf() gather below never
+            # materializes.
+            from repro.kernels.paged_attention import ops as pa_ops
+            pages = view.layer_arrays()
+            ctx = pa_ops.paged_ring_attend(
+                qg, pages["k"], pages["v"], block_tables[:, :rb],
+                pos=pos, window=cfg.sliding_window,
+                softcap=cfg.attn_logit_softcap, scale=scale,
+                k_scale=pages.get("k_scale"),
+                v_scale=pages.get("v_scale"))
+            backends.record_fused("paged_ring", ctx.shape)
+        else:
+            ring_k = backends.dequant_leaf(cfg, view, "k")
+            ring_v = backends.dequant_leaf(cfg, view, "v")
             # ring-slot absolute positions; invalid slots masked out.  The
             # window bound is a no-op when cap <= window (static path) but
             # trims page-aligned rings that hold slightly more than a
@@ -608,16 +591,16 @@ def attention_decode(cfg: ModelConfig, params: Dict, x: jax.Array,
         backend = backends.get_backend(cfg.attention_backend)
         spec = backend.cache_spec(cfg)
         if block_tables is None:
-            view = backends.ContiguousView(cache, spec)
+            view = backends.ContiguousView(cache, spec, layer=layer)
         else:
             view = backends.PagedView(cache, spec, block_tables,
-                                      block_size=cfg.serving.block_size)
+                                      block_size=cfg.serving.block_size,
+                                      layer=layer)
         backend.append(cfg, params, view, jnp.swapaxes(k_new, 1, 2),
                        jnp.swapaxes(v_new, 1, 2), pos)
         ctx = backend.attend(cfg, params, qg, view, length=pos + 1,
                              scale=scale)
-        cache = view.arrays
 
     ctx = jnp.transpose(ctx, (0, 3, 1, 2, 4)).reshape(b, 1, h_eff, hd)
     with jax.named_scope("layer.proj"):
-        return _merge_heads(cfg, params, ctx.astype(x.dtype)), cache
+        return _merge_heads(cfg, params, ctx.astype(x.dtype)), view.arrays

@@ -19,8 +19,13 @@ three operations the model/engine needs:
 the standard ``(B, KVH, N, ...)`` cache; :class:`PagedView` wraps the
 serving engine's page pool ``(num_blocks, KVH, block_size, ...)`` plus a
 per-request block table, translating logical token indices through the
-table.  A backend whose ``attend`` reads full K/V only through
-``gather_rows`` (top-k selection) is **paged-capable**
+table.  Either view holds the **layer-stacked** leaves (a leading layer
+axis, as the layer scan carries them) and the index of its layer: reads
+gather with the layer as the first index and writes update the new rows
+in place (whole pages on the pool, :func:`update_pages`), so no layer's
+slice is ever cut out of the stack and written back.  A backend whose
+``attend`` reads full K/V only through ``gather_rows`` (top-k
+selection) is **paged-capable**
 (``supports_paged``): the engine then never materializes contiguous K/V
 views — per step it moves only the small metadata leaves plus
 ``O(top_k)`` K/V rows.
@@ -51,19 +56,65 @@ __all__ = ["LeafSpec", "LayerCacheSpec", "KVView", "ContiguousView",
            "dequant_leaf", "effective_keys", "kv_scales_of"]
 
 
-def gather_block_leaf(pages: jax.Array, bt: jax.Array) -> jax.Array:
+def gather_block_leaf(pages: jax.Array, bt: jax.Array,
+                      layer=None) -> jax.Array:
     """Materialize a paged leaf's logical view through a block table:
     ``(NB, KVH, rows_pb, *rest), (B, nb) -> (B, KVH, nb*rows_pb, *rest)``.
+    With ``layer``, ``pages`` is a layer-stacked leaf ``(L, NB, ...)``
+    read at that layer by the same single gather.
 
     The one implementation of the pool layout's logical flattening —
     shared by :meth:`PagedView.leaf`, the serving engine's dense
     fallback (``serving.paged.gather_views``), and the fused paged
     kernel's test oracle."""
     b, nb = bt.shape
-    g = pages[bt]                          # (B, nb, KVH, rows_pb, *rest)
+    g = pages[bt] if layer is None else pages[layer, bt]
     g = jnp.moveaxis(g, 2, 1)              # (B, KVH, nb, rows_pb, *rest)
-    return g.reshape(b, pages.shape[1], nb * pages.shape[2],
-                     *pages.shape[3:])
+    return g.reshape(b, g.shape[1], nb * g.shape[3], *g.shape[4:])
+
+
+def set_rows(a: jax.Array, layer, row, value: jax.Array) -> jax.Array:
+    """Write ``value[b]`` ``(KVH, *suffix)`` at ``a[layer, b, :, row[b]]``
+    of a layer-stacked contiguous cache ``(L, B, KVH, rows, *suffix)``:
+    one dynamic-update-slice per request, which XLA does in place."""
+    value = value.astype(a.dtype)
+    for b in range(value.shape[0]):
+        a = jax.lax.dynamic_update_slice(
+            a, value[b][None, None, :, None],
+            (layer, b, 0, row[b]) + (0,) * (a.ndim - 4))
+    return a
+
+
+def update_pages(a: jax.Array, layer, blk, fn) -> jax.Array:
+    """Replace page ``a[layer, blk[i]]`` ``(KVH, rows_pb, *suffix)`` of a
+    layer-stacked pool leaf by ``fn(i, page)``, for each request ``i``.
+
+    Each page is read and written whole, at the same place: the
+    tile-aligned form the TPU (and XLA on the CPU) updates in place in a
+    loop-carried leaf.  Writing a single row, or scattering over the
+    leaf's non-adjacent axes, makes the compiler copy or relayout the
+    whole leaf instead."""
+    pages = a[layer, blk]                  # (B, KVH, rows_pb, *suffix)
+    return set_pages(a, layer, blk, jnp.stack(
+        [fn(i, pages[i]) for i in range(blk.shape[0])]))
+
+
+def put_row(page: jax.Array, row, value: jax.Array) -> jax.Array:
+    """``page`` ``(KVH, rows_pb, *suffix)`` with row ``row`` set to
+    ``value`` ``(KVH, *suffix)``."""
+    hit = (jnp.arange(page.shape[1]) == row).reshape(
+        1, -1, *([1] * (page.ndim - 2)))
+    return jnp.where(hit, value[:, None].astype(page.dtype), page)
+
+
+def set_pages(a: jax.Array, layer, blk, pages: jax.Array) -> jax.Array:
+    """Write whole pages ``pages[i]`` ``(KVH, rows_pb, *suffix)`` at
+    ``a[layer, blk[i]]`` of a layer-stacked pool leaf, in place."""
+    pages = pages.astype(a.dtype)
+    for i in range(pages.shape[0]):
+        a = jax.lax.dynamic_update_slice(
+            a, pages[i][None, None], (layer, blk[i]) + (0,) * (a.ndim - 2))
+    return a
 
 
 @dataclasses.dataclass(frozen=True)
@@ -75,15 +126,38 @@ class LeafSpec:
     statistics.  A capacity of ``N`` tokens allocates ``ceil(N /
     granularity)`` rows.  ``dtype is None`` means "use the cache compute
     dtype"; ``fill`` is the zero/identity init value (±inf for min/max).
+
+    ``per_head=False`` folds the KV heads into the row: the head axis has
+    length 1 and ``suffix`` leads with the heads, so a pool page is
+    ``(1, rows, KVH, ...)`` — key-major, each token's heads side by side.
     """
 
     suffix: Tuple[int, ...] = ()
     dtype: Optional[Any] = None
     granularity: int = 1
     fill: float = 0.0
+    per_head: bool = True
 
     def rows(self, capacity: int) -> int:
         return -(-capacity // self.granularity)
+
+    def heads(self, kv_heads: int) -> int:
+        """Length of the leaf's head axis."""
+        return kv_heads if self.per_head else 1
+
+    def axes(self, seq_axis: str) -> Tuple:
+        """Logical sharding axes of the leaf ``(batch, heads, rows,
+        *suffix)``: the KV heads shard wherever they lie."""
+        if self.per_head:
+            return ("cache_batch", "cache_heads", seq_axis) + \
+                (None,) * len(self.suffix)
+        return ("cache_batch", None, seq_axis, "cache_heads") + \
+            (None,) * (len(self.suffix) - 1)
+
+    def row_width(self, kv_heads: int) -> int:
+        """Elements of one row across all KV heads."""
+        return self.heads(kv_heads) * int(np.prod(self.suffix,
+                                                  dtype=np.int64))
 
     def leaf_dtype(self, cache_dtype) -> Any:
         return cache_dtype if self.dtype is None else jnp.dtype(self.dtype)
@@ -174,16 +248,24 @@ def record_fused(name: str, shape) -> None:
 class KVView:
     """Uniform read/write interface over one layer's decode cache.
 
-    ``arrays`` maps leaf name -> array; layout depends on the subclass.
+    ``arrays`` maps leaf name -> layer-stacked array (a leading layer
+    axis; the rest of the layout depends on the subclass) and ``layer``
+    is this view's index into it (traced inside the layer scan).
     Writes replace entries in ``arrays`` functionally (the dict mutates,
     the arrays never do) — callers read back ``view.arrays`` as the
     updated cache pytree.
     """
 
     def __init__(self, arrays: Dict[str, jax.Array],
-                 spec: Dict[str, LeafSpec]):
+                 spec: Dict[str, LeafSpec], *, layer):
         self.arrays = dict(arrays)
         self.spec = spec
+        self.layer = layer
+
+    def layer_arrays(self) -> Dict[str, jax.Array]:
+        """This layer's slice of every leaf — a copy of each, for the
+        fused paged kernels, which take a pool leaf whole."""
+        return {name: a[self.layer] for name, a in self.arrays.items()}
 
     # ---- reads
     @property
@@ -218,74 +300,75 @@ class KVView:
 
 
 class ContiguousView(KVView):
-    """Today's layout: each leaf is ``(B, KVH, rows, *suffix)``."""
+    """Today's layout: each leaf is ``(L, B, KVH, rows, *suffix)``."""
 
     @property
     def n_tokens(self) -> int:
-        return self.arrays["k"].shape[2] * self.spec["k"].granularity
+        return self.arrays["k"].shape[3] * self.spec["k"].granularity
 
     def leaf(self, name: str) -> jax.Array:
-        return self.arrays[name]
+        return self.arrays[name][self.layer]
 
     def gather_rows(self, name: str, idx: jax.Array) -> jax.Array:
         assert self.spec[name].granularity == 1, name
         a = self.arrays[name]
-        b, kvh = a.shape[:2]
+        b, kvh = a.shape[1:3]
         bidx = jnp.arange(b).reshape(b, *([1] * (idx.ndim - 1)))
         hidx = jnp.arange(kvh).reshape(1, kvh, *([1] * (idx.ndim - 2)))
-        return a[bidx, hidx, idx]
+        return a[self.layer, bidx, hidx, idx]
 
     def _row(self, name: str, pos: jax.Array):
         a = self.arrays[name]
-        pos = self._pos_vec(pos, a.shape[0])
-        return a, jnp.arange(a.shape[0]), pos // self.spec[name].granularity
+        pos = self._pos_vec(pos, a.shape[1])
+        return a, pos // self.spec[name].granularity
 
-    # Scalar pos (lockstep batch) keeps the dynamic-update-slice lowering —
-    # a per-row scatter for the whole-batch-one-position case is markedly
-    # slower than DUS on TPU; the gather/scatter form is only for ragged
-    # (B,) position vectors.
+    # Scalar pos (lockstep batch) writes the whole batch's row in one
+    # dynamic-update-slice; a ragged (B,) position vector writes one per
+    # request (:func:`set_rows`).
     def write_token(self, name, pos, value) -> None:
         a = self.arrays[name]
         if jnp.ndim(pos) == 0:
             row = jnp.asarray(pos, jnp.int32) // self.spec[name].granularity
-            start = (0, 0, row) + (0,) * (a.ndim - 3)
+            start = (self.layer, 0, 0, row) + (0,) * (a.ndim - 4)
             self.arrays[name] = jax.lax.dynamic_update_slice(
-                a, value[:, :, None].astype(a.dtype), start)
+                a, value[None, :, :, None].astype(a.dtype), start)
             return
-        a, bidx, row = self._row(name, pos)
-        self.arrays[name] = a.at[bidx, :, row].set(value.astype(a.dtype))
+        a, row = self._row(name, pos)
+        self.arrays[name] = set_rows(a, self.layer, row, value)
 
     def rmw_token(self, name, pos, fn) -> None:
         a = self.arrays[name]
         if jnp.ndim(pos) == 0:
             row = jnp.asarray(pos, jnp.int32) // self.spec[name].granularity
-            start = (0, 0, row) + (0,) * (a.ndim - 3)
+            start = (self.layer, 0, 0, row) + (0,) * (a.ndim - 4)
             old = jax.lax.dynamic_slice(
-                a, start, (a.shape[0], a.shape[1], 1) + a.shape[3:])
+                a, start, (1,) + a.shape[1:3] + (1,) + a.shape[4:])
             self.arrays[name] = jax.lax.dynamic_update_slice(
-                a, fn(old[:, :, 0])[:, :, None].astype(a.dtype), start)
+                a, fn(old[0, :, :, 0])[None, :, :, None].astype(a.dtype),
+                start)
             return
-        a, bidx, row = self._row(name, pos)
-        self.arrays[name] = a.at[bidx, :, row].set(
-            fn(a[bidx, :, row]).astype(a.dtype))
+        a, row = self._row(name, pos)
+        bidx = jnp.arange(a.shape[1])
+        self.arrays[name] = set_rows(a, self.layer, row,
+                                     fn(a[self.layer, bidx, :, row]))
 
 
 class PagedView(KVView):
-    """Serving-engine layout: each leaf is ``(num_blocks, KVH,
+    """Serving-engine layout: each leaf is ``(L, num_blocks, KVH,
     block_size / granularity, *suffix)`` plus a per-request block table
     ``(B, blocks_per_seq)`` of physical block ids (trash-padded).
 
     Logical token ``t`` of request ``b`` lives in physical block
     ``block_table[b, t // block_size]`` at row ``(t % block_size) //
-    granularity``.  ``leaf()`` materializes the full logical view (cheap
-    for metadata leaves, what paged-capable backends avoid for K/V);
-    ``gather_rows`` translates selected logical indices through the table
-    and touches only those rows.
+    granularity`` of the view's layer.  ``leaf()`` materializes the full
+    logical view (cheap for metadata leaves, what paged-capable backends
+    avoid for K/V); ``gather_rows`` translates selected logical indices
+    through the table and touches only those rows.
     """
 
     def __init__(self, arrays, spec, block_table: jax.Array,
-                 block_size: int):
-        super().__init__(arrays, spec)
+                 block_size: int, *, layer):
+        super().__init__(arrays, spec, layer=layer)
         self.block_table = block_table
         self.block_size = block_size
 
@@ -294,19 +377,25 @@ class PagedView(KVView):
         return self.block_table.shape[1] * self.block_size
 
     def leaf(self, name: str) -> jax.Array:
-        out = gather_block_leaf(self.arrays[name], self.block_table)
+        out = gather_block_leaf(self.arrays[name], self.block_table,
+                                self.layer)
         _GATHER_TRACE.append(("leaf", name, out.shape))
         return out
 
-    def gather_rows(self, name: str, idx: jax.Array) -> jax.Array:
-        assert self.spec[name].granularity == 1, name
+    def _gather(self, name: str, idx: jax.Array, entry) -> jax.Array:
+        """Rows at logical indices ``idx`` ``(B, KVH, *sel)`` whose
+        block-table entries are ``entry(idx)``."""
         pages = self.arrays[name]
         bt = self.block_table
-        b, kvh = bt.shape[0], pages.shape[1]
+        b, kvh = bt.shape[0], pages.shape[2]
         bidx = jnp.arange(b).reshape(b, *([1] * (idx.ndim - 1)))
         hidx = jnp.arange(kvh).reshape(1, kvh, *([1] * (idx.ndim - 2)))
-        blk = bt[bidx, idx // self.block_size]
-        out = pages[blk, hidx, idx % self.block_size]
+        return pages[self.layer, bt[bidx, entry(idx)], hidx,
+                     idx % self.block_size]
+
+    def gather_rows(self, name: str, idx: jax.Array) -> jax.Array:
+        assert self.spec[name].granularity == 1, name
+        out = self._gather(name, idx, lambda i: i // self.block_size)
         _GATHER_TRACE.append(("rows", name, out.shape))
         return out
 
@@ -320,21 +409,26 @@ class PagedView(KVView):
 
     def write_token(self, name, pos, value) -> None:
         pages, blk, row = self._addr(name, pos)
-        self.arrays[name] = pages.at[blk, :, row].set(
-            value.astype(pages.dtype))
+        self.arrays[name] = update_pages(
+            pages, self.layer, blk,
+            lambda i, page: put_row(page, row[i], value[i]))
 
     def rmw_token(self, name, pos, fn) -> None:
         pages, blk, row = self._addr(name, pos)
-        self.arrays[name] = pages.at[blk, :, row].set(
-            fn(pages[blk, :, row]).astype(pages.dtype))
+        new = fn(pages[self.layer, blk, :, row])
+        self.arrays[name] = update_pages(
+            pages, self.layer, blk,
+            lambda i, page: put_row(page, row[i], new[i]))
 
 
-def ring_write_page(pages: jax.Array, blk: jax.Array, pos: jax.Array,
-                    value: jax.Array, *, block_size: int, ring_blocks: int,
-                    window: int) -> jax.Array:
+def ring_write_page(pages: jax.Array, layer, blk: jax.Array,
+                    pos: jax.Array, value: jax.Array, *, block_size: int,
+                    ring_blocks: int, window: int) -> jax.Array:
     """Write token ``pos``'s ``value`` (B, KVH, *suffix) into its circular
-    page ``blk`` (B,) at row ``pos % block_size``, **scrubbing rows that
-    cannot hold in-window tokens at page-opening writes** (row 0):
+    page ``blk`` (B,) of layer ``layer`` of the stacked leaf ``pages``
+    ``(L, NB, KVH, block_size, *suffix)`` at row ``pos % block_size``,
+    **scrubbing rows that cannot hold in-window tokens at page-opening
+    writes** (row 0):
 
     * first pass over the ring (``pos < ring capacity``): the page is a
       freshly allocated pool block still carrying its previous owner's
@@ -351,17 +445,18 @@ def ring_write_page(pages: jax.Array, blk: jax.Array, pos: jax.Array,
     the live requests — recycled blocks are never zeroed on device
     otherwise.  Active slots hold disjoint blocks; only trash-page
     writes alias (their content is never read unmasked)."""
-    b = blk.shape[0]
     cap = ring_blocks * block_size
     row = pos % block_size
-    page = pages[blk]                      # (B, KVH, block_size, *suffix)
     r = jnp.arange(block_size)
     scrub = (row == 0)[:, None] & (r[None] >= 1) & (
         (r[None] <= cap - window) | (pos < cap)[:, None])   # (B, bs)
-    scrub = scrub.reshape(b, 1, block_size, *([1] * (page.ndim - 3)))
-    page = jnp.where(scrub, jnp.zeros((), page.dtype), page)
-    page = page.at[jnp.arange(b), :, row].set(value.astype(page.dtype))
-    return pages.at[blk].set(page)
+
+    def put(i, page):                      # (KVH, block_size, *suffix)
+        dead = scrub[i].reshape(1, block_size, *([1] * (page.ndim - 2)))
+        page = jnp.where(dead, jnp.zeros((), page.dtype), page)
+        return put_row(page, row[i], value[i])
+
+    return update_pages(pages, layer, blk, put)
 
 
 class RingView(PagedView):
@@ -380,8 +475,8 @@ class RingView(PagedView):
     """
 
     def __init__(self, arrays, spec, block_table: jax.Array,
-                 block_size: int, ring_blocks: int, window: int):
-        super().__init__(arrays, spec, block_table, block_size)
+                 block_size: int, ring_blocks: int, window: int, *, layer):
+        super().__init__(arrays, spec, block_table, block_size, layer=layer)
         self.ring_blocks = ring_blocks
         self.window = window
 
@@ -391,39 +486,33 @@ class RingView(PagedView):
 
     def leaf(self, name: str) -> jax.Array:
         out = gather_block_leaf(self.arrays[name],
-                                self.block_table[:, :self.ring_blocks])
+                                self.block_table[:, :self.ring_blocks],
+                                self.layer)
         _GATHER_TRACE.append(("ring", name, out.shape))
         return out
+
+    def _entry(self, t: jax.Array) -> jax.Array:
+        """Block-table entry of logical token ``t``."""
+        return (t // self.block_size) % self.ring_blocks
 
     def _addr(self, name: str, pos: jax.Array):
         assert self.spec[name].granularity == 1, name
         pages = self.arrays[name]
         pos = self._pos_vec(pos, self.block_table.shape[0])
         bidx = jnp.arange(self.block_table.shape[0])
-        blk = self.block_table[
-            bidx, (pos // self.block_size) % self.ring_blocks]
-        return pages, blk, pos % self.block_size
+        return pages, self.block_table[bidx, self._entry(pos)], \
+            pos % self.block_size
 
     def gather_rows(self, name: str, idx: jax.Array) -> jax.Array:
-        pages = self.arrays[name]
-        bt = self.block_table
-        b, kvh = bt.shape[0], pages.shape[1]
-        bidx = jnp.arange(b).reshape(b, *([1] * (idx.ndim - 1)))
-        hidx = jnp.arange(kvh).reshape(1, kvh, *([1] * (idx.ndim - 2)))
-        blk = bt[bidx, (idx // self.block_size) % self.ring_blocks]
-        out = pages[blk, hidx, idx % self.block_size]
+        out = self._gather(name, idx, self._entry)
         _GATHER_TRACE.append(("ring", name, out.shape))
         return out
 
     def write_token(self, name, pos, value) -> None:
-        assert self.spec[name].granularity == 1, name
-        pages = self.arrays[name]
+        pages, blk, _ = self._addr(name, pos)
         pos = self._pos_vec(pos, self.block_table.shape[0])
-        bidx = jnp.arange(self.block_table.shape[0])
-        blk = self.block_table[
-            bidx, (pos // self.block_size) % self.ring_blocks]
         self.arrays[name] = ring_write_page(
-            pages, blk, pos, value, block_size=self.block_size,
+            pages, self.layer, blk, pos, value, block_size=self.block_size,
             ring_blocks=self.ring_blocks, window=self.window)
 
 
@@ -536,14 +625,13 @@ class DecodeBackend:
         out = {}
         for name, s in self.cache_spec(cfg).items():
             out[name] = jnp.full(
-                (batch, kv_heads, s.rows(capacity), *s.suffix),
+                (batch, s.heads(kv_heads), s.rows(capacity), *s.suffix),
                 s.fill, s.leaf_dtype(dtype))
         return out
 
     def cache_axes(self, cfg, seq_axis: str) -> Dict[str, Tuple]:
         """Logical sharding axes mirroring :meth:`init_cache`."""
-        return {name: ("cache_batch", "cache_heads", seq_axis) +
-                (None,) * len(s.suffix)
+        return {name: s.axes(seq_axis)
                 for name, s in self.cache_spec(cfg).items()}
 
     # ---- ops ------------------------------------------------------------
@@ -595,43 +683,76 @@ def write_block_prefill(pages: jax.Array, leaf: jax.Array,
     return pages.at[bt_row[:nb]].set(blocks.astype(pages.dtype))
 
 
-def write_chunk_blocks(pages: jax.Array, leaf: jax.Array,
+def write_chunk_blocks(pages: jax.Array, layer, leaf: jax.Array,
                        bt_row: jax.Array, block0) -> jax.Array:
-    """Scatter one *prefill chunk's* batch=1 cache leaf ``(1, KVH, rows,
-    *rest)`` into pool pages at block-table offset ``block0`` (a traced
-    scalar — the chunk's first logical block, ``history // block_size``):
+    """Write one *prefill chunk's* batch=1 cache leaf ``(1, KVH, rows,
+    *rest)`` into layer ``layer`` of the stacked pool leaf ``pages`` at
+    block-table offset ``block0`` (a traced scalar — the chunk's first
+    logical block, ``history // block_size``), a whole page at a time:
     the chunked analogue of :func:`write_block_prefill`.  ``bt_row`` must
     be padded so ``block0 + rows / rows_per_block`` never exceeds its
     static length (entries past the request's allocation are trash)."""
     kvh, rows = leaf.shape[1], leaf.shape[2]
-    rows_pb = pages.shape[2]
+    rows_pb = pages.shape[3]
     nb = rows // rows_pb
     blocks = leaf[0].reshape(kvh, nb, rows_pb, *leaf.shape[3:])
     blocks = jnp.moveaxis(blocks, 1, 0)      # (nb, KVH, rows_pb, *rest)
     ids = jax.lax.dynamic_slice(bt_row, (jnp.asarray(block0, jnp.int32),),
                                 (nb,))
-    return pages.at[ids].set(blocks.astype(pages.dtype))
+    return set_pages(pages, layer, ids, blocks)
 
 
-def write_chunk_rows(pages: jax.Array, leaf: jax.Array, bt_row: jax.Array,
-                     history, last_index) -> jax.Array:
-    """Row-granular variant of :func:`write_chunk_blocks`: chunk token
-    ``i`` lands at logical position ``history + i``, i.e. row
-    ``(history + i) % rows_per_block`` of block ``bt_row[(history + i) //
-    rows_per_block]``.  Needed when the chunk start is **not**
+def write_chunk_rows(pages: Dict[str, jax.Array], layer,
+                     leaves: Dict[str, jax.Array], bt_row: jax.Array,
+                     history, last_index) -> Dict[str, jax.Array]:
+    """Row-granular variant of :func:`write_chunk_blocks`, for the leaves
+    ``pages`` (name -> layer-stacked pool leaf) and the chunk's batch=1
+    cache leaves ``leaves`` ``(1, KVH, rows, *rest)``: chunk token ``i``
+    lands at logical position ``history + i``, i.e. row ``(history + i) %
+    rows_per_block`` of block ``bt_row[(history + i) // rows_per_block]``
+    of layer ``layer``.  Needed when the chunk start is **not**
     page-aligned — a prefix-cache hit resumes prefill mid-page after the
     shared tail page is CoW-cloned — and only valid for granularity-1
     leaves (per-token rows; page-granular stats can't be written by the
-    row).  Rows past ``last_index`` (final-chunk padding) are routed to
-    the trash page instead of committing junk into real blocks."""
-    rows = leaf.shape[2]
-    rows_pb = pages.shape[2]
-    i = jnp.arange(rows, dtype=jnp.int32)
-    ti = jnp.asarray(history, jnp.int32) + i
-    blk = jnp.where(i <= jnp.asarray(last_index, jnp.int32),
-                    bt_row[ti // rows_pb], 0)
-    vals = jnp.moveaxis(leaf[0], 1, 0)       # (rows, KVH, *rest)
-    return pages.at[blk, :, ti % rows_pb].set(vals.astype(pages.dtype))
+    row).
+
+    Each page the chunk may touch (one more than a page-aligned chunk
+    fills) is written whole — the tile-aligned form the TPU writes in
+    place.  Only two pages can hold rows the chunk does not write, the
+    first (rows before ``history``) and the one with the chunk's last
+    real row (rows past ``last_index``, final-chunk padding): those two
+    are read first and keep those rows.  A page with no chunk row goes
+    to the trash page, so no junk lands in real blocks."""
+    out = {}
+    for name, a in pages.items():
+        leaf = leaves[name]
+        rows, rows_pb = leaf.shape[2], a.shape[3]
+        nb = rows // rows_pb + 1
+        h = jnp.asarray(history, jnp.int32)
+        li = jnp.asarray(last_index, jnp.int32)
+        first = h // rows_pb + jnp.arange(nb, dtype=jnp.int32)
+        i = first[:, None] * rows_pb + \
+            jnp.arange(rows_pb, dtype=jnp.int32)[None] - h   # chunk row
+        keep = (i >= 0) & (i <= li)
+        ids = jnp.where(jnp.any(keep, axis=1), bt_row[first], 0)
+        last = (h % rows_pb + li) // rows_pb     # page of the last row
+
+        def page(j):
+            return jax.lax.dynamic_slice(
+                a, (layer, ids[j]) + (0,) * (a.ndim - 2),
+                (1, 1) + a.shape[2:])[0, 0]
+        j = jnp.arange(nb).reshape(nb, 1, 1, *([1] * (a.ndim - 4)))
+        old = jnp.where(j == 0, page(0)[None], page(last)[None])
+        vals = leaf[0][:, jnp.clip(i, 0, rows - 1)].astype(a.dtype)
+        vals = jnp.moveaxis(vals, 1, 0)        # (nb, KVH, rows_pb, *rest)
+        keep = keep.reshape(nb, 1, rows_pb, *([1] * (a.ndim - 4)))
+        merged = jnp.where(keep, vals, old)
+        out[name] = jax.lax.fori_loop(
+            0, nb, lambda j, a, m=merged, ids=ids:
+            jax.lax.dynamic_update_slice(
+                a, m[j][None, None], (layer, ids[j]) + (0,) * (a.ndim - 2)),
+            a)
+    return out
 
 
 class LayerCacheHandler:

@@ -60,14 +60,15 @@ class HardLSHBackend(SocketBackend):
         if budget is None:
             budget = jnp.full((q.shape[0],), kq, jnp.int32)
         from repro.kernels.paged_attention import ops as pa_ops
+        pages = view.layer_arrays()
         out = pa_ops.paged_hard_lsh_attend(
-            q, view.arrays["k"], view.arrays["v"], view.arrays["bits"],
-            view.arrays["vnorm"], u_signs, view.block_table, length=length,
+            q, pages["k"], pages["v"], self._bits(scfg, pages["bits"]),
+            pages["vnorm"], u_signs, view.block_table, length=length,
             budget=budget, num_tables=scfg.num_tables,
             num_planes=scfg.num_planes, scale=scale,
             sink_tokens=scfg.sink_tokens, window_tokens=scfg.window_tokens,
-            k_scale=base.kv_scales_of(view.arrays, "k"),
-            v_scale=base.kv_scales_of(view.arrays, "v"))
+            k_scale=base.kv_scales_of(pages, "k"),
+            v_scale=base.kv_scales_of(pages, "v"))
         base.record_fused("paged_hard_lsh", out.shape)
         return out.astype(q.dtype)
 
@@ -86,7 +87,8 @@ class HardLSHBackend(SocketBackend):
 
         u = sk.soft_hash_query(params["hash_w"], q[..., 0, :])
         u_signs = jnp.where(u >= 0, 1.0, -1.0)
-        scores = _hard_collision_scores(scfg, view.leaf("bits"), u_signs)
+        scores = _hard_collision_scores(
+            scfg, self._bits(scfg, view.leaf("bits")), u_signs)
         scores = jnp.sum(scores, axis=2)                 # sum over group
         kq = sk.topk_budget(scfg, n)
         vnorm = view.leaf("vnorm").astype(jnp.float32)

@@ -112,13 +112,14 @@ class QuestBackend(base.DecodeBackend):
         n = view.n_tokens
         kp = quest_mod.page_budget(qcfg, n // qcfg.page_size, n)
         from repro.kernels.paged_attention import ops as pa_ops
+        pages = view.layer_arrays()
         out = pa_ops.paged_quest_attend(
-            q, view.arrays["k"], view.arrays["v"], view.arrays["kmin"],
-            view.arrays["kmax"], view.block_table, length=length,
+            q, pages["k"], pages["v"], pages["kmin"], pages["kmax"],
+            view.block_table, length=length,
             page_budget=kp, page_size=qcfg.page_size, scale=scale,
             sink_tokens=qcfg.sink_tokens, window_tokens=qcfg.window_tokens,
-            k_scale=base.kv_scales_of(view.arrays, "k"),
-            v_scale=base.kv_scales_of(view.arrays, "v"))
+            k_scale=base.kv_scales_of(pages, "k"),
+            v_scale=base.kv_scales_of(pages, "v"))
         base.record_fused("paged_quest", out.shape)
         return out.astype(q.dtype)
 

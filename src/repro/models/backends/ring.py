@@ -66,6 +66,6 @@ class RingCacheHandler(base.LayerCacheHandler):
         for name, p in pages.items():
             val = views[name][bidx, :, pos % rows]     # (B, KVH, *rest)
             out[name] = base.ring_write_page(
-                p, blk, pos, val, block_size=bs, ring_blocks=rb,
-                window=cfg.sliding_window)
+                p[None], 0, blk, pos, val, block_size=bs, ring_blocks=rb,
+                window=cfg.sliding_window)[0]
         return out

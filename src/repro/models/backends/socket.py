@@ -36,6 +36,23 @@ from repro.models.backends.base import ContiguousView, KVView, LeafSpec
 
 __all__ = ["SocketBackend", "socket_config_of"]
 
+# The bits leaf keeps a token's KV heads side by side in one row (a
+# key-major page, ``LeafSpec(per_head=False)``), each head's hash words
+# padded to whole rows of the TPU's 128 lanes.  Key-major is the order the
+# scoring kernel reads; and with a narrower minor axis the device's
+# default layout puts the block axis in the lanes instead, in which a page
+# is no longer contiguous: the compiler then relayouts the whole leaf into
+# and out of every step that writes a page of it.
+LANES = 128
+
+
+def bits_width(scfg: sk.SocketConfig) -> int:
+    """Hash words (packed ``uint32``) or sign bytes (``int8``) of one
+    token: the leading part of the bits leaf's minor axis."""
+    if scfg.bits_storage == "packed":
+        return hashing.num_words(scfg.num_tables, scfg.num_planes)
+    return scfg.num_tables * scfg.num_planes
+
 
 def socket_config_of(cfg) -> sk.SocketConfig:
     """Map the model config's :class:`SocketSettings` to the scorer's
@@ -57,14 +74,32 @@ class SocketBackend(base.DecodeBackend):
     def cache_spec(self, cfg):
         scfg = socket_config_of(cfg)
         spec = base.kv_leaf_specs(cfg)
-        if scfg.bits_storage == "packed":
-            w = hashing.num_words(scfg.num_tables, scfg.num_planes)
-            spec["bits"] = LeafSpec(suffix=(w,), dtype=jnp.uint32)
-        else:
-            spec["bits"] = LeafSpec(
-                suffix=(scfg.num_tables * scfg.num_planes,), dtype=jnp.int8)
+        spec["bits"] = LeafSpec(
+            suffix=(cfg.num_kv_heads,
+                    -(-bits_width(scfg) // LANES) * LANES),
+            dtype=jnp.uint32 if scfg.bits_storage == "packed" else jnp.int8,
+            per_head=False)
         spec["vnorm"] = LeafSpec(suffix=(), dtype=jnp.bfloat16)
         return spec
+
+    @staticmethod
+    def _words(bits: jax.Array) -> jax.Array:
+        """A bits leaf's logical view ``(B, 1, N, KVH, W_pad)`` as the
+        per-head ``(B, KVH, N, W_pad)``, lane padding included."""
+        return jnp.moveaxis(bits[:, 0], 2, 1)
+
+    @classmethod
+    def _bits(cls, scfg, bits: jax.Array) -> jax.Array:
+        """A bits leaf's hash words ``(B, KVH, N, W)``."""
+        return cls._words(bits)[..., :bits_width(scfg)]
+
+    @staticmethod
+    def _stored(bits: jax.Array, leaf: jax.Array) -> jax.Array:
+        """Per-head hash words ``bits`` ``(B, KVH, T, W)`` as rows of the
+        bits leaf ``leaf``: ``(B, 1, T, KVH, W_pad)``, zero-padded."""
+        pad = leaf.shape[-1] - bits.shape[-1]
+        rows = jnp.moveaxis(bits, 1, 2)
+        return jnp.pad(rows, [(0, 0)] * (rows.ndim - 1) + [(0, pad)])[:, None]
 
     # ---- ops ------------------------------------------------------------
     def prefill_build(self, cfg, params, cache, kc, vc):
@@ -73,7 +108,8 @@ class SocketBackend(base.DecodeBackend):
         scfg = socket_config_of(cfg)
         side = sk.precompute_key_hashes(
             scfg, jax.lax.stop_gradient(params["hash_w"]), kc, vc)
-        cache["bits"] = cache["bits"].at[:, :, :t].set(side.bits)
+        cache["bits"] = cache["bits"].at[:, :, :t].set(
+            self._stored(side.bits, cache["bits"]))
         cache["vnorm"] = cache["vnorm"].at[:, :, :t].set(
             side.vnorm.astype(cache["vnorm"].dtype))
         return cache
@@ -85,7 +121,8 @@ class SocketBackend(base.DecodeBackend):
         # untouched by K/V quantization by construction
         scfg = socket_config_of(cfg)
         side = sk.precompute_key_hashes(scfg, params["hash_w"], kc, vc)
-        view.write_token("bits", pos, side.bits[:, :, 0])
+        view.write_token("bits", pos, self._stored(
+            side.bits, view.arrays["bits"])[:, :, 0])
         view.write_token("vnorm", pos, side.vnorm[:, :, 0])
 
     def _budget(self, cfg, length, n):
@@ -117,7 +154,8 @@ class SocketBackend(base.DecodeBackend):
         for it; elsewhere the XLA factorized scorer."""
         scfg = socket_config_of(cfg)
         u = self._soft_hash(scfg, params, q)
-        bits = view.leaf("bits")
+        leaf = view.leaf("bits")
+        bits = self._bits(scfg, leaf)
         if cfg.socket.use_score_kernel and scfg.selection == "qhead":
             raise NotImplementedError(
                 "the Pallas scoring kernel group-sums scores (kvhead "
@@ -131,8 +169,11 @@ class SocketBackend(base.DecodeBackend):
             from repro.kernels.socket_score import ops as score_ops
             # kernel wants (B,KVH,G,L,P); pooled hashes once per KV head
             u_k = u[:, :, None] if scfg.selection == "pooled" else u
+            # packed words go in with their lane padding, which the
+            # kernel never reads: a slice would copy them
             scores = score_ops.socket_score(
-                bits, u_k, vnorm=None, num_tables=scfg.num_tables,
+                self._words(leaf) if scfg.bits_storage == "packed"
+                else bits, u_k, vnorm=None, num_tables=scfg.num_tables,
                 num_planes=scfg.num_planes, tau=scfg.tau,
                 length=length)                         # (B,KVH,N), G-sum
             base.record_fused("socket_score", scores.shape)
@@ -169,14 +210,16 @@ class SocketBackend(base.DecodeBackend):
         if budget is None:
             budget = jnp.full((q.shape[0],), kq, jnp.int32)
         from repro.kernels.paged_attention import ops as pa_ops
+        pages = view.layer_arrays()
         out = pa_ops.paged_socket_attend(
-            q, view.arrays["k"], view.arrays["v"], view.arrays["bits"],
-            view.arrays["vnorm"], u, view.block_table, length=length,
+            q, pages["k"], pages["v"], self._bits(scfg, pages["bits"]),
+            pages["vnorm"], u,
+            view.block_table, length=length,
             budget=budget, num_tables=scfg.num_tables,
             num_planes=scfg.num_planes, tau=scfg.tau, scale=scale,
             sink_tokens=scfg.sink_tokens, window_tokens=scfg.window_tokens,
-            k_scale=base.kv_scales_of(view.arrays, "k"),
-            v_scale=base.kv_scales_of(view.arrays, "v"))
+            k_scale=base.kv_scales_of(pages, "k"),
+            v_scale=base.kv_scales_of(pages, "v"))
         base.record_fused("paged_attention", out.shape)
         return out.astype(q.dtype)
 
@@ -223,12 +266,12 @@ class SocketBackend(base.DecodeBackend):
             # materializing the (B,KVH,N) global score tensor
             from repro.distributed.context_parallel import \
                 context_parallel_socket_attend
-            cache = view.arrays
             return context_parallel_socket_attend(
                 scfg, mesh, cfg.decode_cp_axes, params["hash_w"], q,
                 base.dequant_leaf(cfg, view, "k"),
-                base.dequant_leaf(cfg, view, "v"), cache["bits"],
-                cache["vnorm"].astype(jnp.float32),
+                base.dequant_leaf(cfg, view, "v"),
+                self._bits(scfg, view.leaf("bits")),
+                view.leaf("vnorm").astype(jnp.float32),
                 length=length, scale=scale,
                 batch_axes=cfg.decode_cp_batch_axes)
 

@@ -109,15 +109,16 @@ def _block_prefill(cfg: ModelConfig, params: Dict, spec: LayerSpec,
 
 def _block_prefill_chunk(cfg: ModelConfig, params: Dict, spec: LayerSpec,
                          x: jax.Array, positions: jax.Array, cache: Dict,
-                         bt_row: jax.Array, slot: jax.Array,
+                         layer, bt_row: jax.Array, slot: jax.Array,
                          history: jax.Array, last_index: jax.Array):
-    """One block's share of one prefill chunk, writing the pool in place
-    (see :func:`prefill_chunk`).  An MoE MLP dispatches dropless."""
+    """One block's share of one prefill chunk, writing layer ``layer`` of
+    the stacked pool leaves ``cache`` in place (see
+    :func:`prefill_chunk`).  An MoE MLP dispatches dropless."""
     h = rmsnorm(params["norm_mix"], x)
     if spec.kind == "attn":
         h, cache = attn.attention_prefill_chunk(
             cfg, params["attn"], h, positions, spec.attn_type, cache,
-            bt_row, history, last_index)
+            layer, bt_row, history, last_index)
     else:
         # Mamba state carries across chunks through the per-slot rows:
         # read the previous chunk's SSD state + conv tail, run the chunk
@@ -125,18 +126,25 @@ def _block_prefill_chunk(cfg: ModelConfig, params: Dict, spec: LayerSpec,
         # The FIRST chunk starts from zeros — the slot row still holds the
         # previous occupant's state (nothing scrubs it on free).
         with jax.named_scope("mamba.state"):
-            first = jnp.asarray(history, jnp.int32) == 0
-            h0 = jnp.where(first, 0.0, cache["ssm"][slot][None])
-            conv0 = jnp.where(first, 0.0, cache["conv"][slot][None])
-        h, st = mb.mamba_train(cfg, params["mamba"], h, h0=h0, conv0=conv0,
-                               return_state=True, last_index=last_index)
+            start = {name: (layer, slot) + (0,) * (a.ndim - 2)
+                     for name, a in cache.items()}
+            # one read of the slot's rows, which both the chunk's output
+            # and its final state consume, so the write below updates
+            # the pool in place
+            old = jax.lax.cond(
+                jnp.asarray(history, jnp.int32) == 0,
+                lambda: {name: jnp.zeros((1,) + a.shape[2:], a.dtype)
+                         for name, a in cache.items()},
+                lambda: {name: jax.lax.dynamic_slice(
+                    a, start[name], (1, 1) + a.shape[2:])[0]
+                    for name, a in cache.items()})
+        h, st = mb.mamba_train(cfg, params["mamba"], h, h0=old["ssm"],
+                               conv0=old["conv"], return_state=True,
+                               last_index=last_index)
         with jax.named_scope("mamba.state"):
-            cache = {
-                "ssm": cache["ssm"].at[slot].set(
-                    st["ssm"][0].astype(cache["ssm"].dtype)),
-                "conv": cache["conv"].at[slot].set(
-                    st["conv"][0].astype(cache["conv"].dtype)),
-            }
+            cache = {name: jax.lax.dynamic_update_slice(
+                a, st[name].astype(a.dtype)[None], start[name])
+                for name, a in cache.items()}
     x = _residual(cfg, x, h)
     y, _ = _mlp_branch(cfg, params, spec, x, dropless=True)
     if y is not None:
@@ -145,18 +153,31 @@ def _block_prefill_chunk(cfg: ModelConfig, params: Dict, spec: LayerSpec,
 
 
 def _block_decode(cfg: ModelConfig, params: Dict, spec: LayerSpec,
-                  x: jax.Array, cache: Dict, pos: jax.Array,
-                  block_tables=None):
+                  x: jax.Array, cache: Dict, pos: jax.Array, layer,
+                  active: jax.Array, block_tables=None):
+    """One block's decode step on layer ``layer`` of the stacked cache
+    leaves ``cache``, written in place.  A Mamba layer takes its new
+    state rows only where ``active`` (see :func:`decode_step`)."""
     with jax.named_scope("layer.proj"):
         h = rmsnorm(params["norm_mix"], x)
     if spec.kind == "attn":
         # the QKV/O projections and rope are scoped layer.proj inside
         h, cache = attn.attention_decode(cfg, params["attn"], h, cache, pos,
-                                         spec.attn_type,
+                                         spec.attn_type, layer,
                                          block_tables=block_tables)
     else:
+        with jax.named_scope("mamba.state"):
+            old = {name: jax.lax.dynamic_slice_in_dim(a, layer, 1)
+                   for name, a in cache.items()}
         # scoped mamba.proj / mamba.scan inside
-        h, cache = mb.mamba_decode(cfg, params["mamba"], h, cache)
+        h, new = mb.mamba_decode(cfg, params["mamba"], h,
+                                 {name: a[0] for name, a in old.items()})
+        with jax.named_scope("mamba.state"):
+            cache = {name: jax.lax.dynamic_update_slice_in_dim(
+                a, jnp.where(
+                    active.reshape((1, -1) + (1,) * (a.ndim - 2)),
+                    new[name][None].astype(a.dtype), old[name]), layer, 0)
+                for name, a in cache.items()}
     x = _residual(cfg, x, h)
     with jax.named_scope("layer.mlp"):
         # an MoE's router, experts and shared expert are scoped moe.*
@@ -383,6 +404,40 @@ def prefill(cfg: ModelConfig, params, batch: Dict, capacity: int,
     return logits, {"groups": group_caches, "remainder": rem_caches}
 
 
+def _layer_scan(cfg: ModelConfig, block, params, caches, x):
+    """Run ``block(spec, block_params, x, stacked_cache, layer) -> (x,
+    stacked_cache)`` over the stack, the pool-addressing form every
+    serving step shares.
+
+    The scan-stacked cache leaves ride in the scan's **carry** beside
+    ``x`` and each block writes its layer's new rows into them in place;
+    the scan's inputs are only the group parameters and the layer index.
+    Passing the caches as the scan's ``xs`` and taking them back as its
+    ``ys`` would cut each layer's slice out of the stack, copy it to
+    write it, and write a second whole pool.  A remainder block's leaves
+    get a layer axis of one so that it addresses them the same way."""
+    def group_body(carry, xs):
+        x, gcache = carry
+        gparams, layer = xs
+        gcache = dict(gcache)
+        for i, spec in enumerate(cfg.pattern):
+            x, gcache[f"slot_{i}"] = block(spec, gparams[f"slot_{i}"], x,
+                                           gcache[f"slot_{i}"], layer)
+        return (x, gcache), None
+
+    (x, groups), _ = jax.lax.scan(
+        group_body, (x, caches["groups"]),
+        (params["groups"], jnp.arange(cfg.num_groups, dtype=jnp.int32)))
+
+    rem = {}
+    for i, spec in enumerate(cfg.remainder):
+        one = jax.tree_util.tree_map(lambda a: a[None],
+                                     caches["remainder"][f"slot_{i}"])
+        x, one = block(spec, params["remainder"][f"slot_{i}"], x, one, 0)
+        rem[f"slot_{i}"] = jax.tree_util.tree_map(lambda a: a[0], one)
+    return x, {"groups": groups, "remainder": rem}
+
+
 def prefill_chunk(cfg: ModelConfig, params, caches, tokens: jax.Array,
                   *, bt_row: jax.Array, slot: jax.Array,
                   history: jax.Array, last_index: jax.Array):
@@ -408,34 +463,21 @@ def prefill_chunk(cfg: ModelConfig, params, caches, tokens: jax.Array,
                  jnp.arange(c, dtype=jnp.int32))[None]
     positions = jnp.broadcast_to(positions, (b, c))
 
-    def group_body(x, xs):
-        gparams, gcache = xs
-        new_caches = {}
-        for i, spec in enumerate(cfg.pattern):
-            x, new_caches[f"slot_{i}"] = _block_prefill_chunk(
-                cfg, gparams[f"slot_{i}"], spec, x, positions,
-                gcache[f"slot_{i}"], bt_row, slot, history, last_index)
-        return x, new_caches
+    def block(spec, bparams, x, cache, layer):
+        return _block_prefill_chunk(cfg, bparams, spec, x, positions, cache,
+                                    layer, bt_row, slot, history,
+                                    last_index)
 
-    x, group_caches = jax.lax.scan(
-        group_body, x, (params["groups"], caches["groups"]))
-
-    rem_caches = {}
-    for i, spec in enumerate(cfg.remainder):
-        x, rem_caches[f"slot_{i}"] = _block_prefill_chunk(
-            cfg, params["remainder"][f"slot_{i}"], spec, x, positions,
-            caches["remainder"][f"slot_{i}"], bt_row, slot, history,
-            last_index)
-
+    x, caches = _layer_scan(cfg, block, params, caches, x)
     li = jnp.asarray(last_index, jnp.int32).reshape(b)
     x = x[jnp.arange(b), li][:, None]
     x = rmsnorm(params["final_norm"], x)
     logits = lm_head(cfg, params["embed"], x)
-    return logits, {"groups": group_caches, "remainder": rem_caches}
+    return logits, caches
 
 
 def decode_step(cfg: ModelConfig, params, caches, inputs: jax.Array,
-                pos: jax.Array, block_tables=None):
+                pos: jax.Array, block_tables=None, active=None):
     """One token for the whole stack.
 
     inputs: (B, 1) token ids or (B, 1, d) embeddings; pos: scalar int32 or
@@ -446,35 +488,33 @@ def decode_step(cfg: ModelConfig, params, caches, inputs: jax.Array,
     ids — when given, ``caches`` is the serving engine's page pool (leaves
     ``(num_blocks, KVH, block_size, ...)``, shared block ids across
     layers) and attention layers read/write it through ``PagedView``.
-    Returns (logits (B,1,V), updated caches).
+    ``active``: optional ``(B,)`` bool of the slots holding a runnable
+    request (all, by default).  Mamba layers keep the other slots' state
+    rows: the step integrates their trash tokens like any slot's, and a
+    state row has no trash row to absorb them, while a request between
+    its prefill chunks needs its state to survive the decode steps run
+    meanwhile.  Attention rows of inactive slots land in the trash page.
+
+    The caches are carried through the layer scan and written in place
+    (:func:`_layer_scan`).  Returns (logits (B,1,V), updated caches).
     """
     if cfg.input_mode == "tokens":
         x = embed_tokens(cfg, params["embed"], inputs)
     else:
         x = inputs.astype(jnp.dtype(cfg.compute_dtype))
 
-    def group_body(x, xs):
-        gparams, gcache = xs
-        new_caches = {}
-        for i, spec in enumerate(cfg.pattern):
-            x, new_caches[f"slot_{i}"] = _block_decode(
-                cfg, gparams[f"slot_{i}"], spec, x, gcache[f"slot_{i}"],
-                pos, block_tables)
-        return x, new_caches
+    b = x.shape[0]
+    active = jnp.ones((b,), bool) if active is None else active
 
-    x, new_group_caches = jax.lax.scan(
-        group_body, x, (params["groups"], caches["groups"]))
+    def block(spec, bparams, x, cache, layer):
+        return _block_decode(cfg, bparams, spec, x, cache, pos, layer,
+                             active, block_tables)
 
-    new_rem = {}
-    for i, spec in enumerate(cfg.remainder):
-        x, new_rem[f"slot_{i}"] = _block_decode(
-            cfg, params["remainder"][f"slot_{i}"], spec, x,
-            caches["remainder"][f"slot_{i}"], pos, block_tables)
-
+    x, caches = _layer_scan(cfg, block, params, caches, x)
     with jax.named_scope("model.head"):
         x = rmsnorm(params["final_norm"], x)
         logits = lm_head(cfg, params["embed"], x)
-    return logits, {"groups": new_group_caches, "remainder": new_rem}
+    return logits, caches
 
 
 # ------------------------------------------------------------- accounting

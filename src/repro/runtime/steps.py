@@ -113,11 +113,13 @@ def make_chunk_prefill_step(cfg: ModelConfig) -> Callable:
 
 
 def make_serve_step(cfg: ModelConfig) -> Callable:
-    """(params, caches, inp, pos[, block_tables]) -> (logits, caches).
-    ``pos`` may be a scalar (static batch) or a ``(B,)`` vector (ragged
-    continuous batch); ``block_tables`` switches ``caches`` to the paged
-    pool (see :func:`tfm.decode_step`)."""
-    def serve_step(params, caches, inp, pos, block_tables=None):
+    """(params, caches, inp, pos[, block_tables, active]) -> (logits,
+    caches).  ``pos`` may be a scalar (static batch) or a ``(B,)`` vector
+    (ragged continuous batch); ``block_tables`` switches ``caches`` to the
+    paged pool; ``active`` marks the runnable slots (see
+    :func:`tfm.decode_step`)."""
+    def serve_step(params, caches, inp, pos, block_tables=None,
+                   active=None):
         return tfm.decode_step(cfg, params, caches, inp, pos,
-                               block_tables=block_tables)
+                               block_tables=block_tables, active=active)
     return serve_step

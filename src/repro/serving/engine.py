@@ -311,21 +311,20 @@ class ContinuousBatchingEngine:
         ``active`` (``(B,)`` bool) marks slots holding a runnable
         request: inactive slots keep their per-slot state rows (a
         chunk-owner's Mamba state must survive the decode iterations
-        between its chunks) and their PRNG keys (a request's sample
-        stream advances exactly once per emitted token, never while the
-        slot idles — the replay-exact resume invariant).
+        between its chunks; the model writes them so, in place) and
+        their PRNG keys (a request's sample stream advances exactly once
+        per emitted token, never while the slot idles — the replay-exact
+        resume invariant).
         """
         if self._paged_native:
             # page-native path: the pool + block tables go straight into
             # the model; no contiguous K/V view is ever materialized.
-            logits, new_pages = serve(params, pages, tokens, pos, bt)
+            logits, new_pages = serve(params, pages, tokens, pos, bt,
+                                      active)
         else:
             views = paged.gather_views(self.cfg, pages, bt)
-            logits, views = serve(params, views, tokens, pos)
+            logits, views = serve(params, views, tokens, pos, None, active)
             new_pages = paged.scatter_token(self.cfg, pages, views, bt, pos)
-        if self._has_state:
-            new_pages = paged.keep_state_rows(self.cfg, pages, new_pages,
-                                              active)
         tok, new_keys = self._pick(logits, keys)
         keys = jnp.where(active[:, None], new_keys, keys)
         return tok, keys, new_pages
@@ -354,6 +353,11 @@ class ContinuousBatchingEngine:
             logits_c, pages = chunk_fn(params, pages, ch_tokens, ch_bt,
                                        ch_slot, ch_hist, ch_last)
             tok_c, key_c = self._pick(logits_c, keys[ch_slot][None])
+            # adds 0 (token ids are never negative), but makes the decode
+            # half depend on everything the chunk read from the pool, so
+            # XLA orders those reads before the decode's writes and
+            # updates the pool in place instead of copying it
+            tokens = tokens + jnp.minimum(tok_c[0], 0).astype(tokens.dtype)
             keys = keys.at[ch_slot].set(
                 jnp.where(ch_final, key_c[0], keys[ch_slot]))
             tok, keys, pages = self._decode_body(

@@ -44,14 +44,13 @@ from typing import Dict
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from repro.configs.base import ModelConfig, ServingSettings
 from repro.models import backends as bk
 from repro.models import transformer as tfm
 
 __all__ = ["init_paged_caches", "gather_views", "scatter_token",
-           "write_prefill", "keep_state_rows", "clone_block",
+           "write_prefill", "clone_block",
            "gather_footprint", "cache_kind_counts", "kv_row_bytes",
            "pool_block_bytes"]
 
@@ -118,28 +117,6 @@ def write_prefill(cfg: ModelConfig, pages, caches, bt_row: jax.Array,
         pages, caches)
 
 
-def keep_state_rows(cfg: ModelConfig, before, after, active: jax.Array):
-    """Preserve inactive decode slots' per-slot **state** rows across a
-    decode step: the jitted ragged step updates every Mamba slot row
-    unconditionally (masked attention slots write the trash page, but
-    state rows have no trash row to absorb the garbage).  With the legacy
-    whole-prompt prefill that was harmless — a slot's state was only live
-    while the slot decoded.  Under chunked prefill a slot's state must
-    survive the decode iterations running *between* its chunks, so state
-    leaves take the post-step value only where ``active`` (``(B,)``
-    bool) marks a runnable request; paged/ring leaves pass through
-    untouched (their inactive-slot writes already land in the trash
-    page).  Scoped ``mamba.state``: it rewrites every state row."""
-    def sel(h, old, new):
-        if h.kind != "state":
-            return new
-        return {name: jnp.where(
-            active.reshape((-1,) + (1,) * (new[name].ndim - 1)),
-            new[name], old[name]) for name in new}
-    with jax.named_scope("mamba.state"):
-        return _map_slots(cfg, sel, before, after)
-
-
 def clone_block(cfg: ModelConfig, pages, src, dst, keep_tokens):
     """Copy-on-write clone: duplicate physical page ``src`` into ``dst``
     across every **paged**-kind leaf, keeping only the rows covering the
@@ -185,11 +162,12 @@ def clone_block(cfg: ModelConfig, pages, src, dst, keep_tokens):
 
 # -------------------------------------------------------------- accounting
 
-def _leaf_row_bytes(s, cdt) -> float:
-    """Bytes one *token* of leaf ``s`` occupies (suffix width x storage
-    itemsize, amortized over the leaf's sequence granularity)."""
-    width = int(np.prod(s.suffix, dtype=np.int64)) if s.suffix else 1
-    return width * jnp.dtype(s.leaf_dtype(cdt)).itemsize / s.granularity
+def _leaf_row_bytes(s, cdt, kvh: int) -> float:
+    """Bytes one *token* of leaf ``s`` occupies across ``kvh`` KV heads
+    (row width x storage itemsize, amortized over the leaf's sequence
+    granularity)."""
+    return s.row_width(kvh) * jnp.dtype(s.leaf_dtype(cdt)).itemsize / \
+        s.granularity
 
 
 def kv_row_bytes(cfg: ModelConfig, names=("k", "v", "k_scale",
@@ -199,8 +177,8 @@ def kv_row_bytes(cfg: ModelConfig, names=("k", "v", "k_scale",
     the plan stores int8/fp8 pages."""
     spec = bk.kv_leaf_specs(cfg)
     cdt = jnp.dtype(cfg.compute_dtype)
-    return int(cfg.num_kv_heads * sum(
-        _leaf_row_bytes(spec[nm], cdt) for nm in names if nm in spec))
+    return int(sum(_leaf_row_bytes(spec[nm], cdt, cfg.num_kv_heads)
+                   for nm in names if nm in spec))
 
 
 def pool_block_bytes(cfg: ModelConfig) -> Dict[str, int]:
@@ -219,8 +197,9 @@ def pool_block_bytes(cfg: ModelConfig) -> Dict[str, int]:
         if plan.kind == "state":
             continue
         leaves = bk.layer_cache_spec(cfg, spec_l).leaves
-        out[plan.kind] += int(cfg.num_kv_heads * sv.block_size * sum(
-            _leaf_row_bytes(s, cdt) for s in leaves.values()))
+        out[plan.kind] += int(sv.block_size * sum(
+            _leaf_row_bytes(s, cdt, cfg.num_kv_heads)
+            for s in leaves.values()))
     out["per_block_id"] = out["paged"] + out["ring"]
     out["num_paged_layers"] = counts["paged"]
     out["num_ring_layers"] = counts["ring"]
@@ -266,9 +245,7 @@ def gather_footprint(cfg: ModelConfig) -> Dict[str, int]:
         spec = backend.cache_spec(cfg)
 
         def leaf_bytes(s):
-            width = int(np.prod(s.suffix, dtype=np.int64)) if s.suffix \
-                else 1
-            return b * kvh * s.rows(n) * width * jnp.dtype(
+            return b * s.rows(n) * s.row_width(kvh) * jnp.dtype(
                 s.leaf_dtype(cdt)).itemsize
 
         full_l = sum(leaf_bytes(s) for s in spec.values())
@@ -278,8 +255,8 @@ def gather_footprint(cfg: ModelConfig) -> Dict[str, int]:
                     if nm in spec]
         kv_bytes = sum(leaf_bytes(spec[nm]) for nm in kv_names)
         selected = backend.selected_rows(cfg, n)
-        row_b = kvh * sum(_leaf_row_bytes(spec[nm], cdt)
-                          for nm in kv_names)
+        row_b = sum(_leaf_row_bytes(spec[nm], cdt, kvh)
+                    for nm in kv_names)
         paged_l = (full_l - kv_bytes) + int(b * selected * row_b)
         fused = backend.supports_paged and backend.fused_paged(cfg)
         if fused:

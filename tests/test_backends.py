@@ -16,6 +16,18 @@ from repro.models import param as pm
 
 ALL_BACKENDS = ["socket", "hard_lsh", "quest", "dense"]
 NB = 4            # blocks per request in the parity fixtures
+LAYER = 1         # the views' layer in a two-layer stack of leaves
+
+
+def _stacked(arrays):
+    """Each leaf as layer ``LAYER`` of a two-layer stack whose other
+    layer holds noise, as the layer scan carries the caches."""
+    rng = np.random.default_rng(99)
+
+    def stack(a):
+        noise = jnp.asarray(rng.normal(size=a.shape)).astype(a.dtype)
+        return jnp.stack([noise, a])
+    return {name: stack(a) for name, a in arrays.items()}
 
 
 def _cfg(backend):
@@ -55,8 +67,9 @@ def _setup(backend, seed=0):
         pages[name] = jnp.asarray(p)
 
     spec = be.cache_spec(cfg)
-    cview = bk.ContiguousView(dict(cache), spec)
-    pview = bk.PagedView(pages, spec, jnp.asarray(bt), block_size=bs)
+    cview = bk.ContiguousView(_stacked(cache), spec, layer=LAYER)
+    pview = bk.PagedView(_stacked(pages), spec, jnp.asarray(bt),
+                         block_size=bs, layer=LAYER)
     q = jnp.asarray(rng.normal(size=(b, kv, cfg.gqa_groups, 1, hd)),
                     jnp.float32)
     return cfg, be, params, cview, pview, q
@@ -151,7 +164,8 @@ def test_socket_kernel_scores_int8_bits():
     vals = jnp.asarray(rng.normal(size=(2, kv, 32, hd)), jnp.float32)
     cache = be8.init_cache(cfg8, 2, kv, 32, jnp.float32)
     cache = be8.prefill_build(cfg8, params, cache, keys, vals)
-    view = bk.ContiguousView(cache, be8.cache_spec(cfg8))
+    view = bk.ContiguousView(_stacked(cache), be8.cache_spec(cfg8),
+                             layer=LAYER)
     outs = {}
     for use_kernel in (False, True):
         ck = cfg8.replace(socket=dataclasses.replace(
@@ -241,10 +255,11 @@ def test_socket_backend_matches_reference_socket_attend(selection):
     out_b = be.attend(cfg, params, q, cview, length=jnp.int32(29),
                       scale=0.125)
     out_ref = sk.socket_attend(
-        bk.socket_config_of(cfg), params["hash_w"], q, cview.arrays["k"],
-        cview.arrays["v"],
-        sk.SocketCache(bits=cview.arrays["bits"],
-                       vnorm=cview.arrays["vnorm"]),
+        bk.socket_config_of(cfg), params["hash_w"], q, cview.leaf("k"),
+        cview.leaf("v"),
+        sk.SocketCache(bits=be._bits(bk.socket_config_of(cfg),
+                                     cview.leaf("bits")),
+                       vnorm=cview.leaf("vnorm")),
         length=jnp.int32(29), scale=0.125)
     np.testing.assert_allclose(np.asarray(out_b), np.asarray(out_ref),
                                atol=1e-6)
@@ -274,8 +289,13 @@ def test_cache_spec_drives_cache_and_axes():
                                         attn_type="global")
     assert set(cache_s) == {"k", "v", "bits", "vnorm"}
     assert cache_s["bits"].dtype == jnp.uint32
-    assert attn.cache_logical_axes(cfg_s, "global")["vnorm"] == (
-        "cache_batch", "cache_heads", "cache_seq")
+    # key-major rows: the KV heads folded into the row, each head's hash
+    # words padded with zeros to whole 128-lane rows
+    assert cache_s["bits"].shape == (2, 1, 32, cfg_s.num_kv_heads, 128)
+    axes_s = attn.cache_logical_axes(cfg_s, "global")
+    assert axes_s["bits"] == ("cache_batch", None, "cache_seq",
+                              "cache_heads", None)
+    assert axes_s["vnorm"] == ("cache_batch", "cache_heads", "cache_seq")
 
 
 FUSED_BACKENDS = ["socket", "hard_lsh", "quest"]
@@ -291,7 +311,8 @@ def _fused_cfg(cfg, backend):
 
 
 def _count_pool_gathers(fn, *args, num_blocks):
-    """# of XLA gather eqns (recursively) whose operand is a pool leaf."""
+    """# of XLA gather eqns (recursively) whose operand is a (layer-
+    stacked) pool leaf."""
     jaxpr = jax.make_jaxpr(fn)(*args)
 
     def walk(jx):
@@ -299,7 +320,7 @@ def _count_pool_gathers(fn, *args, num_blocks):
         for eqn in jx.eqns:
             if eqn.primitive.name == "gather":
                 op = eqn.invars[0].aval
-                if op.ndim >= 3 and op.shape[0] == num_blocks:
+                if op.ndim >= 4 and op.shape[1] == num_blocks:
                     hits += 1
             for sub in jax.core.jaxprs_in_params(eqn.params):
                 hits += walk(sub)
@@ -313,13 +334,14 @@ def test_fused_paged_attend_has_zero_pool_gathers(backend):
     contain ZERO gather primitives on pool-shaped operands, where the
     unfused paged path needs them for every leaf view / top-k row fetch."""
     cfg, be, params, _, pview, q = _setup(backend)
-    num_blocks = pview.arrays["k"].shape[0]
+    num_blocks = pview.arrays["k"].shape[1]
     lengths = jnp.asarray([13, 29], jnp.int32)
 
     def attend(cfg):
         def fn(q, pages, bt):
             view = bk.PagedView(pages, be.cache_spec(cfg), bt,
-                                block_size=cfg.serving.block_size)
+                                block_size=cfg.serving.block_size,
+                                layer=LAYER)
             return be.attend(cfg, params, q, view, length=lengths,
                              scale=0.125)
         return fn
@@ -378,7 +400,7 @@ def test_fused_paged_kernel_rejects_unsupported_combos():
 
     cfg_bs = cfg.replace(socket=base_s)
     bad_view = bk.PagedView(pview.arrays, be.cache_spec(cfg_bs),
-                            pview.block_table, block_size=12)
+                            pview.block_table, block_size=12, layer=LAYER)
     with pytest.raises(NotImplementedError, match="block_size"):
         be.attend(cfg_bs, params, q, bad_view, length=lengths, scale=0.125)
 

@@ -63,8 +63,14 @@ def _build_socket_score(case):
     u = socket.soft_hash_query(w, q) * case.kwargs.get("u_scale", 1.0)
     vnorm = (jax.random.uniform(kv, (bh, n)) + 0.5) if weighted else None
     length = None if lengths is None else jnp.asarray(lengths, jnp.int32)
-    out = socket_score(bits, u, vnorm, num_tables=l, num_planes=p, tau=0.4,
-                       length=length, block_n=block_n)
+    words = bits
+    if case.kwargs.get("lane_pad"):
+        # rows padded to whole 128-lane rows, as the bits leaf stores
+        # them: the padding (all ones here) is never read
+        words = jnp.pad(bits, ((0, 0), (0, 0), (0, 128 - bits.shape[-1])),
+                        constant_values=np.uint32(0xFFFFFFFF))
+    out = socket_score(words, u, vnorm, num_tables=l, num_planes=p,
+                       tau=0.4, length=length, block_n=block_n)
     ref = socket_score_ref(bits, u, vnorm, num_tables=l, num_planes=p,
                            tau=0.4)
     # the XLA scorer the served path runs where the kernel does not
@@ -377,6 +383,10 @@ KERNEL_OPS = (
             # tiles wholly past a row's length are skipped and score 0
             _c("length-skips-tiles", p=10, l=60, n=3072, g=4, bh=2, d=64,
                block_n=1024, weighted=True, lengths=(700, 2100)),
+            # words in rows padded to 128 lanes, the pool's bits leaf
+            _c("lane-padded-words", p=10, l=60, n=1024, g=4, bh=2, d=64,
+               block_n=512, weighted=True, lane_pad=True,
+               lengths=(700, 1024)),
             # bits_storage="int8": the kernel streams ±1 plane bytes
             # (no unpack, no padding tables) — same scores as packed
             _c("int8-bits-paper-point", p=10, l=60, n=1024, g=4, bh=2,

@@ -165,8 +165,9 @@ def test_local_ring_buffer_decode(rng):
     y_ref = attn.attention_train(cfg, params, x, pos, "local")
     _, cache = attn.attention_prefill(cfg, params, x[:, :32], pos[:, :32],
                                       "local", capacity=64)
+    cache = jax.tree_util.tree_map(lambda a: a[None], cache)  # layer axis
     y32, cache = attn.attention_decode(cfg, params, x[:, 32:33], cache,
-                                       jnp.int32(32), "local")
+                                       jnp.int32(32), "local", 0)
     np.testing.assert_allclose(np.asarray(y32[:, 0]),
                                np.asarray(y_ref[:, 32]), atol=2e-4)
 

@@ -146,15 +146,16 @@ def _socket_score():
 def _socket_score_cell():
     """The kernel as the served step calls it at the mistral-7b-v0.3
     decode-long cell's shape: 4 requests, 8 KV heads, group 4, an
-    8,192-token ceiling, the packed words key-major (B, N, KVH, W) as
-    the block-table gather writes them, and per-request lengths."""
+    8,192-token ceiling, the packed words key-major (B, N, KVH, 128) as
+    the block-table gather writes them (the pool pads each row to 128
+    lanes), and per-request lengths."""
     b, n = 4, 8192
 
     def fn(words, u, length):
         return socket_score_pallas(words, u, None, num_tables=L,
                                    num_planes=P, tau=0.4, length=length,
                                    interpret=False)
-    return fn, [((b, n, KVH, W), jnp.uint32),
+    return fn, [((b, n, KVH, 128), jnp.uint32),
                 ((b, KVH, G, L, P), jnp.float32), ((b,), jnp.int32)]
 
 
